@@ -12,11 +12,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 
 import paddle_tpu as paddle
 from paddle_tpu import nn, optimizer
+from paddle_tpu.core.chip import enable_compile_cache
 from paddle_tpu.vision.datasets import MNIST
 from paddle_tpu.vision.models import LeNet
 
 
 def main():
+    enable_compile_cache()
     paddle.seed(0)
     model = paddle.Model(LeNet(10))
     model.prepare(optimizer.Adam(1e-3, parameters=model.parameters()),

@@ -50,6 +50,7 @@ import contextlib
 from dataclasses import dataclass
 from typing import FrozenSet, Iterator, List, Tuple
 
+from ..core.chip import chip_peaks
 from .jaxpr_lint import Diagnostic, ERROR, WARNING, emit
 
 __all__ = ["CommSpec", "check_comm_spec", "enforce", "record", "recording",
@@ -84,9 +85,11 @@ SPEC_NAMES = (ALLGATHER_MATMUL, MATMUL_REDUCE_SCATTER, CP_RING,
               SLICE_REDUCE_SCATTER, DCN_ALLREDUCE, SLICE_ALL_GATHER,
               FLAT_ICI_ALLREDUCE)
 
-# Per-direction, per-link ICI bandwidth (v5e 2D torus) and bf16 peak.
+# Per-direction, per-link ICI bandwidth (v5e 2D torus) and bf16 peak. The
+# accounting is static (it runs at trace time, with no chip attached), so
+# it names the chip it plans for; the peak comes from the one table.
 ICI_GBPS = 45.0
-PEAK_TFLOPS = 197.0
+PEAK_TFLOPS = chip_peaks("TPU v5 lite").bf16_tflops
 
 # Per-chip DCN bandwidth between pod slices (host NICs shared across the
 # slice's chips; assumed v5e-class figure — ~7x below one ICI direction).

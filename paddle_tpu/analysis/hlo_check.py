@@ -125,12 +125,15 @@ def collect_hlo_facts(compiled) -> HloFacts:
         memory = _hlo_utils.memory_stats(compiled)
     mod = _hlo_utils.parse_hlo(text)
     facts = HloFacts(memory=memory, aliases=list(mod.aliases))
-    # name -> (out dtype, operand dtype, operand name) for convert ops
-    converts: Dict[str, Tuple[str, str, str]] = {}
+    # convert name -> (out dtype, operand name); operand dtypes come from
+    # the instruction table (this XLA prints operands without their types)
+    converts: Dict[str, Tuple[str, str]] = {}
+    dtype_of: Dict[str, str] = {}
     import re as _re
-    conv_pat = _re.compile(r"convert\((\w+)\[[^\]]*\][^%]*%([\w.\-]+)\)")
+    conv_pat = _re.compile(r"convert\((?:[^%()]*\s)?%([\w.\-]+)\)")
     for ins in mod.instructions():
         facts.n_instructions += 1
+        dtype_of[ins.name] = ins.dtype
         if ins.dtype in ("f64", "c128"):
             facts.f64_values += 1
         if ins.op in COLLECTIVE_OPS:
@@ -141,12 +144,13 @@ def collect_hlo_facts(compiled) -> HloFacts:
         elif ins.op == "convert":
             m = conv_pat.search(ins.line)
             if m:
-                converts[ins.name] = (ins.dtype, m.group(1), m.group(2))
+                converts[ins.name] = (ins.dtype, m.group(1))
     # convert round-trip chains: convert(convert(x: a) -> b) -> a — pure
     # churn (a->b->c staged casts are legitimate and not counted)
-    for out_dtype, _, src_name in converts.values():
+    for out_dtype, src_name in converts.values():
         inner = converts.get(src_name)
-        if inner is not None and inner[1] == out_dtype and out_dtype:
+        if (inner is not None and out_dtype
+                and dtype_of.get(inner[1]) == out_dtype):
             facts.convert_chains += 1
     return facts
 

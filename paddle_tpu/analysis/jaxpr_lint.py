@@ -24,6 +24,8 @@ import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
+import jax
+
 from ._jaxpr_utils import (CALLBACK_PRIMS, INLINE_PRIMS, LOOP_PRIMS,
                            eqn_source, fmt_aval, inner_jaxprs)
 
@@ -507,10 +509,15 @@ def _rule_nondet_reduction(ctx: LintContext):
 
 
 def _transfer_kinds(eqn) -> List[str]:
-    """Explicit memory-kind targets of a device_put eqn (Sharding or
-    TransferToMemoryKind destinations with a declared memory_kind)."""
+    """Explicit memory-kind targets of a device_put eqn: a Sharding with
+    a declared ``memory_kind``, or a ``jax.memory.Space`` (how a traced
+    function names host/device memory without a concrete sharding)."""
     kinds = []
     for d in (eqn.params.get("devices") or ()):
+        if isinstance(d, jax.memory.Space):
+            if d is not jax.memory.Space.Any:
+                kinds.append(d.name.lower())
+            continue
         k = getattr(d, "memory_kind", None)
         if k is not None:
             kinds.append(str(k))

@@ -38,7 +38,8 @@ from .jaxpr_lint import Diagnostic, ERROR, WARNING, emit
 
 __all__ = ["VMEM_BUDGET", "KernelSpec", "BlockUse", "check_kernel_spec",
            "spec_for_flash_packed", "spec_for_flash", "spec_for_conv_matmul",
-           "spec_for_conv3x3", "enforce", "check_jaxpr_pallas"]
+           "spec_for_conv3x3", "enforce", "report_fallback",
+           "check_jaxpr_pallas"]
 
 # Mosaic's scoped-VMEM stack per core (v4/v5 generations): ~16 MB.
 VMEM_BUDGET = 16 * 1024 * 1024
@@ -277,6 +278,27 @@ def enforce(spec: KernelSpec, where: str = "") -> List[Diagnostic]:
         return []
     diags = check_kernel_spec(spec)
     return emit(diags, where=where or spec.name)
+
+
+_FALLBACKS_REPORTED: set = set()
+
+
+def report_fallback(kernel: str, shape: str, reason: str) -> None:
+    """P005: on a TPU, a Pallas kernel was asked for (its flag is on) and
+    the call takes the XLA path instead. Reported once per (kernel,
+    shape), whatever ``FLAGS_static_analysis`` says — a fallback that is
+    not announced hides an unused chip path."""
+    if (kernel, shape) in _FALLBACKS_REPORTED:
+        return
+    _FALLBACKS_REPORTED.add((kernel, shape))
+    d = Diagnostic(
+        rule="P005", name="kernel-fallback", severity=WARNING,
+        message=f"{kernel} does not take its Pallas kernel for {shape}: "
+                f"{reason} — this call runs the XLA path",
+        where=kernel,
+        hint="pad/reshape to a supported shape, or switch the kernel's "
+             "flag off to make the XLA path the declared one")
+    emit([d], where=kernel, mode="warn")
 
 
 # ---------------------------------------------------------------------------

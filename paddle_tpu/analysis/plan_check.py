@@ -636,8 +636,8 @@ def enforce(plan: StepPlan, closed_jaxpr=None, *,
 
 # The six flag-gated tiers and their supported values. Every combination
 # is a supported composition; parts that cannot activate in a given
-# environment (e.g. the decomposed TP matmul on a legacy-jax multi-axis
-# mesh, or the multislice reduction on a mesh without a 'slice' axis)
+# environment (e.g. the decomposed TP matmul on a mesh whose mp axis is
+# 1, or the multislice reduction on a mesh without a 'slice' axis)
 # gate themselves off at the call site, and the plan records what was
 # actually composed.
 TIER_FLAGS: Tuple[Tuple[str, Tuple[Any, ...]], ...] = (

@@ -113,7 +113,7 @@ def lint_file(path: str, relpath: Optional[str] = None) -> List[Diagnostic]:
                 "wall-clock, not device time, and is a trace-time "
                 "constant under jit",
                 hint="use the profiler-trace device timing "
-                     "(ops/_pallas/autotune._device_ms_from_trace)")
+                     "(profiler.statistic.device_total_ms)")
         # R002: constant PRNG seeds in library code
         if not in_tests and dotted.endswith("PRNGKey") and node.args and \
                 isinstance(node.args[0], ast.Constant):

@@ -192,8 +192,6 @@ def _eager_run(group: Group, fn, x, out_has_rank_dim: bool = True):
     group.nranks): shard x's leading dim over the group axes, apply fn in
     shard_map (real XLA collective over the mesh devices), return the results
     re-stacked along the rank dim — same layout in, same layout out."""
-    # jax.shard_map (the maintained entry point; the legacy
-    # jax.experimental path rejects check_vma in this jax version)
     shard_map = jax.shard_map
     mesh = group.mesh
     n = group.nranks

@@ -86,25 +86,30 @@ def _dense_block_olse(q, k, v, scale, causal, q_off, k_off):
 
 def _ring_use_flash(s_local: int, d: int, dtype) -> bool:
     """Static decision: run the Pallas flash kernel inside the ring step?
-    (TPU backend + kernel-supported local block shapes; else dense jnp —
-    the CPU-mesh test path.)"""
+    (TPU backend + kernel-supported local block shapes; off the chip the
+    dense jnp hop is the declared path — the CPU-mesh test path. An
+    unsupported block ON a TPU is announced once, P005.)"""
     from ..core import flags
     if not flags.flag("use_pallas_kernels"):
         return False
     if jax.default_backend() != "tpu":
         return False
-    return s_local % 128 == 0 and d in (64, 128, 256)
+    if s_local % 128 == 0 and d in (64, 128, 256):
+        return True
+    from ..analysis.pallas_check import report_fallback
+    report_fallback(
+        "ring_attention_hop", f"s_local={s_local} d={d}",
+        "needs the per-rank sequence block % 128 == 0 and head_dim in "
+        "(64, 128, 256)")
+    return False
 
 
 def _inner_mesh(mesh):
     """Mesh to hand a nested shard_map: when already inside a shard_map /
     use_mesh scope (e.g. the pipeline runtime's manual pp axis), jax
     requires the AMBIENT abstract mesh, not the concrete one."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return mesh
-    if am is not None and len(getattr(am, "axis_names", ())):
+    am = jax.sharding.get_abstract_mesh()
+    if am is not None and len(am.axis_names):
         return am
     return mesh
 
@@ -122,14 +127,10 @@ def _nested_ring_enabled() -> bool:
 def _ambient_manual_axes():
     """Axis names already bound manual by an enclosing shard_map (e.g. the
     pipeline runtime's pp axis)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return ()
+    am = jax.sharding.get_abstract_mesh()
     if am is None:
         return ()
-    return tuple(n for n, t in zip(am.axis_names,
-                                   getattr(am, "axis_types", ()))
+    return tuple(n for n, t in zip(am.axis_names, am.axis_types)
                  if "Manual" in str(t))
 
 

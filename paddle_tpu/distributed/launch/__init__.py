@@ -18,6 +18,7 @@ contract on the trainer side.
 
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import socket
@@ -118,8 +119,40 @@ class Pod:
             c.terminate()
 
 
+def local_tpu_chips() -> List[str]:
+    """Device nodes of the TPU chips attached to this host. Read from
+    /dev, never through JAX: a launcher that touched JAX would hold the
+    chip its children need."""
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_one_process_per_chip(nproc: int, env: Dict[str, str]) -> None:
+    """Refuse N > 1 local processes that would all open this host's TPU.
+
+    A chip belongs to one process at a time and nothing here gives each
+    child a chip of its own, so N children would fail or hang on the
+    device lock. On a TPU host one process drives every local chip
+    through the mesh. Children whose environment pins the CPU platform
+    (the chipless drills, the CPU test pods) are unaffected."""
+    if nproc <= 1:
+        return
+    if env.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
+        return
+    chips = local_tpu_chips()
+    if chips:
+        raise RuntimeError(
+            f"{nproc} processes per node on a host with {len(chips)} TPU "
+            "chip(s): a chip belongs to one process at a time, and one "
+            "process drives all local chips (build the mesh over "
+            "jax.devices() with create_hybrid_mesh). Launch one process "
+            "per host, or set JAX_PLATFORMS=cpu for chipless workers.")
+
+
 def build_pod(cfg: LaunchConfig, training_script: str,
               script_args: Sequence[str]) -> Pod:
+    check_one_process_per_chip(cfg.nproc_per_node,
+                               {**os.environ, **cfg.envs})
     world = cfg.nnodes * cfg.nproc_per_node
     master = cfg.master
     if world > 1 and not master:

@@ -58,10 +58,8 @@ ICI budget) by :mod:`paddle_tpu.analysis.comm_check` at trace time and
 instrumented as a telemetry ``comm`` phase / ``comm/*`` trace span at
 dispatch level (``observability/step_monitor.py``).
 
-Compat: built on ``jax.shard_map`` where available; on legacy jax
-(0.4.x) it falls back to ``jax.experimental.shard_map`` — partial-auto
-meshes (a >1 axis outside the decomposed one) are only supported on the
-maintained API, so :func:`can_decompose` gates on that.
+Built on ``jax.shard_map`` with the decomposed axis manual and every
+other mesh axis left to GSPMD (partial-auto).
 """
 
 from __future__ import annotations
@@ -100,9 +98,6 @@ SP_COMM_SPECS = (ALLGATHER_MATMUL, MATMUL_REDUCE_SCATTER)
 # optimization_barrier chain in zero_gather_ahead).
 GATHER_AHEAD_DEPTH = 2
 
-_LEGACY_SHARD_MAP = not hasattr(jax, "shard_map")
-
-
 # ---------------------------------------------------------------------------
 # Mode plumbing
 # ---------------------------------------------------------------------------
@@ -133,44 +128,27 @@ def dp_enabled() -> bool:
 
 def shard_map_compat(fn: Callable, mesh, in_specs, out_specs,
                      axis_names) -> Callable:
-    """``jax.shard_map`` with ``axis_names`` manual; on legacy jax the
-    ``jax.experimental.shard_map`` form with the complement as ``auto``.
+    """``jax.shard_map`` with ``axis_names`` manual, the rest auto.
 
-    Varying-manual-axes checking is off either way: the decomposed loops
-    build their accumulators with ``jnp.zeros`` (unvarying until the
-    first ppermute'd write), which strict vma tracking rejects without
-    pcast noise on every init."""
-    if not _LEGACY_SHARD_MAP:
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 axis_names=set(axis_names),
-                                 check_vma=False)
-        except TypeError:  # pre-check_vma spelling
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 axis_names=set(axis_names))
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    Varying-manual-axes checking is off: the decomposed loops build
+    their accumulators with ``jnp.zeros`` (unvarying until the first
+    ppermute'd write), which strict vma tracking rejects without pcast
+    noise on every init."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(axis_names),
+                         check_vma=False)
 
 
 def _ambient_manual() -> bool:
-    try:
-        from .context_parallel import _ambient_manual_axes
-        return bool(_ambient_manual_axes())
-    except Exception:
-        return False
+    from .context_parallel import _ambient_manual_axes
+    return bool(_ambient_manual_axes())
 
 
 def can_decompose(mesh, axis: str = MP_AXIS) -> bool:
     """Is the decomposed ppermute pipeline usable on this mesh/axis here?
 
-    Requires the axis with degree > 1, no enclosing manual shard_map
-    (nested manual rings belong to the context-parallel path), and — on
-    legacy jax, where partial-auto shard_map miscompiles with a second
-    >1 axis — that ``axis`` is the only non-trivial mesh axis.
+    Requires the axis with degree > 1 and no enclosing manual shard_map
+    (nested manual rings belong to the context-parallel path).
     """
     if mesh is None or axis not in mesh.axis_names:
         return False
@@ -178,8 +156,6 @@ def can_decompose(mesh, axis: str = MP_AXIS) -> bool:
         return False
     if _ambient_manual():
         return False
-    if _LEGACY_SHARD_MAP:
-        return all(mesh.shape[a] == 1 for a in mesh.axis_names if a != axis)
     return True
 
 

@@ -13,7 +13,7 @@ import multiprocessing as mp
 import os
 from typing import Optional, Sequence
 
-from .launch import free_port
+from .launch import check_one_process_per_chip, free_port
 
 __all__ = ["spawn"]
 
@@ -54,6 +54,7 @@ def spawn(func, args: Sequence = (), nprocs: int = 1, join: bool = True,
     master = f"127.0.0.1:{free_port()}"
     endpoints = [f"127.0.0.1:{free_port()}" for _ in range(nprocs)]
     env = {k: v for k, v in options.pop("envs", {}).items()}
+    check_one_process_per_chip(nprocs, {**os.environ, **env})
     queue = ctx.Queue()
     procs = []
     for rank in range(nprocs):

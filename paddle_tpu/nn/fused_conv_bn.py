@@ -147,10 +147,7 @@ def _pallas_conv():
 
 
 def _pallas_route(x, w, stride, padding, dilation, groups) -> bool:
-    try:
-        _pc = _pallas_conv()
-    except Exception:
-        return False
+    _pc = _pallas_conv()
     if not _pc.pallas_conv_enabled():
         return False
     return _pc.supports(x.shape, w.shape, stride, padding, dilation,

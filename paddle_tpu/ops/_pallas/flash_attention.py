@@ -52,12 +52,9 @@ if "flash_head_pack" not in _flags.get_flags():
 
 def _tuned_blocks(sq: int, sk: int, d: int):
     """Cached autotune result for this shape class, or None."""
-    try:
-        from .autotune import get_cache
-        hit = get_cache().get("flash_attention", f"sq{sq}_sk{sk}_d{d}")
-        return tuple(hit) if hit else None
-    except Exception:
-        return None
+    from .autotune import get_cache
+    hit = get_cache().get("flash_attention", f"sq{sq}_sk{sk}_d{d}")
+    return tuple(hit) if hit else None
 
 
 def tune_flash_blocks(query, key, value, causal: bool = False,
@@ -888,6 +885,84 @@ def supported_shapes(query, key) -> bool:
     return sq % 128 == 0 and sk % 128 == 0 and d in (64, 128, 256)
 
 
+def _free_mesh_axes():
+    """(mesh, manual, free): the hybrid mesh, the axes an enclosing
+    shard_map has already bound manual, and the >1 axes GSPMD still
+    partitions here. ``free`` is empty on one device, without a hybrid
+    mesh, or inside a fully manual region."""
+    from ...distributed.context_parallel import _ambient_manual_axes
+    from ...distributed.topology import get_hybrid_mesh
+    mesh = get_hybrid_mesh()
+    if mesh is None or mesh.size == 1:
+        return mesh, (), ()
+    manual = _ambient_manual_axes()
+    return mesh, manual, tuple(a for a in mesh.axis_names
+                               if a not in manual and mesh.shape[a] > 1)
+
+
+def _flash_on_mesh(mesh, manual, free, query, key, value, causal, scale,
+                   block_q, block_k, segment_ids, segment_ids_k, dropout,
+                   dropout_seed, key_bias):
+    """Run the kernel per shard under ``shard_map``. Mosaic kernels cannot
+    be partitioned by GSPMD ("wrap the call in a shard_map"), so inside a
+    multi-device step the call is made manual over every free mesh axis:
+    batch over the data axes and heads over ``mp`` where they divide,
+    replicated otherwise. Attention is independent per (batch, head), so
+    the result is exact."""
+    from jax.sharding import PartitionSpec as P
+    from ...distributed.context_parallel import _inner_mesh
+    b, sq, h, _ = query.shape
+    sk, hk = key.shape[1], key.shape[2]
+
+    def pick(axes, n):
+        axes = tuple(a for a in axes if a in free)
+        size = math.prod(mesh.shape[a] for a in axes)
+        return axes if axes and n % size == 0 else ()
+
+    data = pick(("dp", "sharding"), b)
+    heads = pick(("mp",), math.gcd(h, hk))
+    d_ax = (data if len(data) > 1 else data[0]) if data else None
+    h_ax = heads[0] if heads else None
+    qkv_spec = P(d_ax, None, h_ax, None)
+    row_spec = P(d_ax, None)
+    n_shards = math.prod(mesh.shape[a] for a in data + heads)
+    # one index per shard (a sharded arange, not lax.axis_index, which
+    # does not verify when this region nests inside another manual axis):
+    # folded into the dropout seed so shards draw different masks
+    shard_ids = jnp.arange(n_shards, dtype=jnp.int32)
+    ids_spec = P(data + heads if data or heads else None)
+    if dropout > 0.0 and dropout_seed is None:
+        from ...core.random import next_key
+        dropout_seed = jax.random.randint(
+            next_key(), (1,), 0, 2 ** 31 - 1, dtype=jnp.int32)
+    seed = None if dropout_seed is None else \
+        jnp.asarray(dropout_seed, jnp.int32).reshape(1)
+    if key_bias is not None:
+        key_bias = jnp.broadcast_to(
+            jnp.asarray(key_bias, jnp.float32).reshape(-1, sk), (b, sk))
+    opt = {"segment_ids": (segment_ids, row_spec),
+           "segment_ids_k": (segment_ids_k, row_spec),
+           "dropout_seed": (seed, P()), "key_bias": (key_bias, row_spec)}
+    names = [n for n, (v, _) in opt.items() if v is not None]
+
+    def per_shard(q, k, v, ids, *rest):
+        kw = dict(zip(names, rest))
+        if "dropout_seed" in kw:
+            kw["dropout_seed"] = kw["dropout_seed"] + ids[0] * 7919
+        return flash_attention_pallas(q, k, v, causal=causal, scale=scale,
+                                      block_q=block_q, block_k=block_k,
+                                      dropout=dropout, **kw)
+
+    fn = jax.shard_map(
+        per_shard, mesh=_inner_mesh(mesh),
+        in_specs=(qkv_spec, qkv_spec, qkv_spec, ids_spec,
+                  *(opt[n][1] for n in names)),
+        out_specs=qkv_spec, check_vma=False,
+        # Mosaic wants EVERY mesh axis manual, the size-1 ones too
+        axis_names=set(mesh.axis_names) - set(manual))
+    return fn(query, key, value, shard_ids, *(opt[n][0] for n in names))
+
+
 def flash_attention_pallas(query, key, value, causal: bool = False,
                            scale: Optional[float] = None,
                            block_q: Optional[int] = None,
@@ -905,7 +980,13 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
     keys with an equal segment id (the TPU-native form of
     flash_attn_unpadded — static shapes, sequences packed along S).
     ``segment_ids_k`` ([B, Sk]) defaults to ``segment_ids``
-    (self-attention packing)."""
+    (self-attention packing). Under a multi-device hybrid mesh the call
+    runs per shard (``_flash_on_mesh``)."""
+    mesh, manual, free = _free_mesh_axes()
+    if free:
+        return _flash_on_mesh(mesh, manual, free, query, key, value, causal,
+                              scale, block_q, block_k, segment_ids,
+                              segment_ids_k, dropout, dropout_seed, key_bias)
     b, sq, h, d = query.shape
     sk = key.shape[1]
     hk = key.shape[2]

@@ -57,15 +57,12 @@ def _pick_blocks_packed(sq: int, sk: int, dp: int, bwd: bool = False):
     temporaries per head vs the forward's 2) need smaller score tiles, so
     bwd caps at 256-square. The autotune cache overrides when populated
     (key class flash_packed / flash_packed_bwd)."""
-    try:
-        from .autotune import get_cache
-        hit = get_cache().get("flash_packed" + ("_bwd" if bwd else ""),
-                              f"sq{sq}_sk{sk}_dp{dp}")
-        if hit:
-            tq, tk = tuple(hit)
-            return min(tq, sq), min(tk, sk)
-    except Exception:
-        pass
+    from .autotune import get_cache
+    hit = get_cache().get("flash_packed" + ("_bwd" if bwd else ""),
+                          f"sq{sq}_sk{sk}_dp{dp}")
+    if hit:
+        tq, tk = tuple(hit)
+        return min(tq, sq), min(tk, sk)
     # on-chip sweep at B64 S512 H12, fwd+bwd, device time, only configs
     # that pass the numeric guard: bwd 256x512 5.20 ms vs 256x256 5.91 /
     # 512x256 6.05; 512x512 overflows the 16MB scoped-vmem stack (the

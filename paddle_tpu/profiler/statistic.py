@@ -19,8 +19,8 @@ import glob
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
-__all__ = ["host_statistics", "device_statistics", "summary_report",
-           "EventStat"]
+__all__ = ["host_statistics", "device_statistics", "device_total_ms",
+           "summary_report", "EventStat"]
 
 
 class EventStat:
@@ -150,6 +150,29 @@ def device_statistics(log_dir: str, top: int = 15, diagnostics=None):
             f"{type(e).__name__}: {e}", severity=WARNING,
             diagnostics=diagnostics)
         return None
+
+
+def device_total_ms(log_dir: str) -> Optional[float]:
+    """Total device self-time (ms) of the newest trace under ``log_dir``
+    — the basis of every device timing (bench steps, kernel autotune).
+
+    On a TPU a trace that cannot be read, or that shows no device time,
+    raises with the parser's reasons: a caller must never drop to the
+    host clock unannounced. Off the chip there is no device plane to
+    read and the answer is None."""
+    import jax
+    diags: list = []
+    stats = device_statistics(log_dir, top=1, diagnostics=diags)
+    total = sum(stats[0].values()) if stats else 0.0
+    if total > 0:
+        return total
+    if jax.default_backend() == "tpu":
+        why = "; ".join(d.message for d in diags) or \
+            "the trace holds no device op time"
+        raise RuntimeError(
+            f"device trace under {log_dir!r} could not be read on a "
+            f"TPU: {why}")
+    return None
 
 
 def _fmt_time(ns: float, unit: str) -> str:

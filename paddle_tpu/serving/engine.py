@@ -72,6 +72,7 @@ import numpy as np
 
 from ..core import flags as _flags
 from ..fault.injection import fire as _fault_fire
+from ..framework.functional import _swapped_state, get_params
 from ..observability import live as fleet_live
 from ..observability import metrics, request_timeline
 from ..observability.request_timeline import percentile
@@ -120,6 +121,36 @@ def _multi_query_attention(q, k, v, pos):
                              1e-30)).astype(q.dtype)
     out = jnp.einsum("blkgs,bskd->blkgd", probs, v)
     return out.reshape(b, L, h, d)
+
+
+class _ParamJit:
+    """``jax.jit`` of a step that reads ``model``'s weights, with the
+    weights an explicit leading argument of the compiled program.
+
+    The raw step closes over the layer tree; jitted as it stands, every
+    weight would be baked into the program as a constant — one copy of
+    the model inside each bucket program, which a 1.3B model cannot
+    afford on a 16 GB chip (and which no compiler should be handed as
+    literals). Callers keep the raw signature: ``fn(*args)`` and
+    ``fn.lower(*args)`` put the weights (snapshotted here, as the
+    closure's were at first trace) in front."""
+
+    def __init__(self, raw, model):
+        self.params = get_params(model)
+
+        def step(params, *args):
+            with _swapped_state(model, params, None):
+                return raw(*args)
+
+        # every step is (tokens, k_pages, v_pages, ...): the two page
+        # pools are donated (raw args 1, 2 — behind the weights: 2, 3)
+        self.jitted = jax.jit(step, donate_argnums=(2, 3))
+
+    def __call__(self, *args):
+        return self.jitted(self.params, *args)
+
+    def lower(self, *args):
+        return self.jitted.lower(self.params, *args)
 
 
 class ServingEngine:
@@ -263,8 +294,8 @@ class ServingEngine:
         # -- compiled steps + their sentinels --------------------------------
         self._prefill_raw = self._make_prefill()
         self._decode_raw = self._make_decode()
-        self._prefill_fn = jax.jit(self._prefill_raw, donate_argnums=(1, 2))
-        self._decode_fn = jax.jit(self._decode_raw, donate_argnums=(1, 2))
+        self._prefill_fn = _ParamJit(self._prefill_raw, model)
+        self._decode_fn = _ParamJit(self._decode_raw, model)
         self._sent_prefill = RecompileSentinel(
             threshold=len(self.prefill_buckets))
         self._sent_decode = RecompileSentinel(
@@ -275,8 +306,7 @@ class ServingEngine:
         if self.prefix_on or self.chunk_tokens:
             self._chunk_raw = self._make_extend(self.model,
                                                 last_only=True)
-            self._chunk_fn = jax.jit(self._chunk_raw,
-                                     donate_argnums=(1, 2))
+            self._chunk_fn = _ParamJit(self._chunk_raw, model)
             self._sent_chunk = RecompileSentinel(
                 threshold=len(self.prefill_buckets))
         self._verify_raw = None
@@ -285,20 +315,19 @@ class ServingEngine:
         if self.spec_gamma:
             self._verify_raw = self._make_extend(self.model,
                                                  last_only=False)
-            self._verify_fn = jax.jit(self._verify_raw,
-                                      donate_argnums=(1, 2))
+            self._verify_fn = _ParamJit(self._verify_raw, model)
             self._sent_verify = RecompileSentinel(
                 threshold=len(self.decode_buckets))
         self._draft_decode_fn = None
         self._draft_extend_fn = None
         self._sent_draft = None
         if self._draft_cache is not None:
-            self._draft_decode_fn = jax.jit(
+            self._draft_decode_fn = _ParamJit(
                 self._make_decode(self.drafter.model),
-                donate_argnums=(1, 2))
-            self._draft_extend_fn = jax.jit(
+                self.drafter.model)
+            self._draft_extend_fn = _ParamJit(
                 self._make_extend(self.drafter.model, last_only=True),
-                donate_argnums=(1, 2))
+                self.drafter.model)
             self._sent_draft = RecompileSentinel(
                 threshold=len(self.decode_buckets) +
                 len(self.prefill_buckets))

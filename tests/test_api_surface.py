@@ -229,7 +229,9 @@ class TestDeviceAPI:
 
     def test_accelerator_namespace(self):
         assert paddle.device.cuda is paddle.device.tpu
-        assert paddle.device.tpu.device_count() >= 1
+        # the suite is chipless: the accelerator count is 0 here, never
+        # the CPU count under the accelerator's name
+        assert paddle.device.tpu.device_count() == 0
         paddle.device.tpu.empty_cache()
         stats = paddle.device.tpu.memory_stats()
         assert isinstance(stats, dict)

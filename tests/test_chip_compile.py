@@ -1,0 +1,238 @@
+"""Deviceless compiles for the real chip: the Pallas kernels of the main
+path, at real widths, handed to the TPU's own compiler for a described
+(not attached) ``v5e:2x2`` topology. Nothing runs — a pass says the chip's
+compiler accepts the program (what interpret mode cannot say), never that
+its results or its speed are right.
+
+This is the one file that describes the topology. It does so inside a
+module-scoped fixture (only the xdist worker that is handed this file
+loads the TPU library), never at import; the persistent compile cache is
+off around these compiles (a deviceless executable cannot be read back).
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` on ShapeDtypeStructs placed on the described chip and
+    compile it; returns the compiled text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _qkv(b, s, h, d, dtype=jnp.bfloat16):
+    return [((b, s, h, d), dtype)] * 3
+
+
+# GPT-1.3B attention shape (per-head kernel, d=128) and the BERT-base
+# shape (head-packed kernel, d=64).
+FLASH_SHAPES = {"gpt13b_d128": (4, 2048, 16, 128, True),
+                "bert_d64_packed": (64, 512, 12, 64, False)}
+
+
+@pytest.mark.parametrize("which", sorted(FLASH_SHAPES))
+@pytest.mark.parametrize("pass_", ["fwd", "bwd"])
+def test_flash_attention_compiles(one_chip, which, pass_):
+    from paddle_tpu.ops._pallas.flash_attention import flash_attention_pallas
+    b, s, h, d, causal = FLASH_SHAPES[which]
+
+    def fwd(q, k, v):
+        return flash_attention_pallas(q, k, v, causal=causal)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if pass_ == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _compile(fn, one_chip, *_qkv(b, s, h, d))
+    # fwd is one custom call; bwd re-runs fwd and adds the backward
+    # kernel(s) (dq and dk/dv apart at d=128, one at packed d=64)
+    assert text.count("tpu_custom_call") >= (1 if pass_ == "fwd" else 2)
+
+
+@pytest.mark.parametrize("variant", ["segment_ids", "dropout"])
+@pytest.mark.parametrize("pass_", ["fwd", "bwd"])
+def test_flash_variants_compile(one_chip, variant, pass_):
+    """The packed-varlen (flash_attn_unpadded's segment ids) and the
+    attention-prob dropout variants of the d=128 kernel."""
+    from paddle_tpu.ops._pallas.flash_attention import flash_attention_pallas
+    b, s, h, d = 4, 2048, 16, 128
+
+    def fwd(q, k, v, extra):
+        if variant == "segment_ids":
+            return flash_attention_pallas(q, k, v, causal=True,
+                                          segment_ids=extra)
+        return flash_attention_pallas(q, k, v, causal=True, dropout=0.1,
+                                      dropout_seed=extra)
+
+    def loss(q, k, v, extra):
+        return fwd(q, k, v, extra).astype(jnp.float32).sum()
+
+    fn = fwd if pass_ == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    extra = ((b, s), jnp.int32) if variant == "segment_ids" \
+        else ((1,), jnp.int32)
+    text = _compile(fn, one_chip, *_qkv(b, s, h, d), extra)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_hop_flash_compiles(one_chip, causal):
+    """The (o, lse) call one ring-attention hop makes on its resident KV
+    block (context_parallel: S=2048 over a 4-way ring -> 512 per rank),
+    forward and backward through the lse cotangent."""
+    from paddle_tpu.ops._pallas.flash_attention import (
+        flash_attention_with_lse)
+
+    def loss(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, causal=causal)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+                    *_qkv(4, 512, 16, 128))
+    assert text.count("tpu_custom_call") >= 2
+
+
+# ResNet-50 stage-1 1x1 shapes (batch 256, 56x56): reduce 256->64 and
+# expand 64->256, as RESNET50_TOP3_SHAPES lists them.
+STAGE1_1X1 = {"reduce_256_64": (256, 56, 56, 256, 64),
+              "expand_64_256": (256, 56, 56, 64, 256)}
+
+
+@pytest.mark.parametrize("which", sorted(STAGE1_1X1))
+def test_fused_matmul_bn_compiles(one_chip, which):
+    from paddle_tpu.ops._pallas.fused_matmul_bn import fused_matmul_bn_act
+    n, h, w, cin, cout = STAGE1_1X1[which]
+    m = n * h * w
+
+    def loss(x, wgt, scale, shift):
+        y, s, ss = fused_matmul_bn_act(x, wgt, scale, shift)
+        return y.astype(jnp.float32).sum() + s.sum() + ss.sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
+                    ((m, cin), jnp.bfloat16), ((cin, cout), jnp.bfloat16),
+                    ((cin,), jnp.float32), ((cin,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("which", sorted(STAGE1_1X1))
+def test_pallas_conv1x1_compiles(one_chip, which):
+    """The 1x1-as-matmul conv kernel with its BN+ReLU prologue and stat
+    epilogue, compiled for Mosaic (interpret=False), fwd + dgrad + wgrad."""
+    from paddle_tpu.ops._pallas import conv as pconv
+    n, h, w, cin, cout = STAGE1_1X1[which]
+    x_shape, w_shape = (n, h, w, cin), (cout, cin, 1, 1)
+
+    def fwd(x, wgt, scale, shift):
+        return pconv.conv2d_fwd(x, wgt, scale, shift, act="relu",
+                                interpret=False)
+
+    def bwd(x, wgt, dy):
+        return (pconv.conv2d_dgrad(dy, wgt, x_shape, interpret=False),
+                pconv.conv2d_wgrad(x, dy, w_shape, interpret=False))
+
+    bf16 = jnp.bfloat16
+    text = _compile(fwd, one_chip, (x_shape, bf16), (w_shape, bf16),
+                    ((cin,), jnp.float32), ((cin,), jnp.float32))
+    assert "tpu_custom_call" in text
+    text = _compile(bwd, one_chip, (x_shape, bf16), (w_shape, bf16),
+                    ((n, h, w, cout), bf16))
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_pallas_conv3x3_is_refused_and_not_routable_on_tpu(one_chip,
+                                                           monkeypatch):
+    """The 3x3 kernels have only ever run in interpret mode: Mosaic
+    refuses them (value-level dynamic_slice in _c3_taps). Pin the
+    compiler's answer, and that ``supports`` says no for 3x3 on a TPU —
+    announced (P005), not an interpret-mode run or a silent lax hand-over.
+    When the kernel is rewritten to compile, this becomes its compile
+    test; if it is deleted (ROADMAP A2/C2), this goes with it."""
+    from paddle_tpu.analysis import pallas_check
+    from paddle_tpu.ops._pallas import conv as pconv
+    x_shape, w_shape = (256, 56, 56, 64), (64, 64, 3, 3)
+
+    def fwd(x, wgt):
+        return pconv.conv2d_fwd(x, wgt, padding=(1, 1), interpret=False)
+
+    with pytest.raises(NotImplementedError, match="dynamic_slice"):
+        _compile(fwd, one_chip, (x_shape, jnp.bfloat16),
+                 (w_shape, jnp.bfloat16))
+
+    kw = dict(stride=(1, 1), padding=(1, 1), dtype=jnp.bfloat16)
+    assert pconv.supports(x_shape, w_shape, **kw)  # interpret mode: yes
+    monkeypatch.setattr(pconv, "_interpret_default", lambda: False)
+    monkeypatch.setattr(pallas_check, "_FALLBACKS_REPORTED", set())
+    assert not pconv.supports(x_shape, w_shape, **kw)
+    assert any(k == "pallas_conv3x3"
+               for k, _ in pallas_check._FALLBACKS_REPORTED)
+    # 1x1 stays routable on the chip
+    assert pconv.supports((256, 56, 56, 256), (64, 256, 1, 1),
+                          dtype=jnp.bfloat16)
+
+
+def test_serving_decode_program_compiles(one_chip):
+    """One paged decode program of the serving engine at GPT-1.3B widths
+    (depth cut to 2 layers to keep the compile to seconds): the engine's
+    own jitted decode step, lowered on described-device shapes with the
+    weights as arguments (never baked in as constants)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
+
+    cfg = GPTConfig(vocab_size=50304, hidden_size=2048, num_layers=2,
+                    num_heads=16, intermediate_size=8192,
+                    max_position_embeddings=2048)
+    paddle.seed(0)
+    model = GPTForCausalLM(cfg)
+    model.astype(paddle.bfloat16)
+    eng = ServingEngine(model, block_size=16, num_blocks=129,
+                        max_batch=8, prefill_buckets=[256],
+                        decode_buckets=[8])
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._decode_fn.params)
+    pages = on_chip(eng.cache.k)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    compiled = eng._decode_fn.jitted.lower(
+        params, i32((8,)), pages, pages,
+        i32((8, eng.max_blocks_per_seq)), i32((8,))).compile()
+    # the 50304x2048 bf16 embedding is an argument of the program, not a
+    # literal inside it
+    assert len(compiled.as_text()) < 5_000_000
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        > 2 * 50304 * 2048

@@ -47,9 +47,8 @@ def rules_of(diags):
 
 
 def jitted(fn, *args):
-    """Dispatch through jit: on legacy jax (0.4.x) a partial-auto
-    shard_map — every production call site lives inside the jitted step —
-    has no eager execution path."""
+    """Dispatch through jit, as every production call site does (they
+    all live inside the jitted step)."""
     return jax.jit(fn)(*args)
 
 

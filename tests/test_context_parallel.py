@@ -14,12 +14,6 @@ from paddle_tpu.distributed.topology import (create_hybrid_mesh,
                                              set_hybrid_mesh)
 from paddle_tpu.ops.flash_attention import reference_attention
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 
 @pytest.fixture(autouse=True)
 def _reset_mesh():

@@ -16,12 +16,6 @@ from paddle_tpu.optimizer import SGD
 
 import pytest  # noqa: E402
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 GROUPS = 8
 
 

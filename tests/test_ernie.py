@@ -9,12 +9,6 @@ import paddle_tpu as paddle
 from paddle_tpu.text.models.ernie import (ErnieForPretraining, ernie_tiny,
                                           ernie_pipeline_descs)
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 
 def test_ernie_pretraining_loss_sane():
     paddle.seed(0)

@@ -146,7 +146,6 @@ class TestDistributedUtils:
     def test_counts_in_trace_rejected(self):
         """Ragged count routing cannot be expressed as an equal-split a2a;
         the traced path must refuse rather than misroute."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:2]), ("ep",))
 
@@ -156,11 +155,10 @@ class TestDistributedUtils:
                 axis_name="ep")
 
         with pytest.raises(NotImplementedError, match="capacity"):
-            shard_map(f, mesh=mesh, in_specs=P("ep"),
-                      out_specs=P("ep"))(jnp.ones((4, 2)))
+            jax.shard_map(f, mesh=mesh, in_specs=P("ep"),
+                          out_specs=P("ep"))(jnp.ones((4, 2)))
 
     def test_global_scatter_in_shard_map(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:2]), ("ep",))
         x = jnp.arange(8.0).reshape(4, 2)
@@ -169,8 +167,8 @@ class TestDistributedUtils:
             return paddle.distributed.utils.global_scatter(
                 xs, None, None, axis_name="ep")
 
-        out = shard_map(f, mesh=mesh, in_specs=P("ep"),
-                        out_specs=P("ep"))(x)
+        out = jax.shard_map(f, mesh=mesh, in_specs=P("ep"),
+                            out_specs=P("ep"))(x)
         # all_to_all over 2 ranks with tiled split: row blocks exchanged
         assert out.shape == x.shape
 

@@ -15,12 +15,6 @@ import sys
 import numpy as np
 import pytest
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "tests", "dist_trainer_script.py")
 

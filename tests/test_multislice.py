@@ -382,21 +382,6 @@ class TestMultisliceTrainStep:
                                     mesh=topo.mesh)
         set_hybrid_mesh(None)
 
-    def test_legacy_jax_gate_on_extra_axes(self, ms_flags):
-        """On legacy jax (no jax.shard_map) a >1 non-data axis cannot
-        compose with the manual {slice, dp} region — construction must
-        say so instead of miscompiling."""
-        if hasattr(jax, "shard_map"):
-            pytest.skip("maintained-API jax composes partial-auto")
-        topo = SliceTopology(2, dp=2, mp=2)
-        core_flags.set_flags({"multislice": "hierarchical"})
-        set_hybrid_mesh(topo.mesh)
-        with pytest.raises(ValueError, match="legacy jax"):
-            make_sharded_train_step(GPTForCausalLM(_gpt_cfg()),
-                                    AdamW(1e-3), _gpt_loss,
-                                    mesh=topo.mesh, fsdp_axis=None)
-        set_hybrid_mesh(None)
-
     def test_plan_declares_and_trace_verifies(self, ms_flags):
         """The composed step passes the S/D plan rules; the recorded hop
         plan carries the three hierarchical stages with the DCN payload

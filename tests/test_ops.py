@@ -29,12 +29,6 @@ import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 import paddle_tpu.tensor as T
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 
 # ---------------------------------------------------------------------------
 # Harness

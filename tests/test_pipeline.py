@@ -21,12 +21,6 @@ from paddle_tpu.distributed.pipeline_schedule import (analyze_pipeline,
 from paddle_tpu.framework.functional import get_params, set_params
 from paddle_tpu.optimizer import AdamW
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 
 @pytest.fixture(autouse=True)
 def _reset_mesh():

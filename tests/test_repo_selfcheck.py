@@ -178,32 +178,6 @@ def test_pass_rules_registered():
     assert all(r.doc for r in pass_check.all_pass_rules())
 
 
-def test_requires_new_jax_marker_matches_known_gap_files():
-    """Selfcheck both directions: every file in the pinned jax-0.4.37
-    API-gap set carries the module-level `requires_new_jax` pytestmark,
-    and no other test file does — so `-m "not requires_new_jax"` is a
-    known-green tier-1 run and a failure outside the set is a real
-    regression."""
-    import glob
-    import re
-
-    from conftest import REQUIRES_NEW_JAX_FILES
-
-    mark_pat = re.compile(
-        r"^pytestmark = pytest\.mark\.requires_new_jax$", re.MULTILINE)
-    tests_dir = os.path.dirname(os.path.abspath(__file__))
-    marked = set()
-    for path in glob.glob(os.path.join(tests_dir, "test_*.py")):
-        with open(path, encoding="utf-8") as f:
-            src = f.read()
-        if mark_pat.search(src):
-            marked.add(os.path.basename(path))
-    assert marked == set(REQUIRES_NEW_JAX_FILES), (
-        f"unmarked known-gap files: "
-        f"{sorted(set(REQUIRES_NEW_JAX_FILES) - marked)}; "
-        f"marked but not in conftest.REQUIRES_NEW_JAX_FILES: "
-        f"{sorted(marked - set(REQUIRES_NEW_JAX_FILES))}")
-
 
 def test_repo_lint_default_coverage_is_wide():
     """The self-lint gate runs over paddle_tpu/ + tools/ + examples/ +

@@ -33,8 +33,7 @@ def analysis_error_mode():
 # ---------------------------------------------------------------------------
 
 def test_j001_f64_promotion_flagged():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         diags = lint_fn(lambda x: x.astype(jnp.float64) * 2.0,
                         jnp.ones((4,), jnp.float32))
     hits = [d for d in diags if d.rule == "J001"]
@@ -224,9 +223,7 @@ def test_j011_negative_flag_off():
 # ---------------------------------------------------------------------------
 
 def _to_host_kind():
-    from paddle_tpu.framework.offload import host_memory_kind
-    from jax._src.sharding_impls import TransferToMemoryKind
-    return TransferToMemoryKind(host_memory_kind())
+    return jax.memory.Space.Host
 
 
 def test_j012_device_put_in_scan_body():
@@ -274,7 +271,11 @@ def test_j012_negative_offload_block_update_clean():
     state = su.init_state(params)
     grads = {k: jnp.ones_like(v) for k, v in params.items()}
     names = offload.group_by_block(list(params))[0][1]
-    st_blk = {n: dict(state["param_states"][n]) for n in names}
+    # the block program sees moments already prefetched to device memory
+    # (StreamingUpdate.update's dispatch order), never host-committed ones
+    dev = su._prefetch(names, params, state["param_states"])
+    st_blk = {n: {**state["param_states"][n], **dev.get(n, {})}
+              for n in names}
     diags = lint_fn(su._block_fn.__wrapped__,
                     {n: params[n] for n in names},
                     {n: grads[n] for n in names},
@@ -437,8 +438,7 @@ def test_packed_flash_entry_enforces_under_error_mode(analysis_error_mode):
 # ---------------------------------------------------------------------------
 
 def test_emit_error_mode_raises(analysis_error_mode):
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         diags = lint_fn(lambda x: x.astype(jnp.float64),
                         jnp.ones((2,), jnp.float32))
     with pytest.raises(GraphLintError) as ei:
@@ -449,8 +449,7 @@ def test_emit_error_mode_raises(analysis_error_mode):
 def test_emit_warn_mode_prints(capsys):
     flags.set_flags({"static_analysis": "warn"})
     try:
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             diags = lint_fn(lambda x: x.astype(jnp.float64),
                             jnp.ones((2,), jnp.float32))
         with pytest.warns(UserWarning):

@@ -9,12 +9,6 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.nn import functional as F
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 
 class TestFunctionalWave4:
     def test_pairwise_distance(self):

@@ -4,6 +4,7 @@ The closing sweep: every name in the reference's top-level ``__all__``
 (python/paddle/__init__.py, 355 names) must exist on paddle_tpu.
 """
 
+import os
 import re
 
 import jax
@@ -13,15 +14,12 @@ import pytest
 
 import paddle_tpu as paddle
 
-# Known jax-0.4.37 API gaps (wave-era tests written against newer
-# jax.numpy / sharding surfaces). File-level set is pinned by
-# tests/test_repo_selfcheck.py; deselect with
-# `-m "not requires_new_jax"` for a known-green run.
-pytestmark = pytest.mark.requires_new_jax
-
 
 def test_full_top_level_export_parity():
-    src = open("/root/reference/python/paddle/__init__.py").read()
+    ref_init = "/root/reference/python/paddle/__init__.py"
+    if not os.path.exists(ref_init):
+        pytest.skip("reference checkout not present")
+    src = open(ref_init).read()
     block = re.search(r"__all__ = \[(.*?)\]", src, re.S).group(1)
     names = re.findall(r"'([^']+)'", block)
     missing = [n for n in names if not hasattr(paddle, n)]

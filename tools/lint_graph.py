@@ -22,8 +22,7 @@ and verifies it with ``analysis/plan_check`` (sharding-flow S-rules +
 donation-lifetime D-rules) + ``analysis/comm_check`` hop plans +
 ``tools/hbm_budget.py`` capacity, AOT-compiles each trace-distinct step
 and runs the compiled-HLO X-rules (``analysis/hlo_check`` — skip with
-``--no-hlo``) — then runs the ten multichip dryrun scenarios (skipped
-with a note on legacy jax, where they cannot trace). ``--hlo`` runs the
+``--no-hlo``) — then runs the ten multichip dryrun scenarios. ``--hlo`` runs the
 X-rules standalone over the representative composed steps plus a seeded
 X001 self-test. ``--passes`` runs the step-compiler pass-pipeline
 verifier standalone: the ordered pass list and per-pass contract hashes,
@@ -393,7 +392,7 @@ _SEV_RANK = {"info": 0, "warning": 1, "error": 2}
 
 # --json report schema. v2 adds schema_version itself plus the
 # rule_index section (family -> {count, ids -> per-id counts}) so CI can
-# diff reports across PRs without re-deriving the rule taxonomy. v3 adds
+# diff reports across PRs without re-deriving the rule families. v3 adds
 # the passes section (ordered pass list, per-pass contract hashes,
 # per-combo composed-plan hash) so CI can diff step-pipeline composition.
 SCHEMA_VERSION = 3
@@ -599,8 +598,8 @@ def _matrix_step_diags(remat: bool, with_hlo: bool = True):
 
 
 def _matrix_sp_pair_diags():
-    """The decomposed TP/SP pair traced fwd+grad on an mp-only mesh (the
-    shape the legacy-jax gate admits), with the comm registry recording —
+    """The decomposed TP/SP pair traced fwd+grad on an mp-only mesh,
+    with the comm registry recording —
     the declared-vs-actual ppermute cross-check (S001/S002) on the real
     decomposed path, plus the C-rule accounting of each recorded spec and
     the production-shape hop plans."""
@@ -725,13 +724,7 @@ def _matrix_conv_diags():
 
 def run_dryruns():
     """The ten multichip dryrun scenarios (__graft_entry__._dryrun_base)
-    in a subprocess on the 8-device virtual mesh. Needs the maintained
-    jax.shard_map API; on legacy jax this reports skipped — the driver
-    environment runs them for real."""
-    if not hasattr(jax, "shard_map"):
-        return {"skipped": "legacy jax (no jax.shard_map); the dryrun "
-                           "scenarios only trace in the driver env",
-                "ok": True, "scenarios": []}
+    in a subprocess on the 8-device virtual mesh."""
     env = dict(os.environ)
     env["_GRAFT_DRYRUN_NO_ESCALATE"] = "1"
     env["JAX_PLATFORMS"] = "cpu"

@@ -42,7 +42,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402
 
@@ -149,7 +148,9 @@ def main(argv=None):
     p.add_argument("--json", action="store_true")
     args = p.parse_args(argv)
 
+    import jax
     import paddle_tpu as paddle
+    from paddle_tpu.core.chip import enable_compile_cache
     from paddle_tpu.observability import metrics, request_timeline
     from paddle_tpu.serving import Request, ServingEngine
     from paddle_tpu.text.models.gpt import GPTForCausalLM, gpt_tiny
@@ -176,6 +177,10 @@ def main(argv=None):
                         priority=int(r.get("priority", 0)))
                 for r in trace]
 
+    # runs on whatever platform the caller's environment gives it; the
+    # report names the device every number came from
+    enable_compile_cache()
+    dev = jax.devices()[0]
     paddle.seed(args.seed)
     cfg = gpt_tiny(vocab_size=args.vocab, hidden_size=args.hidden,
                    num_layers=args.layers, num_heads=args.heads,
@@ -205,6 +210,8 @@ def main(argv=None):
     summary = rt.summary()
     new_tokens = summary["new_tokens"]
     report = {
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "requests": len(requests),
         "new_tokens": new_tokens,
         "wall_s": round(wall_s, 4),
