@@ -21,6 +21,7 @@ __all__ = [
     "get_flags",
     "set_flags",
     "flag",
+    "watch",
     "unknown_env_flags",
 ]
 
@@ -106,6 +107,25 @@ def set_flags(flags_map: Dict[str, Any]) -> None:
                 spec.on_change(_values[name])
 
 
+def watch(name: str, fn: Callable[[Any], None]) -> None:
+    """Call ``fn(value)`` now and after every later ``set_flags`` of
+    ``name`` — for a hot path that keeps its own copy of a flag so as not
+    to look it up by name on every call (``observability.trace``)."""
+    with _lock:
+        if name not in _registry:
+            raise _unknown_flag_error(name)
+        spec = _registry[name]
+        prev = spec.on_change
+
+        def both(value, _prev=prev, _fn=fn):
+            if _prev is not None:
+                _prev(value)
+            _fn(value)
+
+        spec.on_change = both
+        fn(_values[name])
+
+
 def list_flags() -> List[_FlagSpec]:
     with _lock:
         return list(_registry.values())
@@ -162,9 +182,10 @@ define_flag("telemetry", "metrics",
             "disables every host-side signal (bitwise non-intrusive on "
             "step outputs), 'metrics' (default) keeps the always-on "
             "counters/gauges/histograms + step timeline + recompile "
-            "sentinel + HBM watermarks, 'trace' additionally records "
-            "span trees into the in-memory ring for chrome-trace/JSONL "
-            "export.",
+            "sentinel + HBM watermarks + the boundary spans of the "
+            "engine, the train step, compiles and collections in the "
+            "in-memory ring, 'trace' adds the open-span table (hang "
+            "post-mortems) and per-hop comm/* spans.",
             choices=("off", "metrics", "trace"))
 define_flag("flight_recorder", "off",
             "Crash-persistent per-process flight recorder "

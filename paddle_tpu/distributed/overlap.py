@@ -194,18 +194,13 @@ def _account(op: str, spec, *operands) -> None:
 
 def _comm_span(op: str, spec, *operands):
     """A ``comm/<op>`` trace span for an *eager* decomposed dispatch (the
-    hop loop is in-graph; per-call attrs carry the static hop plan).
-    Inside a trace (operands are tracers) there is no dispatch to span."""
+    hop loop is in-graph; per-call attrs carry the static hop plan),
+    under ``FLAGS_telemetry=trace`` only: per-hop detail is for a deep
+    dive, not for the default ring. Inside a trace (operands are tracers)
+    there is no dispatch to span."""
     from ..observability import trace
-    if _is_tracer(*operands):
-        class _Noop:
-            def __enter__(self):
-                return self
-
-            def __exit__(self, *exc):
-                return False
-
-        return _Noop()
+    if _is_tracer(*operands) or not trace.tracing_active():
+        return trace.NOOP
     return trace.span(f"comm/{op}", hops=spec.hops,
                       bytes_per_hop=spec.bytes_per_hop,
                       axis_size=spec.axis_size)

@@ -412,7 +412,6 @@ class TrainStep:
 
     def _step_inner(self, batch, tm, index: Optional[int] = None
                     ) -> jax.Array:
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         ndim_cache: Dict[int, NamedSharding] = {}
 
         def place(x):
@@ -424,16 +423,8 @@ class TrainStep:
             return jax.device_put(x, sh)
 
         with tm.phase("h2d"):
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
             batch = jax.tree_util.tree_map(place, batch)
-        if index is None:
-            self._step_count += 1
-        else:
-            self._step_count = int(index)
-        # the flight recorder's step commits carry this global applied
-        # index (checkpointed, so it spans incarnations), not just the
-        # timeline's process-local step counter
-        tm.note("index", self._step_count)
-        key = jax.random.fold_in(self._base_key, self._step_count)
         # Trace-time consumers (sharding constraints, CP attention) resolve
         # the mesh via get_hybrid_mesh(); install THIS step's mesh for the
         # call only, so concurrent TrainSteps on different meshes don't
@@ -442,17 +433,29 @@ class TrainStep:
         prev_mesh = get_hybrid_mesh()
         set_hybrid_mesh(self.mesh)
         try:
-            self._maybe_lint(batch, lr, key)
-            # Recompile sentinel: params/opt-state signatures are fixed at
-            # construction — churn can only come from the batch (and lr
-            # dtype), so only those are fingerprinted. The dispatch that
-            # first sees a signature is timed as "compile", later ones as
-            # "device".
-            dispatch_phase = "device"
-            if tm.enabled:
-                dispatch_phase = tm.observe_dispatch(
-                    ("sharded.TrainStep", id(self)), (batch, lr),
-                    where="sharded.TrainStep")
+            # everything between placement and dispatch that is not the
+            # dispatch: step index, key fold, lint, recompile sentinel
+            with tm.phase("checks"):
+                if index is None:
+                    self._step_count += 1
+                else:
+                    self._step_count = int(index)
+                # the flight recorder's step commits carry this global
+                # applied index (checkpointed, so it spans incarnations),
+                # not just the timeline's process-local step counter
+                tm.note("index", self._step_count)
+                key = jax.random.fold_in(self._base_key, self._step_count)
+                self._maybe_lint(batch, lr, key)
+                # Recompile sentinel: params/opt-state signatures are fixed
+                # at construction — churn can only come from the batch (and
+                # lr dtype), so only those are fingerprinted. The dispatch
+                # that first sees a signature is timed as "compile", later
+                # ones as "device".
+                dispatch_phase = "device"
+                if tm.enabled:
+                    dispatch_phase = tm.observe_dispatch(
+                        ("sharded.TrainStep", id(self)), (batch, lr),
+                        where="sharded.TrainStep")
             if self._step_kind == "offload":
                 with tm.phase(dispatch_phase):
                     loss, grads, self.buffers = self._compiled(

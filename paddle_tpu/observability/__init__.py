@@ -10,8 +10,9 @@ low-overhead measurement layer that is always there (gated by
   Prometheus-text and JSON exposition; absorbs the old
   ``profiler.monitor`` flat stat registry (which now forwards here).
 - :mod:`.trace` — thread-safe nestable ``span()`` context managers
-  buffering into an in-memory ring, exported as chrome-trace JSON or
-  JSONL (``FLAGS_telemetry=trace`` only).
+  buffering into an in-memory ring (records with ``id``/``parent`` on
+  ``perf_counter_ns``; on unless ``FLAGS_telemetry=off``), exported as
+  chrome-trace JSON or JSONL; ``trace`` mode adds the open-span table.
 - :mod:`.request_timeline` — the serving tier's per-request phase
   accounting (queue/prefill/decode/detokenize, exact-value p50/p99),
   feeding the ``serving.*`` metric families.

@@ -15,17 +15,18 @@ decomposes into four phases the operator actually acts on:
 Each request that reaches a terminal state is one record in a bounded
 ring (JSONL-exportable next to the step timeline — ``tools/trace_view.py``
 passes ``kind: "request"`` records through untouched) and feeds the
-``serving.*`` metric families in :mod:`.metrics`:
-``serving.request_latency_ms`` / ``serving.ttft_ms`` histograms,
-per-phase ``serving.phase_ms``, and the ``serving.requests_completed`` /
-``serving.tokens_generated`` counters. Records carry an ``outcome``
-(``ok``, or the resilience endings ``rejected``/``failed``/``expired``/
-``shed`` — see RESILIENCE.md); only ok records feed the latency
-families, and deadline-carrying records stamp ``deadline_met`` — the
-input to :meth:`RequestTimeline.summary`'s ``slo_attainment_pct`` and
-``shed_rate``. p50/p99 come from the exact recorded latencies, not
-histogram buckets — tail latency is the headline serving metric and
-deserves better than log2-bucket resolution.
+``serving.requests_completed`` / ``serving.tokens_generated`` counters
+(the live fleet plane's goodput and tokens/s). Records carry an
+``outcome`` (``ok``, or the resilience endings ``rejected``/``failed``/
+``expired``/``shed`` — see RESILIENCE.md); only ok records feed the
+counters and the latency percentiles, and deadline-carrying records
+stamp ``deadline_met`` — the input to :meth:`RequestTimeline.summary`'s
+``slo_attainment_pct`` and ``shed_rate``. p50/p99 come from the exact
+recorded latencies: no histogram family doubles them (ISSUE 26 removed
+``serving.request_latency_ms``, ``serving.ttft_ms`` and
+``serving.phase_ms``, which nothing read). A record of the serving engine
+also carries ``t_submit_ns`` and ``token_t_ns``: ``perf_counter_ns`` of the
+submission and of each output token's commit.
 """
 
 from __future__ import annotations
@@ -71,11 +72,6 @@ class RequestTimeline:
             "serving.requests_completed", "requests fully served").labels()
         self._tokens = metrics.counter(
             "serving.tokens_generated", "new tokens emitted").labels()
-        self._lat = metrics.histogram(
-            "serving.request_latency_ms",
-            "submit-to-last-token wall time per request (ms)").labels()
-        self._ttft = metrics.histogram(
-            "serving.ttft_ms", "submit-to-first-token wall time (ms)").labels()
 
     def record(self, *, rid: str, prompt_tokens: int, new_tokens: int,
                phases_ms: Dict[str, float], total_ms: float,
@@ -88,7 +84,7 @@ class RequestTimeline:
         ``outcome`` is ``ok`` for a served request or one of the
         resilience endings (``rejected`` / ``failed`` / ``expired`` /
         ``shed``); non-ok records carry ``error`` and are kept OUT of the
-        latency/TTFT histograms and percentiles — tail latency describes
+        latency/TTFT percentiles and the counters — tail latency describes
         answers, not refusals. ``deadline_ms`` stamps the record with
         ``deadline_met`` (the SLO-attainment input: an ok outcome whose
         total latency fit the deadline)."""
@@ -124,14 +120,6 @@ class RequestTimeline:
         if outcome == "ok":
             self._completed.inc()
             self._tokens.inc(int(new_tokens))
-            self._lat.observe(float(total_ms))
-            if ttft_ms is not None:
-                self._ttft.observe(float(ttft_ms))
-            for name, ms in phases_ms.items():
-                metrics.histogram(
-                    "serving.phase_ms",
-                    "wall time per request phase (ms)").labels(
-                        phase=name).observe(float(ms))
         return rec
 
     # -- inspection / export -------------------------------------------------

@@ -10,9 +10,11 @@ pipeline_schedule``, ``distributed.overlap`` (dispatch-level bucketed
 gradient reductions), ``io.dataloader`` and the ``hapi`` fit loop report
 into the phases (``data``, ``h2d``, ``compile``, ``device``, ``comm``,
 ``offload_in``, ``offload_out``, ``callbacks``); each completed step is a
-record in a bounded ring, durations also feed the log-bucket histograms in
-:mod:`.metrics`, and under ``FLAGS_telemetry=trace`` every phase opens a
-:mod:`.trace` span. ``tools/trace_view.py`` aggregates the JSONL export.
+record in a bounded ring (with the step's ``t0_ns`` on
+``time.perf_counter_ns``), and every step and phase is a :mod:`.trace` span
+(``step``, ``step/<phase>``, ``step/end``) whose exit is where the duration
+is taken: it feeds the record and the log-bucket histograms in
+:mod:`.metrics`. ``tools/trace_view.py`` aggregates the JSONL export.
 
 **RecompileSentinel** — the silent step-time killer on XLA is shape churn:
 a jitted callable fed a new (shape, dtype, sharding) signature recompiles,
@@ -28,24 +30,31 @@ shape/dtype diff between the two most recent signatures — the reference's
 against the static plan from ``tools/hbm_budget.py`` via
 :meth:`StepTimeline.check_plan` (rule O002 when measured peak exceeds the
 plan). On CPU ``memory_stats()`` is None and sampling degrades to a no-op.
+
+**Host hooks** — :func:`install_host_hooks` (called once when this module is
+imported) gives the span ring the two things that stall a host thread from
+outside the program's own code: every compile ``jax.monitoring`` reports
+becomes a ``jit/trace``, ``jit/lower`` or ``jit/compile`` span (and feeds the
+``jit.compiles`` / ``jit.cache_hits`` counters — the program's own compile
+counter), and garbage collections become ``host/gc`` spans.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
-import time
 from collections import deque
+from time import perf_counter_ns
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from . import flight_recorder, metrics, trace
-from .trace import telemetry_mode
 
 __all__ = ["StepTimeline", "RecompileSentinel", "current", "reset_default",
            "fingerprint", "fingerprint_diff", "instrument_jitted",
-           "PHASES", "GB"]
+           "install_host_hooks", "PHASES", "GB", "GC_GEN0_MIN_NS"]
 
-PHASES = ("data", "h2d", "compile", "device", "comm",
+PHASES = ("data", "h2d", "checks", "compile", "device", "comm",
           "ckpt_save", "ckpt_restore", "offload_in",
           "offload_out", "callbacks",
           # training-health tier (fault/health.py): the SDC canary's
@@ -194,8 +203,6 @@ class RecompileSentinel:
                  "full XLA compile")
         with self._mu:   # reset() swaps the list under the same lock
             self.diagnostics.append(d)
-        metrics.counter("telemetry.recompile_churn",
-                        "recompile-sentinel firings").inc()
         flight_recorder.emit("diag", rule=d.rule, where=d.where,
                              message=d.message)
         try:
@@ -217,37 +224,21 @@ class RecompileSentinel:
 # Step timeline
 # ---------------------------------------------------------------------------
 
-class _Noop:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _Noop()
-
-
 class _Phase:
-    __slots__ = ("_tl", "name", "_span", "_t0")
+    __slots__ = ("_tl", "name", "_span")
 
     def __init__(self, tl: "StepTimeline", name: str, attrs: Dict[str, Any]):
         self._tl = tl
         self.name = name
         self._span = trace.span(f"step/{name}", **attrs)
-        self._t0 = 0
 
     def __enter__(self):
         self._span.__enter__()
-        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        dur_ms = (time.perf_counter_ns() - self._t0) / 1e6
         self._span.__exit__(*exc)
-        self._tl._phase_done(self.name, dur_ms)
+        self._tl._phase_done(self.name, self._span.dur_ns / 1e6)
         return False
 
 
@@ -259,14 +250,17 @@ class _Step:
         self._span = None
 
     def __enter__(self):
-        idx = self._tl._step_begin()
-        self._span = trace.span("step", step=idx)
+        self._span = trace.span("step", step=self._tl._step_begin())
         self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
+        # the step's wall time ends where its own bookkeeping starts: the
+        # record, the HBM sample and the recorder commit are ``step/end``,
+        # inside the root, so the root's self time is what no phase names
+        with trace.span("step/end") as end:
+            self._tl._step_end(self._span.t0_ns, end.t0_ns)
         self._span.__exit__(*exc)
-        self._tl._step_end()
         return False
 
 
@@ -284,7 +278,6 @@ class StepTimeline:
         self._mu = threading.RLock()
         self._steps: "deque[Dict[str, Any]]" = deque(maxlen=capacity)
         self._cur: Optional[Dict[str, Any]] = None
-        self._cur_t0 = 0
         self._step_idx = 0
         self._device = device
         self.sentinel = RecompileSentinel(recompile_threshold)
@@ -294,23 +287,23 @@ class StepTimeline:
         # hot-path metric children resolved once (registry + label lookups
         # off the per-phase path)
         self._phase_hists: Dict[str, Any] = {}
-        self._step_hist = metrics.histogram(
-            "telemetry.step_ms", "wall time per step (ms)").labels()
-        self._step_counter = metrics.counter(
-            "telemetry.steps", "completed training steps").labels()
+        self._hbm_live = metrics.gauge(
+            "hbm.bytes_in_use", "live device bytes").labels()
+        self._hbm_peak = metrics.gauge(
+            "hbm.peak_bytes_in_use", "runtime peak device bytes").labels()
 
     # -- gating --------------------------------------------------------------
 
     @property
     def enabled(self) -> bool:
-        return telemetry_mode() != "off"
+        return trace.enabled()
 
     # -- step / phase context managers --------------------------------------
 
     def step(self):
         """``with timeline.step(): ...`` around one training step."""
         if not self.enabled:
-            return _NOOP
+            return trace.NOOP
         return _Step(self)
 
     def phase(self, name: str, **attrs):
@@ -318,7 +311,7 @@ class StepTimeline:
         the current step record (or stand alone between steps) and feed
         the ``telemetry.phase_ms`` histogram."""
         if not self.enabled:
-            return _NOOP
+            return trace.NOOP
         return _Phase(self, name, attrs)
 
     def note(self, key: str, value: Any) -> None:
@@ -336,24 +329,22 @@ class StepTimeline:
         with self._mu:
             self._step_idx += 1
             self._cur = {"kind": "step", "step": self._step_idx, "phases": {}}
-            self._cur_t0 = time.perf_counter_ns()
             return self._step_idx
 
-    def _step_end(self) -> None:
+    def _step_end(self, t0_ns: int, end_ns: int) -> None:
         hbm = self.sample_hbm()
         with self._mu:
-            cur, t0 = self._cur, self._cur_t0
+            cur = self._cur
             self._cur = None
         if cur is None:
             return
-        cur["total_ms"] = (time.perf_counter_ns() - t0) / 1e6
+        cur["t0_ns"] = t0_ns
+        cur["total_ms"] = (end_ns - t0_ns) / 1e6
         if hbm is not None:
             cur["hbm_live_gb"] = round(hbm["bytes_in_use"] / GB, 4)
             cur["hbm_peak_gb"] = round(hbm["peak_bytes_in_use"] / GB, 4)
         with self._mu:
             self._steps.append(cur)
-        self._step_counter.inc()
-        self._step_hist.observe(cur["total_ms"])
         # black-box commit: the step's phase totals land in the
         # crash-persistent ring the moment the record returns, so a
         # SIGKILL in the very next instruction keeps this step
@@ -421,10 +412,8 @@ class StepTimeline:
         with self._mu:
             self.hbm_live_bytes = live
             self.hbm_peak_bytes = max(self.hbm_peak_bytes, peak, live)
-        metrics.gauge("hbm.bytes_in_use", "live device bytes").set(live)
-        metrics.gauge("hbm.peak_bytes_in_use",
-                      "runtime peak device bytes").set(
-                          max(self.hbm_peak_bytes, peak))
+        self._hbm_live.set(live)
+        self._hbm_peak.set(max(self.hbm_peak_bytes, peak))
         return {"bytes_in_use": live, "peak_bytes_in_use": peak}
 
     def check_plan(self, plan: Dict[str, Any], slack: float = 0.05):
@@ -543,6 +532,93 @@ def reset_default() -> StepTimeline:
     with _default_mu:
         _default = StepTimeline()
         return _default
+
+
+# ---------------------------------------------------------------------------
+# Host hooks: compiles and garbage collections as spans
+# ---------------------------------------------------------------------------
+
+_JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # the backend compile, or the read of it from the persistent cache
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_JIT_CACHE_HIT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: A generation-0 collection shorter than this leaves no span: tracing a
+#: model makes tens of thousands of them, 10-50 us each, and they would
+#: push the run's other spans out of the ring. Generations 1 and 2, and
+#: any longer pass, always leave one.
+GC_GEN0_MIN_NS = 200_000
+
+#: A ``jit/trace`` event shorter than this leaves no span, for the same
+#: reason: tracing a 12-layer step reports some 18,000 of them, nearly all
+#: the small functions traced inside a larger trace, whose span covers them.
+JIT_TRACE_MIN_NS = 1_000_000
+
+_hooks_mu = threading.Lock()
+_hooks_on = False
+_gc_open: Optional[Tuple[int, Any]] = None     # (t0_ns, annotation)
+
+
+def _on_jit_event(event: str, duration: float, **kw: Any) -> None:
+    if not trace.enabled():
+        return
+    stage = _JIT_STAGES.get(event)
+    if stage is None:
+        if event == _JIT_CACHE_HIT:
+            metrics.counter(
+                "jit.cache_hits",
+                "programs read from the persistent compile cache").inc()
+        return
+    dur_ns = int(duration * 1e9)
+    if stage == "trace" and dur_ns < JIT_TRACE_MIN_NS:
+        return
+    fn = kw.get("fun_name")
+    trace.record(f"jit/{stage}", perf_counter_ns() - dur_ns, dur_ns,
+                 event=event, **({"fn": str(fn)} if fn else {}))
+    if stage == "compile":
+        metrics.counter("jit.compiles",
+                        "programs compiled or read from the compile "
+                        "cache").inc()
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    global _gc_open
+    if phase == "start":
+        if trace.enabled():
+            ann = trace.annotate("host/gc")
+            _gc_open = (perf_counter_ns(), ann)
+        return
+    opened, _gc_open = _gc_open, None
+    if opened is None:
+        return
+    t0, ann = opened
+    dur = perf_counter_ns() - t0
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    if info.get("generation", 0) > 0 or dur >= GC_GEN0_MIN_NS:
+        trace.record("host/gc", t0, dur,
+                     generation=info.get("generation"),
+                     collected=info.get("collected"))
+
+
+def install_host_hooks() -> None:
+    """Register the ``jax.monitoring`` duration listener and the
+    ``gc.callbacks`` hook, once a process. Both return at once under
+    ``FLAGS_telemetry=off``."""
+    global _hooks_on
+    with _hooks_mu:
+        if _hooks_on:
+            return
+        _hooks_on = True
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(_on_jit_event)
+    gc.callbacks.append(_on_gc)
+
+
+install_host_hooks()
 
 
 # ---------------------------------------------------------------------------

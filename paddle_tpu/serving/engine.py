@@ -63,6 +63,7 @@ from __future__ import annotations
 
 import math
 import time
+import types
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence as Seq, Union
 
@@ -74,7 +75,7 @@ from ..core import flags as _flags
 from ..fault.injection import fire as _fault_fire
 from ..framework.functional import _swapped_state, get_params
 from ..observability import live as fleet_live
-from ..observability import metrics, request_timeline
+from ..observability import metrics, request_timeline, trace
 from ..observability.request_timeline import percentile
 from ..observability.step_monitor import RecompileSentinel
 from ..ops.flash_attention import flash_attention, single_query_attention
@@ -92,6 +93,63 @@ __all__ = ["ServingEngine"]
 
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _commit_stamps(seq: Sequence) -> Dict[str, Any]:
+    """What a terminal request record gains from the commit stamps: nothing
+    for a request that has none (``FLAGS_telemetry=off``, or no token)."""
+    if not seq.token_t_ns:
+        return {}
+    return {"t_submit_ns": int(seq.t_submit * 1e9),
+            "token_t_ns": list(seq.token_t_ns)}
+
+
+def _meters() -> types.SimpleNamespace:
+    """The metric children the engine touches on every iteration, looked up
+    in the registry once (the rare endings keep their by-name look-ups)."""
+    kv = metrics.counter(
+        "serving.kv_tokens",
+        "KV positions a decode dispatch needed (kind=needed: the rows' "
+        "contexts) and was handed (kind=gathered: width x table x block)")
+    pf = metrics.counter(
+        "serving.prefill_tokens",
+        "prompt tokens prefilled (kind=real) and the bucket lengths they "
+        "were padded to (kind=bucket)")
+    return types.SimpleNamespace(
+        kv_needed=kv.labels(kind="needed"),
+        kv_gathered=kv.labels(kind="gathered"),
+        prefill_real=pf.labels(kind="real"),
+        prefill_bucket=pf.labels(kind="bucket"),
+        queue_depth=metrics.gauge(
+            "serving.queue_depth",
+            "requests waiting for admission").labels(),
+        running=metrics.gauge(
+            "serving.running",
+            "sequences resident in the decode batch").labels(),
+        free_block_frac=metrics.gauge(
+            "serving.free_block_frac",
+            "free fraction of the usable KV pool (the shed policy's "
+            "admission signal)").labels(),
+        # the family, not a child: the series appears with its first
+        # reading, which only a policy or an armed exporter asks for
+        decode_p99_ms=metrics.gauge(
+            "serving.decode_p99_ms",
+            "sliding-window decode-iteration p99 (ms, the shed policy's "
+            "latency signal)"),
+        decode_step_ms=metrics.histogram(
+            "serving.decode_step_ms",
+            "decode iteration wall time (ms)").labels())
+
+
+def _account(t0_ns: int, end_ns: int, phase: str, seqs) -> None:
+    """Hand a stretch between two span stamps to the phase account of each
+    sequence it served. Under ``FLAGS_telemetry=off`` the spans measured
+    nothing (``end_ns`` 0) and nothing is fed."""
+    if not end_ns:
+        return
+    dur_s = (end_ns - t0_ns) * 1e-9
+    for seq in seqs:
+        seq.add_phase(phase, dur_s)
 
 
 def _multi_query_attention(q, k, v, pos):
@@ -267,7 +325,7 @@ class ServingEngine:
             if self.prefix_on else None
         self.sched = FCFSScheduler(max_batch, max_waiting=max_waiting)
         self._seqs: Dict[str, Sequence] = {}
-        self._t0 = time.perf_counter()
+        self._m = _meters()
         #: scheduler iterations run — the "step index" the live fleet
         #: exporter publishes for a serving worker
         self.n_iterations = 0
@@ -288,6 +346,12 @@ class ServingEngine:
         self._degraded_width: Optional[int] = None
         self._decode_ms: deque = deque(
             maxlen=shed_policy.window if shed_policy else 64)
+        self._p99: Optional[float] = None   # of _decode_ms, as of _p99_at
+        self._p99_at = -1
+        # a shed policy ACTS on the decode iteration's duration, so its two
+        # spans measure in every telemetry mode
+        self._acted_span = trace.timed_span if shed_policy is not None \
+            else trace.span
         if journal is not None:
             journal.launch()
 
@@ -333,6 +397,7 @@ class ServingEngine:
                 len(self.prefill_buckets))
         self.plan = self._build_plan()
         self._linted = False
+        self._gauges()      # a fresh engine's pool reads free, not 0
 
     # ------------------------------------------------------------------
     # The bucketed executables
@@ -714,33 +779,36 @@ class ServingEngine:
         over capacity. Malformed requests (a total that can never fit
         ``max_seq_len``) still raise — that is a client contract error,
         not transient overload."""
-        total = request.prompt_ids.size + request.max_new_tokens
-        if total > self.max_seq_len:
-            raise ValueError(
-                f"request {request.rid!r}: prompt {request.prompt_ids.size} "
-                f"+ max_new_tokens {request.max_new_tokens} exceeds "
-                f"max_seq_len {self.max_seq_len}")
-        # the prompt must fit a registered prefill bucket on its own
-        self.prefill_buckets.fit(request.prompt_ids.size)
-        metrics.counter("serving.requests", "requests submitted").inc()
-        if not self.sched.can_accept():
-            return self._reject(
-                request, "queue_full",
-                f"waiting queue at max_waiting={self.sched.max_waiting}")
-        if (self.max_spilled_bytes is not None
-                and self._spilled_bytes > self.max_spilled_bytes):
-            return self._reject(
-                request, "spill_budget",
-                f"host spill {self._spilled_bytes}B over budget "
-                f"{self.max_spilled_bytes}B")
-        seq = Sequence(request)
-        seq.t_submit = time.perf_counter()
-        self._seqs[request.rid] = seq
-        if self.journal is not None:
-            self.journal.submitted(request)
-        self.sched.submit(seq)
-        self._gauges()
-        return seq
+        n_prompt = int(request.prompt_ids.size)
+        with trace.span("serve/submit", rid=request.rid,
+                        prompt_len=n_prompt):
+            total = n_prompt + request.max_new_tokens
+            if total > self.max_seq_len:
+                raise ValueError(
+                    f"request {request.rid!r}: prompt "
+                    f"{request.prompt_ids.size} + max_new_tokens "
+                    f"{request.max_new_tokens} exceeds max_seq_len "
+                    f"{self.max_seq_len}")
+            # the prompt must fit a registered prefill bucket on its own
+            self.prefill_buckets.fit(request.prompt_ids.size)
+            if not self.sched.can_accept():
+                return self._reject(
+                    request, "queue_full",
+                    f"waiting queue at max_waiting={self.sched.max_waiting}")
+            if (self.max_spilled_bytes is not None
+                    and self._spilled_bytes > self.max_spilled_bytes):
+                return self._reject(
+                    request, "spill_budget",
+                    f"host spill {self._spilled_bytes}B over budget "
+                    f"{self.max_spilled_bytes}B")
+            seq = Sequence(request)
+            seq.t_submit = time.perf_counter()
+            self._seqs[request.rid] = seq
+            if self.journal is not None:
+                self.journal.submitted(request)
+            self.sched.submit(seq)
+            self._gauges()
+            return seq
 
     def _reject(self, request: Request, reason: str,
                 detail: str) -> Rejected:
@@ -759,28 +827,30 @@ class ServingEngine:
         return rej
 
     def _gauges(self) -> None:
-        metrics.gauge("serving.queue_depth",
-                      "requests waiting for admission").set(
-                          len(self.sched.waiting))
-        metrics.gauge("serving.running",
-                      "sequences resident in the decode batch").set(
-                          len(self.sched.running))
+        m = self._m
+        m.queue_depth.set(len(self.sched.waiting))
+        m.running.set(len(self.sched.running))
         used = self.cache.allocator.n_used
         self.peak_blocks_used = max(self.peak_blocks_used, used)
         live = used - (self.prefix.n_idle_device_blocks()
                        if self.prefix is not None else 0)
         self.peak_live_blocks = max(self.peak_live_blocks, live)
         usable = self.cache.num_blocks - 1
-        metrics.gauge("serving.free_block_frac",
-                      "free fraction of the usable KV pool (the shed "
-                      "policy's admission signal)").set(
-                          self.cache.allocator.n_free / usable
-                          if usable else 0.0)
-        p99 = percentile(list(self._decode_ms), 99)
-        if p99 is not None:
-            metrics.gauge("serving.decode_p99_ms",
-                          "sliding-window decode-iteration p99 (ms, "
-                          "the shed policy's latency signal)").set(p99)
+        m.free_block_frac.set(self.cache.allocator.n_free / usable
+                              if usable else 0.0)
+        if fleet_live.enabled():     # the armed exporter publishes it
+            self._decode_p99()
+
+    def _decode_p99(self) -> Optional[float]:
+        """p99 of the decode-time window, sorted at most once an
+        iteration and only for a reader: the shed policy, or the armed
+        fleet exporter (through the ``serving.decode_p99_ms`` gauge)."""
+        if self._p99_at != self.n_iterations:
+            self._p99_at = self.n_iterations
+            self._p99 = percentile(list(self._decode_ms), 99)
+            if self._p99 is not None:
+                self._m.decode_p99_ms.set(self._p99)
+        return self._p99
 
     def reset_peaks(self) -> None:
         """Restart the peak-blocks watermarks (bench arms measure the
@@ -823,7 +893,8 @@ class ServingEngine:
                      if seq.t_first_token is not None else None),
             preemptions=seq.preemptions, outcome=outcome, error=reason,
             deadline_ms=(None if req.deadline_s is None
-                         else req.deadline_s * 1e3))
+                         else req.deadline_s * 1e3),
+            **_commit_stamps(seq))
         self._gauges()
 
     def _diagnose_failure(self, seq: Sequence, reason: str) -> None:
@@ -869,8 +940,7 @@ class ServingEngine:
             return
         usable = self.cache.num_blocks - 1
         free_frac = self.cache.allocator.n_free / usable if usable else 0.0
-        p99 = percentile(list(self._decode_ms), 99)
-        why = pol.overloaded(free_frac, p99)
+        why = pol.overloaded(free_frac, self._decode_p99())
         if why is None:
             self.mode = "healthy"
             self._degraded_width = None
@@ -1031,42 +1101,53 @@ class ServingEngine:
         return True
 
     def _prefill(self, seq: Sequence, block_ids: List[int]) -> None:
-        now = time.perf_counter()
-        seq.add_phase("queue", now - seq.t_enqueue)
+        seq.add_phase("queue", time.perf_counter() - seq.t_enqueue)
         bucket = self.prefill_buckets.fit(seq.prompt_len)
-        nb_bucket = bucket // self.block_size
-        ids = pad_axis(seq.request.prompt_ids[None, :], 1, bucket)
-        btab = np.full((nb_bucket,), NULL_BLOCK, np.int32)
-        btab[:len(block_ids)] = block_ids
-        args = (jnp.asarray(ids, jnp.int32), self.cache.k, self.cache.v,
-                jnp.asarray(btab), jnp.asarray(seq.prompt_len, jnp.int32))
-        self._maybe_lint()
-        self._assert_cow(block_ids)
-        self._sent_prefill.observe_tree(
-            "serving.prefill", (args[0], args[3], args[4]),
-            donate=(1, 2), where="serving.prefill")
-        tok, k2, v2 = self._prefill_fn(*args)
-        tok = int(tok)  # host sync: honest prefill timing
-        self.cache.swap(k2, v2)
-        seq.block_ids = list(block_ids)
-        seq.block_log.extend(block_ids)
-        seq.ctx_len = seq.prompt_len
-        seq.prefill_pos = seq.prompt_len
+        with trace.span("serve/prefill", rid=seq.rid,
+                        prompt_len=seq.prompt_len, bucket=bucket) as sp:
+            with trace.span("serve/prefill/build"):
+                nb_bucket = bucket // self.block_size
+                ids = pad_axis(seq.request.prompt_ids[None, :], 1, bucket)
+                btab = np.full((nb_bucket,), NULL_BLOCK, np.int32)
+                btab[:len(block_ids)] = block_ids
+                args = (jnp.asarray(ids, jnp.int32), self.cache.k,
+                        self.cache.v, jnp.asarray(btab),
+                        jnp.asarray(seq.prompt_len, jnp.int32))
+                self._maybe_lint()
+                self._assert_cow(block_ids)
+                self._sent_prefill.observe_tree(
+                    "serving.prefill", (args[0], args[3], args[4]),
+                    donate=(1, 2), where="serving.prefill")
+            with trace.span("serve/prefill/launch"):
+                tok, k2, v2 = self._prefill_fn(*args)
+            with trace.span("serve/prefill/wait") as wait:
+                tok = int(tok)  # host sync: the first token exists now
+            _account(sp.t0_ns, wait.end_ns, "prefill", (seq,))
+            with trace.span("serve/prefill/commit"):
+                self.cache.swap(k2, v2)
+                seq.block_ids = list(block_ids)
+                seq.block_log.extend(block_ids)
+                seq.ctx_len = seq.prompt_len
+                seq.prefill_pos = seq.prompt_len
+                self._commit_first_token(seq, tok)
+                self._m.prefill_real.inc(seq.prompt_len)
+                self._m.prefill_bucket.inc(bucket)
+                self._mirror_draft_prefill(seq)
+                if self.prefix is not None:
+                    new_nodes = self.prefix.insert(
+                        seq.request.prompt_ids, seq.block_ids,
+                        seq.prompt_len, have=len(seq.prefix_nodes))
+                    seq.prefix_nodes += new_nodes
+                    seq.n_shared_blocks = len(seq.prefix_nodes)
+                if seq.is_finished_by(tok):
+                    self._finish(seq)
+
+    @staticmethod
+    def _commit_first_token(seq: Sequence, tok: int) -> None:
         seq.out_tokens.append(tok)
         seq.t_first_token = time.perf_counter()
-        dur = seq.t_first_token - now
-        seq.add_phase("prefill", dur)
-        metrics.histogram("serving.prefill_ms",
-                          "prefill step wall time (ms)").observe(dur * 1e3)
-        self._mirror_draft_prefill(seq)
-        if self.prefix is not None:
-            new_nodes = self.prefix.insert(
-                seq.request.prompt_ids, seq.block_ids, seq.prompt_len,
-                have=len(seq.prefix_nodes))
-            seq.prefix_nodes += new_nodes
-            seq.n_shared_blocks = len(seq.prefix_nodes)
-        if seq.is_finished_by(tok):
-            self._finish(seq)
+        if trace.enabled():
+            seq.token_t_ns.append(int(seq.t_first_token * 1e9))
 
     def _chunk_prefill(self, seq: Sequence, span: int) -> None:
         """Prefill ``span`` prompt tokens through the ``extend``
@@ -1074,52 +1155,57 @@ class ServingEngine:
         the prefix-hit suffix path and the chunked-prefill path. The
         final span commits the first generated token; every completed
         full block is inserted into the prefix tree as it fills."""
-        now = time.perf_counter()
         start = seq.prefill_pos
         L = self.prefill_buckets.fit(span)
-        toks = pad_axis(
-            seq.request.prompt_ids[None, start:start + span], 1, L)
-        table = np.full((1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
-        table[0, :len(seq.block_ids)] = seq.block_ids
-        args = (jnp.asarray(toks, jnp.int32), self.cache.k, self.cache.v,
-                jnp.asarray(table), jnp.asarray([start], jnp.int32),
-                jnp.asarray([span], jnp.int32))
-        self._maybe_lint()
-        self._assert_cow(self._write_span_ids(seq, start, span))
-        self._sent_chunk.observe_tree(
-            "serving.extend", (args[0], args[3], args[4], args[5]),
-            donate=(1, 2), where="serving.extend")
-        out, k2, v2 = self._chunk_fn(*args)
-        out = np.asarray(out)   # host sync: honest chunk timing
-        self.cache.swap(k2, v2)
-        if self._draft_extend_fn is not None:
-            dargs = (args[0], self._draft_cache.k, self._draft_cache.v,
-                     args[3], args[4], args[5])
-            _, dk, dv = self._draft_extend_fn(*dargs)
-            self._draft_cache.swap(dk, dv)
-            seq.draft_ctx = start + span
-        seq.prefill_pos = start + span
-        seq.ctx_len = seq.prefill_pos
-        if self.prefix is not None:
-            new_nodes = self.prefix.insert(
-                seq.request.prompt_ids, seq.block_ids, seq.prefill_pos,
-                have=len(seq.prefix_nodes))
-            seq.prefix_nodes += new_nodes
-            seq.n_shared_blocks = len(seq.prefix_nodes)
-        dur = time.perf_counter() - now
-        seq.add_phase("chunk_prefill", dur)
-        if self.chunk_tokens:
-            metrics.counter(
-                "serving.chunked_prefill_iterations",
-                "prefill chunks interleaved with decode").inc()
-        metrics.histogram("serving.prefill_ms",
-                          "prefill step wall time (ms)").observe(dur * 1e3)
-        if seq.prefill_pos >= seq.prompt_len:
-            tok = int(out[0])       # last_only: [B] of last-real argmax
-            seq.out_tokens.append(tok)
-            seq.t_first_token = time.perf_counter()
-            if seq.is_finished_by(tok):
-                self._finish(seq)
+        with trace.span("serve/extend", rid=seq.rid, prompt_len=span,
+                        bucket=L) as sp:
+            with trace.span("serve/prefill/build"):
+                toks = pad_axis(
+                    seq.request.prompt_ids[None, start:start + span], 1, L)
+                table = np.full((1, self.max_blocks_per_seq), NULL_BLOCK,
+                                np.int32)
+                table[0, :len(seq.block_ids)] = seq.block_ids
+                args = (jnp.asarray(toks, jnp.int32), self.cache.k,
+                        self.cache.v, jnp.asarray(table),
+                        jnp.asarray([start], jnp.int32),
+                        jnp.asarray([span], jnp.int32))
+                self._maybe_lint()
+                self._assert_cow(self._write_span_ids(seq, start, span))
+                self._sent_chunk.observe_tree(
+                    "serving.extend", (args[0], args[3], args[4], args[5]),
+                    donate=(1, 2), where="serving.extend")
+            with trace.span("serve/prefill/launch"):
+                out, k2, v2 = self._chunk_fn(*args)
+            with trace.span("serve/prefill/wait") as wait:
+                out = np.asarray(out)   # host sync: the chunk is done
+            _account(sp.t0_ns, wait.end_ns, "chunk_prefill", (seq,))
+            with trace.span("serve/prefill/commit"):
+                self.cache.swap(k2, v2)
+                if self._draft_extend_fn is not None:
+                    dargs = (args[0], self._draft_cache.k,
+                             self._draft_cache.v, args[3], args[4], args[5])
+                    _, dk, dv = self._draft_extend_fn(*dargs)
+                    self._draft_cache.swap(dk, dv)
+                    seq.draft_ctx = start + span
+                seq.prefill_pos = start + span
+                seq.ctx_len = seq.prefill_pos
+                self._m.prefill_real.inc(span)
+                self._m.prefill_bucket.inc(L)
+                if self.prefix is not None:
+                    new_nodes = self.prefix.insert(
+                        seq.request.prompt_ids, seq.block_ids,
+                        seq.prefill_pos, have=len(seq.prefix_nodes))
+                    seq.prefix_nodes += new_nodes
+                    seq.n_shared_blocks = len(seq.prefix_nodes)
+                if self.chunk_tokens:
+                    metrics.counter(
+                        "serving.chunked_prefill_iterations",
+                        "prefill chunks interleaved with decode").inc()
+                if seq.prefill_pos >= seq.prompt_len:
+                    tok = int(out[0])   # last_only: [B] of last-real argmax
+                    self._commit_first_token(seq, tok)
+                    if seq.is_finished_by(tok):
+                        self._finish(seq)
 
     def _mirror_draft_prefill(self, seq: Sequence) -> None:
         """ModelDrafter: materialize the drafter's prompt KV in the
@@ -1185,24 +1271,30 @@ class ServingEngine:
             break                     # one chunk per iteration: the budget
 
     def _restore(self, seq: Sequence, ids: List[int]) -> None:
-        now = time.perf_counter()
-        seq.add_phase("queue", now - seq.t_enqueue)
-        self.cache.restore(seq.host_kv, ids)
-        if self._draft_cache is not None and seq.host_draft_kv is not None:
-            self._draft_cache.restore(seq.host_draft_kv, ids)
-            seq.host_draft_kv = None
-        seq.host_kv = None
-        self._account_spill(-seq.spilled_bytes)
-        seq.spilled_bytes = 0
-        # the shared prefix never left the device — rebuild the table as
-        # (pinned shared ids) + (freshly restored private ids)
-        seq.block_ids = seq.block_ids[:seq.n_shared_blocks] + list(ids)
-        seq.block_log.append(-1)  # spill/restore boundary
-        seq.block_log.extend(ids)
+        seq.add_phase("queue", time.perf_counter() - seq.t_enqueue)
+        with trace.span("serve/restore", rid=seq.rid,
+                        blocks=len(ids)) as sp:
+            self.cache.restore(seq.host_kv, ids)
+            if self._draft_cache is not None and \
+                    seq.host_draft_kv is not None:
+                self._draft_cache.restore(seq.host_draft_kv, ids)
+                seq.host_draft_kv = None
+            seq.host_kv = None
+            self._account_spill(-seq.spilled_bytes)
+            seq.spilled_bytes = 0
+            # the shared prefix never left the device — rebuild the table
+            # as (pinned shared ids) + (freshly restored private ids)
+            seq.block_ids = seq.block_ids[:seq.n_shared_blocks] + list(ids)
+            seq.block_log.append(-1)  # spill/restore boundary
+            seq.block_log.extend(ids)
         # KV re-materialization substitutes for prefill on resume
-        seq.add_phase("prefill", time.perf_counter() - now)
+        _account(sp.t0_ns, sp.end_ns, "prefill", (seq,))
 
     def _preempt(self, seq: Sequence) -> None:
+        with trace.span("serve/preempt", rid=seq.rid):
+            self._spill(seq)
+
+    def _spill(self, seq: Sequence) -> None:
         self.sched.preempt(seq)
         shared = seq.n_shared_blocks
         private = seq.block_ids[shared:]
@@ -1274,47 +1366,74 @@ class ServingEngine:
             return []
         if self.spec_gamma:
             return self._spec_iteration(batch)
-        t0 = time.perf_counter()
-        width = self.decode_buckets.fit(len(batch))
+        rows = len(batch)
+        width = self.decode_buckets.fit(rows)
         m_blocks = self.max_blocks_per_seq
-        tokens = np.zeros((width,), np.int32)
-        tables = np.full((width, m_blocks), NULL_BLOCK, np.int32)
-        lens = np.zeros((width,), np.int32)
-        for i, seq in enumerate(batch):
-            tokens[i] = seq.out_tokens[-1]
-            tables[i, :len(seq.block_ids)] = seq.block_ids
-            lens[i] = seq.ctx_len
-        args = (jnp.asarray(tokens), self.cache.k, self.cache.v,
-                jnp.asarray(tables), jnp.asarray(lens))
-        self._maybe_lint()
-        for seq in batch:
-            self._assert_cow(self._write_span_ids(seq, seq.ctx_len, 1))
-        self._sent_decode.observe_tree(
-            "serving.decode", (args[0], args[3], args[4]),
-            donate=(1, 2), where="serving.decode")
-        out, k2, v2 = self._decode_fn(*args)
-        out = np.asarray(out)  # host sync per iteration (token commit)
-        self.cache.swap(k2, v2)
-        # Drill seam: a kill here lands AFTER the iteration's compute but
-        # BEFORE any token is committed/acknowledged — the relaunch must
-        # replay every in-flight request from scratch, exactly once.
-        _fault_fire("serve.mid_decode")
-        dur = time.perf_counter() - t0
-        self._decode_ms.append(dur * 1e3)
-        metrics.histogram("serving.decode_step_ms",
-                          "decode iteration wall time (ms)").observe(
-                              dur * 1e3)
-        finished: List[Sequence] = []
-        for i, seq in enumerate(batch):
-            seq.add_phase("decode", dur)
-            seq.ctx_len += 1
-            tok = int(out[i])
-            seq.out_tokens.append(tok)
-            if seq.is_finished_by(tok):
-                finished.append(seq)
-        for seq in finished:
-            self._finish(seq)
+        with self._acted_span("serve/decode", rows=rows,
+                              width=width) as sp:
+            with trace.span("serve/decode/build"):
+                tokens = np.zeros((width,), np.int32)
+                tables = np.full((width, m_blocks), NULL_BLOCK, np.int32)
+                lens = np.zeros((width,), np.int32)
+                for i, seq in enumerate(batch):
+                    tokens[i] = seq.out_tokens[-1]
+                    tables[i, :len(seq.block_ids)] = seq.block_ids
+                    lens[i] = seq.ctx_len
+                args = (jnp.asarray(tokens), self.cache.k, self.cache.v,
+                        jnp.asarray(tables), jnp.asarray(lens))
+            with trace.span("serve/decode/checks"):
+                self._maybe_lint()
+                for seq in batch:
+                    self._assert_cow(
+                        self._write_span_ids(seq, seq.ctx_len, 1))
+                self._sent_decode.observe_tree(
+                    "serving.decode", (args[0], args[3], args[4]),
+                    donate=(1, 2), where="serving.decode")
+            with trace.span("serve/decode/launch"):
+                out, k2, v2 = self._decode_fn(*args)
+            with self._acted_span("serve/decode/wait") as wait:
+                out = np.asarray(out)  # host sync per iteration
+            with trace.span("serve/decode/commit"):
+                self.cache.swap(k2, v2)
+                # Drill seam: a kill here lands AFTER the iteration's
+                # compute but BEFORE any token is committed/acknowledged —
+                # the relaunch must replay every in-flight request from
+                # scratch, exactly once.
+                _fault_fire("serve.mid_decode")
+                _account(sp.t0_ns, wait.end_ns, "decode", batch)
+                self._decode_done(sp, wait)
+                self._kv_count(int(lens.sum()), width)
+                # one commit stamp a step, shared by its rows; none
+                # under FLAGS_telemetry=off
+                now_ns = time.perf_counter_ns() if trace.enabled() else 0
+                finished: List[Sequence] = []
+                for i, seq in enumerate(batch):
+                    seq.ctx_len += 1
+                    tok = int(out[i])
+                    seq.out_tokens.append(tok)
+                    if now_ns:
+                        seq.token_t_ns.append(now_ns)
+                    if seq.is_finished_by(tok):
+                        finished.append(seq)
+                for seq in finished:
+                    self._finish(seq)
         return finished
+
+    def _decode_done(self, root, wait) -> None:
+        """The decode iteration's wall time, root's start to the arrival
+        of its tokens, to the policy's window and the histogram."""
+        if wait.end_ns:
+            ms = (wait.end_ns - root.t0_ns) / 1e6
+            self._decode_ms.append(ms)
+            self._m.decode_step_ms.observe(ms)
+
+    def _kv_count(self, needed: int, width: int) -> None:
+        """Useful over attempted where the padding happens: the decode (or
+        verify) program is handed the whole table of every row of the
+        bucket, whatever the rows' contexts."""
+        self._m.kv_needed.inc(needed)
+        self._m.kv_gathered.inc(
+            width * self.max_blocks_per_seq * self.block_size)
 
     # -- speculative decoding ------------------------------------------------
 
@@ -1372,47 +1491,59 @@ class ServingEngine:
         exactly the target's greedy stream, drafts or no drafts."""
         gamma = self.spec_gamma
         L = gamma + 1
-        width = self.decode_buckets.fit(len(batch))
+        rows = len(batch)
+        width = self.decode_buckets.fit(rows)
         m_blocks = self.max_blocks_per_seq
-        tables = np.full((width, m_blocks), NULL_BLOCK, np.int32)
-        for i, seq in enumerate(batch):
-            tables[i, :len(seq.block_ids)] = seq.block_ids
-        t0 = time.perf_counter()
-        proposals = self._draft_proposals(batch, width, tables)
-        t_draft = time.perf_counter() - t0
-        tokens = np.zeros((width, L), np.int32)
-        lens = np.zeros((width,), np.int32)
-        n_real = np.zeros((width,), np.int32)
-        for i, seq in enumerate(batch):
-            fed = [seq.out_tokens[-1]] + proposals[i]
-            tokens[i, :len(fed)] = fed
-            lens[i] = seq.ctx_len
-            n_real[i] = len(fed)
-        args = (jnp.asarray(tokens), self.cache.k, self.cache.v,
-                jnp.asarray(tables), jnp.asarray(lens),
-                jnp.asarray(n_real))
-        self._maybe_lint()
-        for i, seq in enumerate(batch):
-            self._assert_cow(self._write_span_ids(seq, seq.ctx_len,
-                                                  int(n_real[i])))
-        self._sent_verify.observe_tree(
-            "serving.verify", (args[0], args[3], args[4], args[5]),
-            donate=(1, 2), where="serving.verify")
-        out, k2, v2 = self._verify_fn(*args)
-        out = np.asarray(out)
-        self.cache.swap(k2, v2)
-        _fault_fire("serve.mid_decode")
-        dur = time.perf_counter() - t0
-        t_verify = dur - t_draft
-        self._decode_ms.append(dur * 1e3)
-        metrics.histogram("serving.decode_step_ms",
-                          "decode iteration wall time (ms)").observe(
-                              dur * 1e3)
+        with self._acted_span("serve/decode", rows=rows, width=width,
+                              gamma=gamma) as sp:
+            with trace.span("serve/decode/build"):
+                tables = np.full((width, m_blocks), NULL_BLOCK, np.int32)
+                for i, seq in enumerate(batch):
+                    tables[i, :len(seq.block_ids)] = seq.block_ids
+            with trace.span("serve/decode/draft") as draft:
+                proposals = self._draft_proposals(batch, width, tables)
+            with trace.span("serve/decode/build"):
+                tokens = np.zeros((width, L), np.int32)
+                lens = np.zeros((width,), np.int32)
+                n_real = np.zeros((width,), np.int32)
+                for i, seq in enumerate(batch):
+                    fed = [seq.out_tokens[-1]] + proposals[i]
+                    tokens[i, :len(fed)] = fed
+                    lens[i] = seq.ctx_len
+                    n_real[i] = len(fed)
+                args = (jnp.asarray(tokens), self.cache.k, self.cache.v,
+                        jnp.asarray(tables), jnp.asarray(lens),
+                        jnp.asarray(n_real))
+            with trace.span("serve/decode/checks"):
+                self._maybe_lint()
+                for i, seq in enumerate(batch):
+                    self._assert_cow(self._write_span_ids(
+                        seq, seq.ctx_len, int(n_real[i])))
+                self._sent_verify.observe_tree(
+                    "serving.verify", (args[0], args[3], args[4], args[5]),
+                    donate=(1, 2), where="serving.verify")
+            with trace.span("serve/decode/launch"):
+                out, k2, v2 = self._verify_fn(*args)
+            with self._acted_span("serve/decode/wait") as wait:
+                out = np.asarray(out)
+            with trace.span("serve/decode/commit"):
+                self.cache.swap(k2, v2)
+                _fault_fire("serve.mid_decode")
+                _account(draft.t0_ns, draft.end_ns, "draft", batch)
+                if draft.end_ns:
+                    _account(draft.end_ns, wait.end_ns, "verify", batch)
+                self._decode_done(sp, wait)
+                self._kv_count(int(lens.sum()), width)
+                finished = self._spec_commit(batch, proposals, out)
+        return finished
+
+    def _spec_commit(self, batch: List[Sequence],
+                     proposals: List[List[int]], out) -> List[Sequence]:
+        gamma = self.spec_gamma
         self.spec_stats["iterations"] += 1
+        now_ns = time.perf_counter_ns() if trace.enabled() else 0
         finished: List[Sequence] = []
         for i, seq in enumerate(batch):
-            seq.add_phase("draft", t_draft)
-            seq.add_phase("verify", t_verify)
             props = proposals[i]
             o = out[i]
             accepted = 0
@@ -1430,11 +1561,11 @@ class ServingEngine:
             ).observe(accepted)
             ctx0 = seq.ctx_len
             done = False
-            kept = 0
             for tok in committed:
                 seq.out_tokens.append(tok)
+                if now_ns:
+                    seq.token_t_ns.append(now_ns)
                 seq.ctx_len += 1
-                kept += 1
                 if seq.is_finished_by(tok):
                     done = True
                     break
@@ -1462,19 +1593,20 @@ class ServingEngine:
                           self._accept_lens)
 
     def _finish(self, seq: Sequence) -> None:
-        t0 = time.perf_counter()
-        self.sched.finish(seq)
-        self._free_seq_blocks(seq)
-        out = seq.full_output()
-        seq.output = out
-        # Acknowledge BEFORE detokenize/record: once the journal holds the
-        # done record (fsynced), a relaunch will not replay this request.
-        if self.journal is not None:
-            self.journal.done(seq.rid, seq.out_tokens)
-        if self.detokenizer is not None:
-            seq.text = self.detokenizer(out)
+        with trace.span("serve/finish", rid=seq.rid) as sp:
+            self.sched.finish(seq)
+            self._free_seq_blocks(seq)
+            out = seq.full_output()
+            seq.output = out
+            # Acknowledge BEFORE detokenize/record: once the journal holds
+            # the done record (fsynced), a relaunch will not replay this
+            # request.
+            if self.journal is not None:
+                self.journal.done(seq.rid, seq.out_tokens)
+            if self.detokenizer is not None:
+                seq.text = self.detokenizer(out)
+        _account(sp.t0_ns, sp.end_ns, "detokenize", (seq,))
         end = time.perf_counter()
-        seq.add_phase("detokenize", end - t0)
         total_ms = (end - seq.t_submit) * 1e3
         ttft_ms = ((seq.t_first_token - seq.t_submit) * 1e3
                    if seq.t_first_token is not None else None)
@@ -1485,7 +1617,8 @@ class ServingEngine:
             total_ms=total_ms, ttft_ms=ttft_ms,
             preemptions=seq.preemptions, outcome="ok",
             deadline_ms=(None if seq.request.deadline_s is None
-                         else seq.request.deadline_s * 1e3))
+                         else seq.request.deadline_s * 1e3),
+            **_commit_stamps(seq))
 
     # ------------------------------------------------------------------
     # Driving loop
@@ -1500,17 +1633,29 @@ class ServingEngine:
         this iteration — FINISHED, and also EXPIRED / SHED / FAILED
         retirements."""
         n0 = len(self.sched.finished)
-        self._expire_deadlines()
-        self._apply_shed_policy()
-        self._enforce_degraded_width()
-        while self._try_admit():
-            pass
-        self._chunk_iteration()
-        self._ensure_decode_blocks()
-        self._decode_iteration()
-        self._gauges()
-        self.n_iterations += 1
-        fleet_live.note_progress(self.n_iterations)
+        with trace.span("serve/step", iteration=self.n_iterations) as root:
+            if root:        # the queue lengths only for a span that records
+                root.set(running=len(self.sched.running),
+                         waiting=len(self.sched.waiting))
+            with trace.span("serve/expire_shed"):
+                self._expire_deadlines()
+                self._apply_shed_policy()
+                self._enforce_degraded_width()
+            with trace.span("serve/admit") as sp:
+                admitted = 0
+                while self._try_admit():
+                    admitted += 1
+                sp.set(admitted=admitted)
+            if self.chunk_tokens:
+                with trace.span("serve/chunk"):
+                    self._chunk_iteration()
+            with trace.span("serve/ensure_blocks"):
+                self._ensure_decode_blocks()
+            self._decode_iteration()
+            with trace.span("serve/gauges"):
+                self._gauges()
+                self.n_iterations += 1
+                fleet_live.note_progress(self.n_iterations)
         return self.sched.finished[n0:]
 
     def serve(self, requests: Seq[Request],

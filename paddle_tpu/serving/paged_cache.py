@@ -115,7 +115,6 @@ class BlockAllocator:
         self._used.update(got)
         for i in got:
             self._refs[i] = 1
-        self._gauges()
         return got
 
     def ref(self, ids: Sequence[int]) -> None:
@@ -126,7 +125,6 @@ class BlockAllocator:
                 raise ValueError(f"ref of unallocated block {i}")
         for i in ids:
             self._refs[i] += 1
-        self._gauges()
 
     def free(self, ids: Sequence[int]) -> None:
         """Drop one owner per block; last-owner blocks return to the
@@ -150,16 +148,6 @@ class BlockAllocator:
                 released.append(i)
         if released:
             self._free = sorted(self._free + released)
-        self._gauges()
-
-    def _gauges(self) -> None:
-        metrics.gauge("serving.kv_blocks_free",
-                      "free KV blocks in the paged pool").set(self.n_free)
-        metrics.gauge("serving.kv_blocks_used",
-                      "allocated KV blocks in the paged pool").set(self.n_used)
-        metrics.gauge("serving.blocks_shared",
-                      "KV blocks held by more than one owner").set(
-                          self.n_shared)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

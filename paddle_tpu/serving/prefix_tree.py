@@ -43,7 +43,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..observability import metrics
 from .paged_cache import PagedKVCache
 
 __all__ = ["PrefixCache", "PrefixNode"]
@@ -89,7 +88,7 @@ class PrefixCache:
         self.root = PrefixNode((), None)
         self._tick = 0
         self._nodes = 0
-        # cumulative hit accounting for serving.prefix_hit_rate
+        # cumulative hit accounting (``hit_rate``, ``prefix_report``)
         self.hit_tokens = 0
         self.lookup_tokens = 0
 
@@ -140,19 +139,10 @@ class PrefixCache:
 
     def hit_rate(self) -> float:
         """Cumulative fraction of looked-up prompt tokens served from
-        the tree (the ``serving.prefix_hit_rate`` gauge)."""
+        the tree."""
         if not self.lookup_tokens:
             return 0.0
         return self.hit_tokens / self.lookup_tokens
-
-    def _gauges(self) -> None:
-        metrics.gauge("serving.prefix_hit_rate",
-                      "cumulative prompt tokens served from the prefix "
-                      "tree / prompt tokens looked up").set(
-                          round(self.hit_rate(), 6))
-        metrics.gauge("serving.prefix_nodes",
-                      "blocks registered in the prefix tree").set(
-                          self._nodes)
 
     # -- match / attach ------------------------------------------------------
 
@@ -204,11 +194,10 @@ class PrefixCache:
 
     def account(self, prompt_len: int, hit_len: int) -> None:
         """Record one successful admission's lookup/hit token counts
-        (the ``serving.prefix_hit_rate`` input) — called once per
-        admitted sequence, never on retried admission attempts."""
+        (the ``hit_rate`` input) — called once per admitted sequence,
+        never on retried admission attempts."""
         self.lookup_tokens += int(prompt_len)
         self.hit_tokens += int(hit_len)
-        self._gauges()
 
     # -- insert --------------------------------------------------------------
 
@@ -252,7 +241,6 @@ class PrefixCache:
             self._touch(child)
             new.append(child)
             node = child
-        self._gauges()
         return new
 
     # -- release / evict -----------------------------------------------------
@@ -267,7 +255,6 @@ class PrefixCache:
                     f"release of unattached prefix node {node.key[:4]}...")
             node.seq_refs -= 1
             self.cache.allocator.free([node.block_id])
-        self._gauges()
 
     def _evictable(self) -> List[PrefixNode]:
         out = []
@@ -314,11 +301,7 @@ class PrefixCache:
             victim.block_id = None
             if not keep:
                 self._drop(victim)
-            metrics.counter("serving.prefix_evictions",
-                            "prefix-tree blocks evicted (spilled or "
-                            "dropped)").inc()
             freed += 1
-        self._gauges()
         return freed
 
     def _drop(self, node: PrefixNode) -> None:

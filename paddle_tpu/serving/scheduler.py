@@ -121,6 +121,9 @@ class Sequence:
     t_submit: float = 0.0
     t_requeue: Optional[float] = None
     t_first_token: Optional[float] = None
+    #: ``perf_counter_ns`` at which each output token was committed (one
+    #: read per engine step, shared by the rows of that step)
+    token_t_ns: List[int] = field(default_factory=list)
     phase_s: Dict[str, float] = field(default_factory=dict)
 
     @property

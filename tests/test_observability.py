@@ -196,13 +196,21 @@ class TestMetricsExposition:
 
 class TestTrace:
     def test_spans_only_under_trace_mode(self):
-        with trace.span("quiet"):
+        """The contract since ISSUE 26: spans record under the default
+        ``metrics`` mode too; ``off`` hands out the shared no-op and
+        leaves the ring empty; ``trace`` adds only the open-span table."""
+        _mode("off")
+        quiet = trace.span("quiet")
+        assert quiet is trace.span("quiet too")     # one shared object
+        with quiet:
             pass
-        assert trace.spans() == []  # metrics mode: spans are no-ops
-        _mode("trace")
+        assert trace.spans() == []
+        _mode("metrics")
         with trace.span("outer", step=1):
-            with trace.span("inner"):
-                pass
+            held = trace.span("inner")
+            held.__enter__()
+            assert trace.open_spans() == []         # no table in metrics
+            held.__exit__(None, None, None)
         got = trace.spans()
         names = [s["name"] for s in got]
         assert names == ["inner", "outer"]  # completion order
@@ -210,7 +218,11 @@ class TestTrace:
         assert by["outer"]["depth"] == 0
         assert by["inner"]["depth"] == 1
         assert by["outer"]["attrs"] == {"step": 1}
-        assert by["outer"]["dur_us"] >= by["inner"]["dur_us"]
+        assert by["outer"]["dur_ns"] >= by["inner"]["dur_ns"]
+        _mode("trace")
+        with trace.span("traced"):
+            assert [s["name"] for s in trace.open_spans()] == ["traced"]
+        assert trace.spans()[-1]["name"] == "traced"
 
     def test_chrome_and_jsonl_export(self, tmp_path):
         _mode("trace")
@@ -244,7 +256,7 @@ class TestTrace:
         assert "incomplete" not in by["done"]
         inc = by["possibly/hung"]
         assert inc["incomplete"] is True
-        assert inc["dur_us"] >= 0 and inc["attrs"] == {"step": 7}
+        assert inc["dur_ns"] >= 0 and inc["attrs"] == {"step": 7}
         # chrome export carries the flag through args
         chrome = tmp_path / "t.json"
         assert trace.export_chrome_trace(str(chrome)) == 2

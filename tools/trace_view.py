@@ -4,8 +4,12 @@
 Input is the JSONL written by ``StepTimeline.export_jsonl`` (one record
 per step: ``{"kind": "step", "step": N, "phases": {...}, "total_ms": ..,
 "hbm_peak_gb": ..}``), optionally interleaved with ``trace.export_jsonl``
-span records (``{"kind": "span", "name": .., "dur_us": ..}``) — bench runs
-write both into one file.
+span records (``{"kind": "span", "name": .., "t0_ns": .., "dur_ns": ..,
+"id": .., "parent": ..}``; files from before ISSUE 26 carry ``dur_us`` and no
+ids) — bench runs write both into one file. Spans with ids also get a table
+of SELF time by span name: each span's duration less what its children
+cover, which is where a serving step's or a train step's host time actually
+goes. The tool reads files and imports nothing of the package.
 
     python tools/trace_view.py BENCH_timeline.jsonl
     python tools/trace_view.py run.jsonl --json          # machine output
@@ -52,6 +56,56 @@ def load_jsonl(path: str) -> Tuple[List[Dict[str, Any]], List[Dict[str, Any]]]:
     return steps, spans
 
 
+def _dur_ms(sp: Dict[str, Any]) -> float:
+    if "dur_ns" in sp:
+        return float(sp["dur_ns"]) / 1e6
+    return float(sp.get("dur_us", 0.0)) / 1e3
+
+
+def self_times(spans: List[Dict[str, Any]]) -> Dict[int, int]:
+    """``{id: self_ns}``: a span's duration less the part of it that its
+    children cover (overlapping children counted once); the arithmetic of
+    ``observability.trace.self_times``, kept here so the tool stands alone."""
+    kids: Dict[int, List[Tuple[int, int]]] = {}
+    for sp in spans:
+        if sp.get("parent") is not None:
+            kids.setdefault(sp["parent"], []).append(
+                (sp["t0_ns"], sp["t0_ns"] + sp["dur_ns"]))
+    own: Dict[int, int] = {}
+    for sp in spans:
+        cur, hi = sp["t0_ns"], sp["t0_ns"] + sp["dur_ns"]
+        covered = 0
+        for a, b in sorted(kids.get(sp["id"], ())):
+            a, b = max(a, cur), min(b, hi)
+            if b > a:
+                covered += b - a
+                cur = b
+        own[sp["id"]] = sp["dur_ns"] - covered
+    return own
+
+
+def self_time_table(spans: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """Self time by span name, from ``id``/``parent``, sorted by it."""
+    linked = [sp for sp in spans if "id" in sp and "t0_ns" in sp
+              and not sp.get("incomplete")]
+    if not linked:
+        return []
+    own = self_times(linked)
+    agg: Dict[str, Dict[str, float]] = {}
+    for sp in linked:
+        row = agg.setdefault(sp["name"], {"calls": 0, "self_ms": 0.0,
+                                          "total_ms": 0.0})
+        row["calls"] += 1
+        row["self_ms"] += own[sp["id"]] / 1e6
+        row["total_ms"] += sp["dur_ns"] / 1e6
+    return [{"span": name, "calls": r["calls"],
+             "self_ms": round(r["self_ms"], 3),
+             "total_ms": round(r["total_ms"], 3),
+             "avg_self_ms": round(r["self_ms"] / r["calls"], 4)}
+            for name, r in sorted(agg.items(),
+                                  key=lambda kv: -kv[1]["self_ms"])]
+
+
 def phase_table(steps: List[Dict[str, Any]],
                 spans: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     """Per-phase aggregate rows sorted by total time descending."""
@@ -73,7 +127,7 @@ def phase_table(steps: List[Dict[str, Any]],
         if name.startswith("step/"):
             continue  # already counted via the step record's phases
         if name:
-            add(f"span:{name}", float(sp.get("dur_us", 0.0)) / 1e3)
+            add(f"span:{name}", _dur_ms(sp))
 
     total = sum(r["total_ms"] for r in agg.values()) or 1.0
     rows = []
@@ -135,7 +189,7 @@ def comm_summary(steps: List[Dict[str, Any]],
                              {"calls": 0, "total_ms": 0.0, "hops": 0,
                               "bytes_moved": 0})
         row["calls"] += 1
-        row["total_ms"] += float(sp.get("dur_us", 0.0)) / 1e3
+        row["total_ms"] += _dur_ms(sp)
         hops = int(attrs.get("hops", 0))
         row["hops"] += hops
         row["bytes_moved"] += hops * int(attrs.get("bytes_per_hop", 0))
@@ -162,6 +216,7 @@ def summarize(steps: List[Dict[str, Any]], spans: List[Dict[str, Any]],
         "max_step_ms": round(max(totals), 3) if totals else None,
         "hbm_peak_gb": max(hbm) if hbm else None,
         "phases": phase_table(steps, spans),
+        "self_time": self_time_table(spans),
         "comm": comm_summary(steps, spans),
         "anomalies": find_anomalies(steps, factor=factor, window=window),
     }
@@ -183,6 +238,14 @@ def render_text(summary: Dict[str, Any]) -> str:
         lines.append(f"{r['phase'][:23]:<24}{r['calls']:>7}"
                      f"{r['total_ms']:>12.3f}{r['avg_ms']:>10.3f}"
                      f"{r['max_ms']:>10.3f}{r['share_pct']:>7.1f}%")
+    if summary.get("self_time"):
+        lines.append(bar)
+        lines.append(f"{'span (self time)':<32}{'calls':>7}{'self ms':>12}"
+                     f"{'avg self':>10}{'total ms':>12}")
+        for r in summary["self_time"]:
+            lines.append(f"{r['span'][:31]:<32}{r['calls']:>7}"
+                         f"{r['self_ms']:>12.3f}{r['avg_self_ms']:>10.4f}"
+                         f"{r['total_ms']:>12.3f}")
     comm = summary.get("comm") or {}
     if comm.get("phase_total_ms") or comm.get("decomposed_ops"):
         lines.append(bar)
