@@ -38,7 +38,8 @@ from .jaxpr_lint import Diagnostic, ERROR, WARNING, emit
 
 __all__ = ["VMEM_BUDGET", "KernelSpec", "BlockUse", "check_kernel_spec",
            "spec_for_flash_packed", "spec_for_flash", "spec_for_conv_matmul",
-           "spec_for_conv3x3", "enforce", "report_fallback",
+           "spec_for_conv3x3", "spec_for_paged_decode", "enforce",
+           "report_fallback",
            "check_jaxpr_pallas"]
 
 # Mosaic's scoped-VMEM stack per core (v4/v5 generations): ~16 MB.
@@ -268,6 +269,28 @@ def spec_for_flash(seq_q: int, seq_k: int, head_d: int, block_q: int,
                                  1, dtype, bwd)
     spec.name = "flash_attention" + ("_bwd" if bwd else "")
     return spec
+
+
+def spec_for_paged_decode(batch: int, table_width: int, block_size: int,
+                          heads: int, kv_heads: int, head_d: int,
+                          pages_per_step: Optional[int] = None,
+                          dtype=np.float32) -> KernelSpec:
+    """Spec for the paged single-query decode kernel
+    (``ops/_pallas/paged_attention.py``): q and the output whole in VMEM,
+    two slots of ``pages_per_step`` pages each for K and for V (a page read
+    as ``[block_size * kv_heads, head_d]``), and the ``[heads, tokens *
+    kv_heads]`` float32 score tile with its probabilities and masks."""
+    if pages_per_step is None:
+        from ..ops._pallas.paged_attention import PAGES_PER_STEP
+        pages_per_step = PAGES_PER_STEP
+    pages = max(1, min(pages_per_step, table_width))
+    step_rows = pages * block_size * kv_heads
+    qo = BlockUse((batch, heads, head_d), dtype, "q/out")
+    slot = BlockUse((2, step_rows, head_d), dtype, "page slots")
+    return KernelSpec(
+        name="paged_single_query_attention", grid=(1,),
+        blocks=[qo, qo], scratch=[slot, slot],
+        score_tile=(heads, step_rows, 4))
 
 
 def enforce(spec: KernelSpec, where: str = "") -> List[Diagnostic]:

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from ..core import flags
 
 __all__ = ["flash_attention", "flash_attn_unpadded", "reference_attention",
-           "single_query_attention"]
+           "single_query_attention", "paged_single_query_attention",
+           "takes_paged_kernel"]
 
 
 def reference_attention(q, k, v, causal: bool = False,
@@ -100,6 +101,72 @@ def single_query_attention(q, k, v, lengths=None,
     return out.reshape(b, 1, h, d)
 
 
+def _platform_of(x) -> str:
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        return next(iter(x.devices())).platform
+    return jax.default_backend()
+
+
+def takes_paged_kernel(q_dtype, k_pool) -> bool:
+    """Does decode attention over ``k_pool`` (``[..., NB, bs, KH, D]``) with
+    queries of ``q_dtype`` take the paged Pallas kernel? As ``_use_pallas``:
+    on a TPU with the flag on and a shape the kernel takes; an unsupported
+    shape ON a TPU is announced once (P005). The serving engine asks too,
+    to count what its decode program reads."""
+    if not flags.flag("use_pallas_kernels") or _platform_of(k_pool) != "tpu":
+        return False
+    from ._pallas.paged_attention import supported_shapes
+    if supported_shapes(q_dtype, k_pool):
+        return True
+    from ..analysis.pallas_check import report_fallback
+    report_fallback(
+        "paged_single_query_attention",
+        f"q {jnp.dtype(q_dtype).name} pool{tuple(k_pool.shape)} "
+        f"{k_pool.dtype}",
+        "needs bf16 queries and pool, head_dim 128, block_size and kv "
+        "heads multiples of 16")
+    return False
+
+
+def paged_single_query_attention(q, k_pool, v_pool, tables, lengths, *,
+                                 block_size: int, layer=0,
+                                 scale: Optional[float] = None):
+    """Decode-step attention read through block tables: ``q [B, 1, H, D]``
+    against the pages ``tables [B, M]`` names in the pool, row ``b`` up to
+    its first ``lengths[b]`` keys (0: the row returns 0).
+
+    The pool is the engine's ``[L, NB, block_size, KH, D]`` with ``layer``
+    the layer to read (a Python int or a traced scalar), or one layer's
+    ``[NB, block_size, KH, D]``. Handing over the whole pool and an index
+    keeps a slice of it from ever being copied.
+
+    On a TPU, for the shapes ``_pallas.paged_attention.supported_shapes``
+    takes, this is the Pallas kernel: each row's pages are fetched from HBM
+    up to its own length and no gathered copy exists. Everywhere else it is
+    the dense path the kernel is checked against: gather every table's
+    pages, then :func:`single_query_attention` behind a length mask."""
+    if k_pool.shape[-3] != block_size:
+        raise ValueError(f"pool pages hold {k_pool.shape[-3]} tokens, "
+                         f"block_size says {block_size}")
+    if takes_paged_kernel(q.dtype, k_pool):
+        from ._pallas.paged_attention import paged_attention_pallas
+        from ..analysis import pallas_check as _pc
+        _pc.enforce(_pc.spec_for_paged_decode(
+            q.shape[0], tables.shape[1], block_size, q.shape[2],
+            k_pool.shape[-2], q.shape[3], dtype=k_pool.dtype),
+            where="paged_single_query_attention")
+        return paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
+                                      layer=layer, scale=scale)
+    if k_pool.ndim == 5:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
+    b = q.shape[0]
+    mx = tables.shape[1] * block_size
+    keys = k_pool[tables].reshape(b, mx, *k_pool.shape[2:])
+    vals = v_pool[tables].reshape(b, mx, *v_pool.shape[2:])
+    return single_query_attention(q, keys, vals, lengths=lengths,
+                                  scale=scale)
+
+
 def _use_pallas(q, k) -> bool:
     """Does this call take the Pallas kernel? Yes on a TPU with the flag
     on and a shape the kernels tile; off the chip the dense path is the
@@ -107,11 +174,7 @@ def _use_pallas(q, k) -> bool:
     never swallowed."""
     if not flags.flag("use_pallas_kernels"):
         return False
-    if isinstance(q, jax.Array) and not isinstance(q, jax.core.Tracer):
-        platform = next(iter(q.devices())).platform
-    else:
-        platform = jax.default_backend()
-    if platform != "tpu":
+    if _platform_of(q) != "tpu":
         return False
     # MXU-friendly shapes only (both seq lens tile-divisible); else the
     # reference path — the kernel would drop tail keys otherwise.

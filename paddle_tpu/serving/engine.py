@@ -39,10 +39,11 @@ byte-identical when off):
 
 The prefill step runs the model's flash-attention forward on one
 bucket-padded prompt and scatters the per-layer K/V into the sequence's
-pages; the decode step is a batched single-query pass that gathers each
-sequence's pages (``ops.flash_attention.single_query_attention`` masks
-the padded tail by context length) and writes the new token's KV in the
-same program; the ``extend`` step is the multi-token generalization
+pages; the decode step is a batched single-query pass that writes the
+new token's KV and attends each sequence's pages through its block table
+up to its own context (``ops.flash_attention.paged_single_query_attention``:
+a Pallas kernel on a TPU, gather + ``single_query_attention`` behind a
+length mask elsewhere); the ``extend`` step is the multi-token generalization
 (offset-causal over gathered pages) shared by chunked prefill, suffix
 prefill after a prefix hit, and speculative verification. Executables
 take the page pool **donated** — the pool is updated in place, never
@@ -78,7 +79,9 @@ from ..observability import live as fleet_live
 from ..observability import metrics, request_timeline, trace
 from ..observability.request_timeline import percentile
 from ..observability.step_monitor import RecompileSentinel
-from ..ops.flash_attention import flash_attention, single_query_attention
+from ..ops.flash_attention import (flash_attention,
+                                   paged_single_query_attention,
+                                   takes_paged_kernel)
 from .buckets import BucketSet, pow2_buckets, pad_axis
 from .paged_cache import (NULL_BLOCK, OutOfBlocksError, PagedKVCache,
                           SpillError)
@@ -321,6 +324,10 @@ class ServingEngine:
                 dcfg.num_layers, num_blocks, self.block_size,
                 dcfg.kv_heads, dcfg.hidden_size // dcfg.num_heads,
                 dtype=self.drafter.model.gpt.wte.weight.dtype)
+        #: whether the decode program reads pages through the kernel (what
+        #: ``serving.kv_tokens{kind=gathered}`` then counts)
+        self._decode_paged = takes_paged_kernel(self.cache.dtype,
+                                                self.cache.k)
         self.prefix = PrefixCache(self.cache, mirror=self._draft_cache) \
             if self.prefix_on else None
         self.sched = FCFSScheduler(max_batch, max_waiting=max_waiting)
@@ -446,7 +453,6 @@ class ServingEngine:
             One iteration: write each token's KV at position ctx_len,
             attend over ctx_len+1 keys, return the next token."""
             b = tokens.shape[0]
-            mx = tables.shape[1] * bs
             pos = ctx_lens
             x = m.gpt.wte(tokens[:, None]) + m.gpt.wpe(pos[:, None])
             bi = jnp.take_along_axis(tables, (pos // bs)[:, None],
@@ -459,9 +465,9 @@ class ServingEngine:
                     k[:, 0].astype(k_pages.dtype))
                 v_pages = v_pages.at[li, bi, si].set(
                     v[:, 0].astype(v_pages.dtype))
-                keys = k_pages[li][tables].reshape(b, mx, *k.shape[2:])
-                vals = v_pages[li][tables].reshape(b, mx, *v.shape[2:])
-                o = single_query_attention(q, keys, vals, lengths=pos + 1)
+                o = paged_single_query_attention(
+                    q, k_pages, v_pages, tables, pos + 1, block_size=bs,
+                    layer=li)
                 x = x + blk.attn.out_proj(o.reshape(b, 1, -1))
                 x = x + blk.mlp(blk.ln_2(x))
             hidden = m.gpt.ln_f(x)
@@ -1402,7 +1408,7 @@ class ServingEngine:
                 _fault_fire("serve.mid_decode")
                 _account(sp.t0_ns, wait.end_ns, "decode", batch)
                 self._decode_done(sp, wait)
-                self._kv_count(int(lens.sum()), width)
+                self._kv_count(lens, self._decode_paged)
                 # one commit stamp a step, shared by its rows; none
                 # under FLAGS_telemetry=off
                 now_ns = time.perf_counter_ns() if trace.enabled() else 0
@@ -1427,13 +1433,19 @@ class ServingEngine:
             self._decode_ms.append(ms)
             self._m.decode_step_ms.observe(ms)
 
-    def _kv_count(self, needed: int, width: int) -> None:
-        """Useful over attempted where the padding happens: the decode (or
-        verify) program is handed the whole table of every row of the
-        bucket, whatever the rows' contexts."""
-        self._m.kv_needed.inc(needed)
-        self._m.kv_gathered.inc(
-            width * self.max_blocks_per_seq * self.block_size)
+    def _kv_count(self, lens: np.ndarray, paged: bool = False) -> None:
+        """Useful over attempted where the padding happens. The dense
+        decode program and verify are handed the whole table of every row
+        of the bucket, whatever the rows' contexts; the paged decode kernel
+        reads each row's pages up to its context and the token it wrote
+        (a pad row: the null page), the last page whole."""
+        self._m.kv_needed.inc(int(lens.sum()))
+        bs = self.block_size
+        if paged:
+            self._m.kv_gathered.inc(int((lens // bs + 1).sum()) * bs)
+        else:
+            self._m.kv_gathered.inc(
+                len(lens) * self.max_blocks_per_seq * bs)
 
     # -- speculative decoding ------------------------------------------------
 
@@ -1533,7 +1545,7 @@ class ServingEngine:
                 if draft.end_ns:
                     _account(draft.end_ns, wait.end_ns, "verify", batch)
                 self._decode_done(sp, wait)
-                self._kv_count(int(lens.sum()), width)
+                self._kv_count(lens)
                 finished = self._spec_commit(batch, proposals, out)
         return finished
 

@@ -1,0 +1,338 @@
+"""The paged single-query decode kernel (``ops/_pallas/paged_attention.py``)
+in interpret mode on the CPU, against the dense path it replaces in the
+serving decode program: gather every table's pages, then
+``single_query_attention`` behind a length mask. The tier-1 engine tests run
+that dense path (off the chip it is the declared one), so the kernel is
+exercised here directly, and once through a whole ``ServingEngine`` run with
+the entry point steered to the interpreted kernel.
+"""
+
+import functools
+import importlib
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.analysis import pallas_check
+from paddle_tpu.observability import metrics
+from paddle_tpu.ops._pallas import paged_attention as PA
+from paddle_tpu.ops.flash_attention import (paged_single_query_attention,
+                                            single_query_attention)
+from paddle_tpu.serving import NULL_BLOCK, Request, ServingEngine
+from paddle_tpu.text.models.gpt import GPTForCausalLM, gpt_tiny
+
+# ``paddle_tpu.ops.flash_attention`` the attribute is the function of that
+# name; the module is reached through importlib
+FA = importlib.import_module("paddle_tpu.ops.flash_attention")
+
+BS, M, D, NB, L = 16, 5, 128, 48, 2
+FULL = M * BS
+# a pad row, one key, a whole page, one less, one more, the table's width
+LENGTHS = [0, 1, BS, BS - 1, BS + 1, FULL, 3 * BS, 37]
+
+kernel = functools.partial(PA.paged_attention_pallas, interpret=True)
+
+
+def _pool_and_tables(lengths, kh, dtype, seed=0, heads=4):
+    """Random pools, queries and block tables: each row's pages drawn
+    scattered and unordered from the pool, the table's tail ``NULL_BLOCK``."""
+    rng = np.random.default_rng(seed)
+    b = len(lengths)
+    q = jnp.asarray(rng.standard_normal((b, 1, heads, D)), dtype)
+    k = jnp.asarray(rng.standard_normal((L, NB, BS, kh, D)), dtype)
+    v = jnp.asarray(rng.standard_normal((L, NB, BS, kh, D)), dtype)
+    tables = np.full((b, M), NULL_BLOCK, np.int32)
+    free = rng.permutation(np.arange(1, NB))      # non-monotonic page order
+    at = 0
+    for i, n in enumerate(lengths):
+        pages = -(-n // BS)
+        tables[i, :pages] = free[at:at + pages]
+        at += pages
+    return q, k, v, tables, np.asarray(lengths, np.int32)
+
+
+def _dense(q, k, v, tables, lengths, layer):
+    keys = k[layer][tables].reshape(len(lengths), FULL, *k.shape[3:])
+    vals = v[layer][tables].reshape(len(lengths), FULL, *v.shape[3:])
+    return single_query_attention(q, keys, vals, lengths=jnp.asarray(lengths))
+
+
+def _f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+TOL = {jnp.float32: 2e-6, jnp.bfloat16: 2e-2}
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 8])
+@pytest.mark.parametrize("kh", [4, 2, 1], ids=["mha", "gqa2", "mqa"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_kernel_matches_gather_then_dense(dtype, kh, pages_per_step):
+    q, k, v, tables, lengths = _pool_and_tables(LENGTHS, kh, dtype)
+    for layer in range(L):
+        out = kernel(q, k, v, jnp.asarray(tables), jnp.asarray(lengths),
+                     layer=layer, pages_per_step=pages_per_step)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        np.testing.assert_allclose(
+            _f32(out), _f32(_dense(q, k, v, tables, lengths, layer)),
+            atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("length", LENGTHS)
+def test_each_length_alone_and_pad_rows_are_zero(length):
+    """One real row between two pad rows: the pad rows (length 0, a table
+    of ``NULL_BLOCK``) return zeros, the masked-row convention."""
+    q, k, v, tables, lengths = _pool_and_tables([0, length, 0], 4,
+                                                jnp.float32, seed=length)
+    out = _f32(kernel(q, k, v, jnp.asarray(tables), jnp.asarray(lengths),
+                      layer=1, pages_per_step=2))
+    assert not out[0].any() and not out[2].any()
+    if length == 0:
+        assert not out[1].any()
+    np.testing.assert_allclose(out, _f32(_dense(q, k, v, tables, lengths, 1)),
+                               atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("pages_per_step", [2, 8])
+def test_kernel_reads_nothing_it_should_not_use(dtype, pages_per_step):
+    """NaN in every pool block that is in no row's table (the null block
+    among them), in every slot past a row's length inside its last page, and
+    in the whole other layer, leaves the output as it was."""
+    q, k, v, tables, lengths = _pool_and_tables(LENGTHS, 2, dtype, seed=3)
+    args = (jnp.asarray(tables), jnp.asarray(lengths))
+    clean = _f32(kernel(q, k, v, *args, layer=1,
+                        pages_per_step=pages_per_step))
+    poison = np.ones((L, NB, BS), bool)
+    poison[1] = True
+    for i, n in enumerate(lengths):
+        for j in range(-(-int(n) // BS)):
+            used = min(BS, int(n) - j * BS)
+            poison[1, tables[i, j], :used] = False
+    assert poison[1, NULL_BLOCK].all() and poison[0].all()
+    mask = jnp.asarray(poison)[..., None, None]
+    kp = jnp.where(mask, jnp.nan, k)
+    vp = jnp.where(mask, jnp.nan, v)
+    out = _f32(kernel(q, kp, vp, *args, layer=1,
+                      pages_per_step=pages_per_step))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean)
+
+
+def test_one_layer_pool_and_traced_layer_index():
+    """A ``[NB, bs, KH, D]`` pool is one layer's; a traced layer index picks
+    the layer of a whole pool (the unrolled layers of a decode program share
+    one lowered kernel that way)."""
+    q, k, v, tables, lengths = _pool_and_tables([40, 7], 4, jnp.float32)
+    t, n = jnp.asarray(tables), jnp.asarray(lengths)
+    whole = kernel(q, k, v, t, n, layer=jnp.asarray(1))
+    np.testing.assert_array_equal(_f32(whole),
+                                  _f32(kernel(q, k[1], v[1], t, n)))
+    assert np.abs(_f32(whole) - _f32(kernel(q, k, v, t, n, layer=0))).max() \
+        > 1e-3
+
+
+def test_entry_point_off_the_chip_is_the_dense_path():
+    """On the CPU ``paged_single_query_attention`` is gather + dense, bit
+    for bit, for a layer's pool and for the whole pool with an index."""
+    q, k, v, tables, lengths = _pool_and_tables(LENGTHS, 2, jnp.float32)
+    t, n = jnp.asarray(tables), jnp.asarray(lengths)
+    want = _f32(_dense(q, k, v, tables, lengths, 1))
+    for got in (paged_single_query_attention(q, k, v, t, n, block_size=BS,
+                                             layer=1),
+                paged_single_query_attention(q, k[1], v[1], t, n,
+                                             block_size=BS)):
+        np.testing.assert_array_equal(_f32(got), want)
+    with pytest.raises(ValueError, match="block_size"):
+        paged_single_query_attention(q, k, v, t, n, block_size=8)
+
+
+SHAPES = {          # q dtype, pool dtype, (bs, kh, d) -> the kernel takes it
+    "cell": (jnp.bfloat16, jnp.bfloat16, (16, 16, 128), True),
+    "gqa32of16": (jnp.bfloat16, jnp.bfloat16, (32, 16, 128), True),
+    "f32": (jnp.float32, jnp.float32, (16, 16, 128), False),
+    "f32_pool": (jnp.bfloat16, jnp.float32, (16, 16, 128), False),
+    "d64": (jnp.bfloat16, jnp.bfloat16, (16, 16, 64), False),
+    "bs8": (jnp.bfloat16, jnp.bfloat16, (8, 16, 128), False),
+    "kh8": (jnp.bfloat16, jnp.bfloat16, (16, 8, 128), False),
+}
+
+
+@pytest.mark.parametrize("which", sorted(SHAPES))
+def test_selection_is_from_platform_and_shapes(which, monkeypatch):
+    """Off the chip: never. On a TPU (the platform steered here, as
+    ``test_chip_compile`` does): the supported shapes, and an unsupported
+    one is announced once through P005."""
+    qd, pd, (bs, kh, d), takes = SHAPES[which]
+    pool = jnp.zeros((2, 3, bs, kh, d), pd)
+    assert not FA.takes_paged_kernel(qd, pool)          # the CPU
+    monkeypatch.setattr(FA, "_platform_of", lambda x: "tpu")
+    monkeypatch.setattr(pallas_check, "_FALLBACKS_REPORTED", set())
+    assert FA.takes_paged_kernel(qd, pool) is takes
+    announced = {k for k, _ in pallas_check._FALLBACKS_REPORTED}
+    assert announced == (set() if takes else {"paged_single_query_attention"})
+
+
+def test_kernel_spec_fits_the_vmem_budget_at_the_cell_size():
+    spec = pallas_check.spec_for_paged_decode(32, 80, 16, 16, 16, 128,
+                                              dtype=jnp.bfloat16)
+    assert not pallas_check.check_kernel_spec(spec)
+    # sixty-four pages a step would not fit: the check says so
+    big = pallas_check.spec_for_paged_decode(32, 80, 16, 16, 16, 128,
+                                             pages_per_step=64,
+                                             dtype=jnp.bfloat16)
+    assert {d.rule for d in pallas_check.check_kernel_spec(big)} >= {"P001"}
+
+
+# ---------------------------------------------------------------------------
+# Through the engine
+# ---------------------------------------------------------------------------
+
+def _micro_model():
+    paddle.seed(7)
+    m = GPTForCausalLM(gpt_tiny(vocab_size=128, hidden_size=48, num_layers=2,
+                                num_heads=4, max_position_embeddings=64))
+    m.eval()
+    return m
+
+
+def _requests():
+    rng = np.random.default_rng(5)
+    return [Request(rid=f"r{i}", max_new_tokens=int(rng.integers(3, 9)),
+                    prompt_ids=rng.integers(0, 128, int(rng.integers(3, 15))))
+            for i in range(5)]
+
+
+def _serve(model):
+    engine = ServingEngine(model, block_size=4, num_blocks=32, max_batch=2)
+    kv = metrics.counter("serving.kv_tokens")
+    before = {k: kv.labels(kind=k).get() for k in ("needed", "gathered")}
+    results = engine.serve(_requests())
+    counted = {k: kv.labels(kind=k).get() - before[k] for k in before}
+    return engine, results, counted
+
+
+def test_engine_tokens_equal_with_the_kernel_and_counter_moves(monkeypatch):
+    """Greedy tokens of a whole ``ServingEngine`` run (five requests through
+    two decode rows, so rows are refilled) with the paged entry point
+    steered to the interpreted kernel equal the dense path's, token for
+    token; ``serving.kv_tokens{kind=gathered}`` then counts the pages the
+    kernel reads, not the tables' width."""
+    model = _micro_model()
+    dense_eng, dense, dense_kv = _serve(model)
+    assert not dense_eng._decode_paged
+
+    engine_mod = importlib.import_module("paddle_tpu.serving.engine")
+    calls = []
+
+    def interpreted(*a, **kw):
+        calls.append(1)
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(FA, "takes_paged_kernel", lambda *a: True)
+    monkeypatch.setattr(engine_mod, "takes_paged_kernel", lambda *a: True)
+    monkeypatch.setattr(PA, "paged_attention_pallas", interpreted)
+    paged_eng, paged, paged_kv = _serve(model)
+    assert paged_eng._decode_paged
+    # a call a layer each time the decode program is traced (the engine's
+    # lint traces it once more), none at a later step
+    assert calls and len(calls) % model.cfg.num_layers == 0
+    assert len(calls) <= 2 * model.cfg.num_layers
+    assert set(paged) == set(dense) and len(paged) == 5
+    for rid in dense:
+        np.testing.assert_array_equal(paged[rid].output, dense[rid].output)
+
+    assert paged_kv["needed"] == dense_kv["needed"] > 0
+    # the dense program is handed every row's whole table, the kernel a
+    # row's pages up to the key it wrote: never less than needed, never
+    # a page more than that a row
+    table_tokens = dense_eng.max_blocks_per_seq * 4
+    rows = dense_kv["gathered"] // table_tokens    # bucket rows, pads too
+    assert dense_kv["gathered"] == rows * table_tokens
+    assert paged_kv["needed"] < paged_kv["gathered"] \
+        <= paged_kv["needed"] + rows * 4
+    assert paged_kv["gathered"] < dense_kv["gathered"] / 2
+
+
+# ---------------------------------------------------------------------------
+# The kernel's own roofline, as the benchmark reads it from a trace
+# ---------------------------------------------------------------------------
+
+def _roofline_reader():
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import run as R
+    from benchmark.lib import peaks, readers, trace
+    return (root, R.load_reader(root, "kernels.paged_attention_roofline"),
+            peaks, readers, trace)
+
+
+def _serve_ctx(readers, peaks, tr, steps):
+    return readers.Ctx(
+        run={"traced": {"steps": steps}},
+        cfg={"num_layers": 2, "hidden_size": 2048}, mix={}, cell={}, chips=1,
+        peaks=peaks.PEAKS["TPU v5 lite"], trace=tr, win=(0.0, 1.0))
+
+
+def test_roofline_reader_counts_only_the_decode_programs_custom_calls():
+    """Two traced steps: a prefill program with a flash custom call and a
+    decode program with one kernel call a layer. The kernel's least time is
+    K and V of the rows' real contexts at the bandwidth peak; the flash call
+    and a decode program's other ops are not its time."""
+    _, read, peaks, readers, TR = _roofline_reader()
+    call = ('%paged_single_query_attention.{} = bf16[32,16,128] custom-call'
+            '(...), custom_call_target="tpu_custom_call"')
+    flash = '%flash.1 = bf16[16,512,128] custom-call(...), ' \
+            'custom_call_target="tpu_custom_call"'
+    ops = [TR.Ev(flash, 0.101, 0.002),
+           TR.Ev("%fusion.1 = bf16[1] fusion(...)", 0.111, 0.001),
+           TR.Ev(call.format(1), 0.112, 50e-6),
+           TR.Ev(call.format(2), 0.113, 50e-6),
+           TR.Ev(call.format(1), 0.212, 30e-6),
+           TR.Ev(call.format(2), 0.213, 30e-6)]
+    mods = [TR.Ev("jit_step(1)", 0.100, 0.005),     # the prefill
+            TR.Ev("jit_step(2)", 0.110, 0.005),     # its step's decode
+            TR.Ev("jit_step(2)", 0.210, 0.005)]
+    spans = [TR.Ev("bench.engine_step", 0.099, 0.02),
+             TR.Ev("bench.engine_step", 0.209, 0.02)]
+    tr = TR.Trace([TR.Device("/device:TPU:0", ops, mods)], spans)
+    steps = [{"prefills": [300], "decode_ctx": [301, 500]},
+             {"prefills": [], "decode_ctx": [302, 501]}]
+    got = read(_serve_ctx(readers, peaks, tr, steps))
+    keys = (301 + 500 + 302 + 501) * 2                 # a layer each
+    least = keys * 2 * 2048 * 2 / 819e9                # K and V, bf16
+    assert got["value"] == pytest.approx(100 * least / 160e-6, rel=1e-9)
+    assert got["bound"] == "memory"
+    assert got["calls"] == 4 and got["calls_per_program"] == 2
+    assert got["ms_per_call"] == pytest.approx(0.04)
+    # the gather-and-dense decode program has no such call: nothing to read
+    tr.devices[0].ops = [e for e in ops if "paged" not in e.name]
+    assert read(_serve_ctx(readers, peaks, tr, steps)) is None
+
+
+def test_roofline_reader_reads_nothing_in_a_trace_of_the_gather_program(
+        tmp_path):
+    """The trace the benchmark keeps, recorded on the chip before the kernel
+    existed (five decode programs of gathers and fusions): None, as at the
+    parent commit."""
+    import gzip
+    import os
+    root, read, peaks, readers, TR = _roofline_reader()
+    dst = tmp_path / "serve_2layer.xplane.pb"
+    with gzip.open(os.path.join(
+            root, "benchmark/testdata/serve_2layer.xplane.pb.gz")) as f:
+        dst.write_bytes(f.read())
+    tr = TR.load(str(dst))
+    steps = [{"prefills": [400, 900, 400, 900], "decode_ctx": [401] * 4}] \
+        + [{"prefills": [], "decode_ctx": [402 + i] * 4} for i in range(4)]
+    ctx = _serve_ctx(readers, peaks, tr, steps)
+    ctx.win = (tr.spans[0].start, tr.spans[-1].end)
+    assert len(readers.decode_programs(ctx)) == 5
+    assert read(ctx) is None

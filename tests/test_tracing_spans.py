@@ -129,16 +129,25 @@ def test_self_time_of_a_nest():
 
 
 def test_trace_view_has_the_same_self_time_and_stands_alone(tmp_path):
+    import gc
     import inspect
     from tools import trace_view
-    with trace.span("root"):
-        with trace.span("child"):
-            with trace.span("leaf"):
+    # an automatic collection between here and the export would add a
+    # ``host/gc`` record; whether one falls due depends on what the process
+    # has allocated so far, not on this test
+    gc.disable()
+    try:
+        trace.clear()
+        with trace.span("root"):
+            with trace.span("child"):
+                with trace.span("leaf"):
+                    pass
+            with trace.span("child"):
                 pass
-        with trace.span("child"):
-            pass
-    path = tmp_path / "ring.jsonl"
-    assert trace.export_jsonl(str(path)) == 4
+        path = tmp_path / "ring.jsonl"
+        assert trace.export_jsonl(str(path)) == 4
+    finally:
+        gc.enable()
     _, spans = trace_view.load_jsonl(str(path))
     assert trace_view.self_times(spans) == trace.self_times(spans)
     table = {r["span"]: r for r in trace_view.self_time_table(spans)}
