@@ -1,7 +1,8 @@
 """Plain reference of the GPT-3 decoder (Brown et al. 2020, after Radford et
 al. 2019): ``jax.numpy``, float32, ``highest`` matmul precision, no kernels,
 no cache, no remat. It imports nothing of the program and takes nothing the
-program has made; weights come from ``lib/weights.py``.
+program has made; weights come from the shared generator
+(``benchmark/lib/weights.py``) over this family's leaves (``weights.py``).
 
 The model: token plus learned position embedding; ``num_layers`` pre-LN
 blocks ``x += attn(ln_1(x)); x += mlp(ln_2(x))`` with causal softmax
@@ -33,6 +34,8 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmark.lib.weights import f32_weights, get_leaf
 
 from . import weights as W
 
@@ -103,10 +106,6 @@ def _row_loss(x, labels, wte, g, b, eps, mode):
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
     return jnp.sum(lse - picked)
-
-
-def f32_weights(tree):
-    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
 
 
 class Reference:
@@ -241,8 +240,8 @@ class Reference:
         """||p - p0|| of every leaf against the starting weights."""
         out = {}
         for name in W.leaf_names(self.cfg):
-            now = W.compared_parts(name, W.get_leaf(state["p"], name))
-            was = W.compared_parts(name, W.get_leaf(weights0, name))
+            now = W.compared_parts(name, get_leaf(state["p"], name))
+            was = W.compared_parts(name, get_leaf(weights0, name))
             for part in now:
                 out[part] = float(self._diff_norm(now[part], was[part]))
         return out
@@ -268,11 +267,3 @@ class Reference:
             x = self._block_jit(x, lp)
         return self._head(x, jnp.asarray(pos), p32["wte"], p32["lnf_g"],
                           p32["lnf_b"])
-
-
-def served_gaps(ref_logits, tokens) -> np.ndarray:
-    """For each served token, how far its reference logit lies below the
-    reference's best at that position (0 where the reference agrees)."""
-    lg = np.asarray(ref_logits, np.float32)[:len(tokens)]
-    tok = np.asarray(tokens)
-    return lg.max(axis=-1) - lg[np.arange(len(tok)), tok]

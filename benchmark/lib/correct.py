@@ -17,6 +17,15 @@ import numpy as np
 # change is not compared
 DEAD_GRAD_SHARE = 1e-3
 
+# the control of a comparison: the reference computed in the nearest
+# precision below the one that the configuration states
+NEXT_LOWER = {"float32": "bfloat16", "bfloat16": "float8",
+              "float16": "float8", "float8": "int4", "int8": "int4"}
+
+
+def control_mode(cfg: Dict) -> str:
+    return NEXT_LOWER[cfg["precision"]["compute"]]
+
 
 def worst_leaf_gap(prog: Dict[str, float], ref: Dict[str, float],
                    skip=()) -> Dict:
@@ -49,6 +58,14 @@ def train_numbers(prog: Dict, ref: Dict) -> Dict[str, Dict]:
     out["delta3_leaf_gap"] = worst_leaf_gap(
         prog["delta"], ref["delta"], skip=dead_leaves(ref["grad"]))
     return out
+
+
+def served_gaps(ref_logits, tokens) -> np.ndarray:
+    """For each served token, how far its reference logit lies below the
+    reference's best at that position (0 where the reference agrees)."""
+    lg = np.asarray(ref_logits, np.float32)[:len(tokens)]
+    tok = np.asarray(tokens)
+    return lg.max(axis=-1) - lg[np.arange(len(tok)), tok]
 
 
 def serve_numbers(gaps_by_request: List[np.ndarray]) -> Dict[str, Dict]:

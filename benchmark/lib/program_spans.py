@@ -1,8 +1,8 @@
 """The program's own account of a run: its span ring, its request records and
 its counters, cut to the measured window, for the readers of the
-``program_span`` and ``program_counter`` metrics. With ``lib/system.py`` this
-is the only file of the benchmark that imports the program, and the last; it
-takes records from it and decides nothing about them.
+``program_span`` and ``program_counter`` metrics. With ``lib/system.py`` and
+the families' adapters this is all of the benchmark that imports the program;
+it takes records from it and decides nothing about them.
 
 What the program keeps (``paddle_tpu/observability``, OBSERVABILITY.md): every
 ``eng.step()`` is a ``serve/step`` span with children ``serve/expire_shed``,

@@ -21,6 +21,7 @@ class Ctx:
     cell: Dict                # the cell's entry (and its file, if any)
     chips: int
     peaks: object             # lib.peaks.Peaks of the device
+    family: object = None     # lib.family.Family: ``needs`` for the counts
     trace: Optional[TR.Trace] = None
     win: Optional[TR.Interval] = None   # the traced stretch, trace clock
 

@@ -25,8 +25,6 @@ if ROOT not in sys.path:
 
 from benchmark import run as R  # noqa: E402
 
-CONTROL_MODE = "float8"   # the nearest precision below bfloat16
-
 
 def say(cell, seed, what, numbers):
     print(json.dumps({"workload": cell.name, "seed": seed, "what": what,
@@ -35,19 +33,21 @@ def say(cell, seed, what, numbers):
 
 def train_readings(cell, seeds, n_controls):
     from benchmark.lib import correct, system, traffic, weights
-    cfg = cell.cfg
+    fam, cfg = cell.family, cell.cfg
+    control = correct.control_mode(cfg)
     mesh = system.build_mesh(cell.extra.get("mesh"), cell.chips)
     ts, progs = None, {}
     for seed in seeds:
-        w0 = weights.make_weights(cfg, seed,
+        w0 = weights.make_weights(fam.weights, cfg, seed,
                                   out_shardings=system.replicated(mesh))
         if ts is None:
-            ts = system.build_train_step(cfg, w0, cfg["optimizer"], mesh)
+            ts = system.build_train_step(fam, cfg, w0, cfg["optimizer"],
+                                         mesh)
         else:
-            system.reset_train_step(ts, cfg, w0)
+            system.reset_train_step(ts, fam, cfg, w0)
         del w0
         pool = traffic.train_batches(cell.mix, cfg["vocab_size"], seed)
-        progs[seed] = R.first_three(ts, cfg, pool, seed)
+        progs[seed] = R.first_three(ts, cell, pool, seed)
     del ts
     gc.collect()
     half = slice(0, cell.mix["batch"] // 2)
@@ -56,8 +56,8 @@ def train_readings(cell, seeds, n_controls):
         ref = R.reference_three(cell, seed, pool, mesh)
         say(cell, seed, "program", correct.train_numbers(progs[seed], ref))
         if i < n_controls:
-            low = R.reference_three(cell, seed, pool, mesh, CONTROL_MODE)
-            say(cell, seed, "control:" + CONTROL_MODE,
+            low = R.reference_three(cell, seed, pool, mesh, control)
+            say(cell, seed, "control:" + control,
                 correct.train_numbers(low, ref))
             cut = R.reference_three(cell, seed, pool, mesh, rows=half)
             say(cell, seed, "fault:half_batch",
@@ -68,13 +68,15 @@ def train_readings(cell, seeds, n_controls):
 
 
 def serve_readings(cell, seeds, n_controls, seconds):
+    from benchmark.lib import correct
+    control = correct.control_mode(cell.cfg)
     counter = R.CompileCounter()
     for i, seed in enumerate(seeds):
         rec, _, check = R.run_serve(cell, seed, seconds, None, counter)
         say(cell, seed, "program", check())
         if i < n_controls:
-            say(cell, seed, "control:" + CONTROL_MODE,
-                R.check_serve(cell, seed, rec, CONTROL_MODE))
+            say(cell, seed, "control:" + control,
+                R.check_serve(cell, seed, rec, control))
         del rec, check
         gc.collect()
 

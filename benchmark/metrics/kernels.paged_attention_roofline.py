@@ -6,7 +6,14 @@ longer: the work, not what the kernel happens to read) over the summed device
 time of the ``tpu_custom_call`` events that fall inside the decode programs'
 intervals. Prefill's flash calls lie in prefill programs and are not counted.
 A program without such a call (the gather-and-dense decode) has nothing to
-read: None. ``calls_per_program`` should read the number of layers."""
+read: None. ``calls_per_program`` should read the number of layers.
+
+The count is the K/V-page kernel's, at the width of a cache that keeps K and
+V of ``hidden_size`` a token a layer, read from the configuration and not
+from its family's ``needs``: ``tests/test_paged_attention.py`` (the kernel's
+tests, outside the benchmark's paths) hands this reader a ``Ctx`` without a
+family. A cell whose cache is grouped or latent is not listed here and
+brings the reader of its own kernel (PERF.md, Open questions)."""
 from benchmark.lib import flops as F
 from benchmark.lib import trace as TR
 from benchmark.lib.readers import decode_programs

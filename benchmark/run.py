@@ -38,6 +38,7 @@ class Cell:
     cfg: Dict
     mix: Dict
     chips: int
+    family: object                               # lib.family.Family
     extra: Dict = field(default_factory=dict)    # benchmark/cells/<name>.json
     end_to_end: List[Dict] = field(default_factory=list)
     per_layer: List[Dict] = field(default_factory=list)
@@ -55,15 +56,17 @@ def load_cell(root: str, workload: str) -> Cell:
         raise SystemExit(f"no workload {workload!r} in BENCHMARK.json; have "
                          f"{[w['name'] for w in man['workloads']]}")
     conf = next(c for c in man["configs"] if c["name"] == entry["config"])
+    from benchmark.lib.family import load_family
     from benchmark.lib.traffic import load_traffic
+    cfg = _read_json(os.path.join(root, conf["file"]))
     cell_file = os.path.join(root, "benchmark", "cells", workload + ".json")
 
     def mine(metrics):
         return [m for m in metrics
                 if workload in m.get("workloads", [workload])]
     return Cell(
-        name=workload, cfg=_read_json(os.path.join(root, conf["file"])),
-        mix=load_traffic(root, entry["traffic"]), chips=entry["chips"],
+        name=workload, cfg=cfg, mix=load_traffic(root, entry["traffic"]),
+        chips=entry["chips"], family=load_family(root, cfg),
         extra=_read_json(cell_file) if os.path.exists(cell_file) else {},
         end_to_end=mine(man["end_to_end"]), per_layer=mine(man["per_layer"]))
 
@@ -106,35 +109,36 @@ def device_info(chips: int) -> Dict:
 
 # -- the two kinds of cell ---------------------------------------------------
 
-def first_three(ts, cfg, pool, seed: int, marks=None) -> Dict:
+def first_three(ts, cell: Cell, pool, seed: int, marks=None) -> Dict:
     """The object the window drives, through the window's own call, on its
     first three batches: each loss, the first gradient's norms as the
     optimizer's state holds them, the masters' change after the three. The
     reference follows the same three afterwards."""
     from benchmark.lib import system
-    beta1 = cfg["optimizer"]["beta1"]
+    fam, cfg, beta1 = cell.family, cell.cfg, cell.cfg["optimizer"]["beta1"]
     prog = {"losses": [float(ts.step(pool[0]))]}
     if marks is not None:       # tracing, lowering, compile or cache read
         marks["first_step_s"] = time.perf_counter() - T_START
-    prog["grad"] = system.train_state_norms(ts, cfg, beta1)
+    prog["grad"] = system.train_state_norms(ts, fam, cfg, beta1)
     prog["losses"] += [float(ts.step(pool[1])), float(ts.step(pool[2]))]
-    prog["delta"] = system.train_state_norms(ts, cfg, beta1, start_seed=seed)
+    prog["delta"] = system.train_state_norms(ts, fam, cfg, beta1,
+                                             start_seed=seed)
     return prog
 
 
 def run_train(cell: Cell, seed: int, seconds: float, trace_dir, counter):
     import jax
     from benchmark.lib import drive, system, traffic, weights
-    cfg, mix, opt = cell.cfg, cell.mix, cell.cfg["optimizer"]
+    fam, cfg, mix = cell.family, cell.cfg, cell.mix
     mesh = system.build_mesh(cell.extra.get("mesh"), cell.chips)
     marks = {"imports_s": time.perf_counter() - T_START}
-    w0 = weights.make_weights(cfg, seed,
+    w0 = weights.make_weights(fam.weights, cfg, seed,
                               out_shardings=system.replicated(mesh))
-    ts = system.build_train_step(cfg, w0, opt, mesh)
+    ts = system.build_train_step(fam, cfg, w0, cfg["optimizer"], mesh)
     del w0
     marks["build_s"] = time.perf_counter() - T_START
     pool = traffic.train_batches(mix, cfg["vocab_size"], seed)
-    prog = first_three(ts, cfg, pool, seed, marks)
+    prog = first_three(ts, cell, pool, seed, marks)
     setup_s = time.perf_counter() - T_START
     counter.armed = True
     rec = drive.train_window(ts.step, pool, 3, seconds, trace_dir)
@@ -154,21 +158,13 @@ def reference_three(cell: Cell, seed: int, pool, mesh, mode="float32",
     """The plain reference over the same first three batches. ``mode``,
     ``rows`` and ``frozen`` make the controls: a lower precision, part of the
     batch left out, a step that leaves its state unchanged."""
-    import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from benchmark.lib import reference_gpt, weights
-    cfg, opt = cell.cfg, cell.cfg["optimizer"]
-    shard = None
-    if mesh.size > 1:
-        # a 24-layer float32 state does not fit one chip: matrices are split
-        # by rows over the chips, which changes no arithmetic
-        flat = Mesh(mesh.devices.reshape(-1), ("x",))
-        shard = jax.tree_util.tree_map(
-            lambda s: NamedSharding(flat, PartitionSpec(
-                "x" if len(s) == 2 else None)), weights.leaf_shapes(cfg),
-            is_leaf=lambda x: isinstance(x, tuple))
-    ref = reference_gpt.Reference(cfg, mode)
-    w0 = weights.make_weights(cfg, seed, out_shardings=shard)
+    from benchmark.lib import weights
+    fam, cfg, opt = cell.family, cell.cfg, cell.cfg["optimizer"]
+    # the float32 state of a sharded cell does not fit one chip
+    shard = weights.row_sharded(fam.weights, cfg, mesh) \
+        if mesh.size > 1 else None
+    ref = fam.reference.Reference(cfg, mode)
+    w0 = weights.make_weights(fam.weights, cfg, seed, out_shardings=shard)
     state = ref.init_state(w0)
     hp = (0.0 if frozen else opt["learning_rate"], opt["beta1"],
           opt["beta2"], opt["epsilon"], opt["weight_decay"])
@@ -187,42 +183,17 @@ def check_train(cell: Cell, seed: int, pool, prog, mesh):
     return correct.train_numbers(prog, reference_three(cell, seed, pool, mesh))
 
 
-def warm_engine(eng, system, cfg, eng_cfg) -> None:
-    """Run every program this traffic uses once: each prefill bucket with one
-    prompt that lands in it, each decode bucket with as many rows as reach
-    it. Nothing else is warmed."""
-    import numpy as np
-    rng = np.random.default_rng(0)
-    n = 0
-
-    def go(lengths):
-        nonlocal n
-        for length in lengths:
-            ids = rng.integers(0, cfg["vocab_size"], size=length)
-            eng.submit(system.make_request(f"warm{n}", ids, 2))
-            n += 1
-        while eng.sched.n_pending:
-            eng.step()
-
-    edges = [0] + sorted(eng_cfg["prefill_buckets"])
-    go([max(lo + 1, 2) for lo in edges[:-1]])
-    rows, prev = len(edges) - 1, 0
-    for width in sorted(eng_cfg["decode_buckets"]):
-        if not prev < rows <= width:
-            go([2] * (prev + 1))
-        prev = width
-
-
 def run_serve(cell: Cell, seed: int, seconds: float, trace_dir, counter):
     import jax
     from benchmark.lib import drive, system, weights
-    cfg, mix = cell.cfg, cell.mix
+    fam, cfg, mix = cell.family, cell.cfg, cell.mix
     marks = {"imports_s": time.perf_counter() - T_START}
-    w0 = weights.make_weights(cfg, seed)
-    eng = system.build_engine(cfg, w0, mix["engine"])
+    w0 = weights.make_weights(fam.weights, cfg, seed)
+    eng = fam.adapter.build_engine(cfg, w0, mix["engine"])
     del w0
     marks["build_s"] = time.perf_counter() - T_START
-    warm_engine(eng, system, cfg, mix["engine"])
+    getattr(fam.adapter, "warm_engine", system.warm_engine)(
+        eng, cfg, mix["engine"])
     marks["warm_s"] = time.perf_counter() - T_START
     counter.armed = True
     rec = drive.serve_window(eng, system, mix, cfg["vocab_size"], seed,
@@ -256,14 +227,14 @@ def check_serve(cell: Cell, seed: int, rec: Dict, control: str = None):
     With ``control`` the served tokens give way to those that the reference
     in that lower precision puts first at each position."""
     import numpy as np
-    from benchmark.lib import correct, reference_gpt, weights
-    cfg, eng_cfg = cell.cfg, cell.mix["engine"]
+    from benchmark.lib import correct, weights
+    fam, cfg, eng_cfg = cell.family, cell.cfg, cell.mix["engine"]
     sample = serve_sample(cell, seed, rec["finished"])
     if not sample:
         return {}
-    ref = reference_gpt.Reference(cfg)
-    low = reference_gpt.Reference(cfg, control) if control else None
-    p32 = reference_gpt.f32_weights(weights.make_weights(cfg, seed))
+    ref = fam.reference.Reference(cfg)
+    low = fam.reference.Reference(cfg, control) if control else None
+    p32 = weights.f32_weights(weights.make_weights(fam.weights, cfg, seed))
     max_out = cell.mix["output_len"]["hi"]
     gaps = []
     for r in sample:
@@ -272,11 +243,30 @@ def check_serve(cell: Cell, seed: int, rec: Dict, control: str = None):
         if low is not None:
             tokens = np.argmax(np.asarray(low.served_logits(*args)),
                                axis=-1)[:len(tokens)]
-        gaps.append(reference_gpt.served_gaps(logits, tokens))
+        gaps.append(correct.served_gaps(logits, tokens))
     return correct.serve_numbers(gaps)
 
 
 # -- one run -------------------------------------------------------------------
+
+def window_work(rec: Dict) -> Dict:
+    """How much work fell into the window, beside the phases' times: when a
+    rate differs between two runs, whether the work differed or the time."""
+    if rec["kind"] == "train_steps":
+        return {"steps": rec["steps"]}
+    steps = rec["steps"]
+    tenth = rec["window_s"] / 10.0
+    by_tenth = [0] * 10
+    for s in steps:     # a slow run: slow throughout, or one stall?
+        by_tenth[min(9, int((s["t1"] - rec["t_open"]) / tenth))] += 1
+    return {"steps": len(steps), "steps_by_tenth": by_tenth,
+            "prefills": sum(len(s["prefills"]) for s in steps),
+            "prefill_tokens": sum(sum(s["prefills"]) for s in steps),
+            "decode_tokens": sum(len(s["decode_ctx"]) for s in steps),
+            "decode_ctx_tokens": sum(sum(s["decode_ctx"]) for s in steps),
+            "in_step_s": sum(s["t1"] - s["t0"] for s in steps),
+            "finished": len(rec["finished"])}
+
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              root: str = ROOT, require_chip: bool = True) -> Dict:
@@ -306,7 +296,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     ctx = readers.Ctx(
         run=rec, cfg=cell.cfg, mix=cell.mix, cell=cell.extra,
-        chips=cell.chips,
+        chips=cell.chips, family=cell.family,
         peaks=peaks.peaks_of(dev["kind"]) if platform == "tpu" else None)
     breakdown = None
     if trace and "traced" in rec:
@@ -332,7 +322,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     t_read = time.perf_counter()
     numbers = check()
     phases = {"setup_marks": rec["setup_marks"], "setup_s": rec["setup_s"],
-              "window_s": rec["window_s"],
+              "window_s": rec["window_s"], "work": window_work(rec),
               "reading_s": t_read - t_window_done,
               "reference_s": time.perf_counter() - t_read}
     numbers["compiles_in_window"] = {"value": counter.n}

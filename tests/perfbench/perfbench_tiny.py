@@ -13,6 +13,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import run as R  # noqa: E402
+from benchmark.lib.family import load_family  # noqa: E402
 
 TRAIN_CELL = "train.gpt3-1.3b-l12.b4s2048"
 SERVE_CELL = "serve.gpt3-1.3b.batch-closed"
@@ -72,5 +73,5 @@ def tiny_cell(mix, like):
         cfg.update(hidden_size=128, head_dim=64, intermediate_size=512,
                    vocab_size=16384)
     extra = {"check": {"sample": 3}, "limits": TINY_LIMITS[mix["kind"]]}
-    return R.Cell("tiny." + mix["kind"], cfg, mix, 1, extra,
-                  mine(man["end_to_end"]), mine(man["per_layer"]))
+    return R.Cell("tiny." + mix["kind"], cfg, mix, 1, load_family(ROOT, cfg),
+                  extra, mine(man["end_to_end"]), mine(man["per_layer"]))

@@ -1,4 +1,5 @@
-"""The yardstick's arithmetic: FLOP and byte counts against values worked by
+"""The yardstick's arithmetic: FLOP and byte counts (the GPT family's
+``needs`` and the kernel-level ``lib/flops.py``) against values worked by
 hand for both configurations, and the trace reduction against a small trace
 recorded on the chip (TPU v5e, 2-layer engine at the real widths: four
 prefills and five decode steps under ``bench.*`` spans)."""
@@ -12,6 +13,7 @@ import pytest
 from perfbench_tiny import ROOT
 
 from benchmark.lib import flops as F
+from benchmark.lib.family import load_family
 from benchmark.lib import peaks as P
 from benchmark.lib import trace as TR
 
@@ -21,38 +23,42 @@ def cfg(name):
         return json.load(f)
 
 
+def needs(name):
+    return load_family(ROOT, cfg(name)).needs
+
+
 @pytest.mark.parametrize("name,matmul,everything", [
     ("gpt3-1.3b", 1_310_982_144, 1_315_819_520),
     ("gpt3-1.3b-l12", 707_002_368, 711_520_256),
 ])
 def test_parameter_counts(name, matmul, everything):
-    assert F.matmul_params(cfg(name)) == matmul
-    assert F.n_params(cfg(name)) == everything
+    assert needs(name).matmul_params(cfg(name)) == matmul
+    assert needs(name).n_params(cfg(name)) == everything
 
 
 @pytest.mark.parametrize("name,per_token", [
     ("gpt3-1.3b", 8_470_167_552), ("gpt3-1.3b-l12", 4_544_151_552)])
 def test_train_flops_per_token(name, per_token):
-    assert F.train_flops_per_token(cfg(name), 2048) == per_token
+    assert needs(name).train_flops_per_token(cfg(name), 2048) == per_token
 
 
 def test_decode_step_counts_real_context_not_max_seq_len():
-    c = cfg("gpt3-1.3b")
-    assert F.weight_bytes(c) == 2_623_250_432
-    flops, nbytes = F.decode_step_needs(c, [1000] * 32)
+    c, N = cfg("gpt3-1.3b"), needs("gpt3-1.3b")
+    assert N.weight_bytes(c) == 2_623_250_432
+    flops, nbytes = N.decode_step_needs(c, [1000] * 32)
     assert flops == 90_194_313_216
     assert nbytes == 2_623_250_432 + 32_000 * 196_608
     t, bound = F.roofline_seconds(flops, nbytes, P.peaks_of("TPU v5 lite"))
     assert bound == "memory" and t == pytest.approx(10.885e-3, rel=1e-3)
     # a row's bytes follow its own context: twice the context, twice the K/V
-    _, b2 = F.decode_step_needs(c, [2000] * 32)
-    assert b2 - F.weight_bytes(c) == 2 * (nbytes - F.weight_bytes(c))
+    _, b2 = N.decode_step_needs(c, [2000] * 32)
+    assert b2 - N.weight_bytes(c) == 2 * (nbytes - N.weight_bytes(c))
 
 
 def test_serve_flops_of_one_prefill_and_one_decode():
-    c = cfg("gpt3-1.3b")
-    assert F.attn_flops_causal(c, 512) == 1_075_838_976
-    assert F.serve_flops(c, [(512, 0)], [513]) == 1_265_699_586_048
+    c, N = cfg("gpt3-1.3b"), needs("gpt3-1.3b")
+    assert N.attn_flops_causal(c, 512) == 1_075_838_976
+    assert N.serve_flops(c, [(512, 0)], [513]) == 1_265_699_586_048
 
 
 @pytest.mark.parametrize("kind,flops,nbytes", [

@@ -11,7 +11,7 @@ from perfbench_tiny import (CLOSED, SEED, SERVE_CELL, TRAIN, TRAIN_CELL,
                             tiny_cell)
 
 from benchmark import run as R
-from benchmark.lib import correct, system, traffic
+from benchmark.lib import correct, system, traffic, weights
 
 
 def _run(cell):
@@ -28,6 +28,7 @@ def test_fp8_control_fails_the_training_limits():
     pool = traffic.train_batches(cell.mix, cell.cfg["vocab_size"], SEED)
     mesh = system.build_mesh(None, 1)
     ref = R.reference_three(cell, SEED, pool, mesh)
+    assert correct.control_mode(cell.cfg) == "float8"
     low = R.reference_three(cell, SEED, pool, mesh, "float8")
     ok, shown = correct.judge(correct.train_numbers(low, ref),
                               cell.extra["limits"])
@@ -37,8 +38,8 @@ def test_fp8_control_fails_the_training_limits():
 def test_unchanged_state_is_not_correct(monkeypatch):
     real = system.build_train_step
 
-    def frozen(cfg, weights, opt_cfg, mesh):
-        return real(cfg, weights, dict(opt_cfg, learning_rate=0.0), mesh)
+    def frozen(fam, cfg, weights, opt_cfg, mesh):
+        return real(fam, cfg, weights, dict(opt_cfg, learning_rate=0.0), mesh)
     monkeypatch.setattr(system, "build_train_step", frozen)
     res = _run(tiny_cell(TRAIN, TRAIN_CELL))
     assert res["correct"] is False
@@ -53,8 +54,9 @@ def test_half_a_batch_is_not_correct(monkeypatch):
         n = ids.shape[0] // 2
         return functional_call(model, params, ids[:n], labels[:n],
                                training=True)
-    monkeypatch.setattr(system, "loss_fn", half)
-    res = _run(tiny_cell(TRAIN, TRAIN_CELL))
+    cell = tiny_cell(TRAIN, TRAIN_CELL)
+    monkeypatch.setattr(cell.family.adapter, "loss_fn", half)
+    res = _run(cell)
     assert res["correct"] is False, res["compared"]
 
 
@@ -62,9 +64,10 @@ def _served_to_the_end(cell, n_requests):
     """``n_requests`` of the mix served until the engine is idle: the same
     requests and tokens on every run, where a timed window on a shared CPU
     finishes now these, now those."""
-    from benchmark.lib import weights
-    eng = system.build_engine(cell.cfg, weights.make_weights(cell.cfg, SEED),
-                              cell.mix["engine"])
+    fam = cell.family
+    eng = fam.adapter.build_engine(
+        cell.cfg, weights.make_weights(fam.weights, cell.cfg, SEED),
+        cell.mix["engine"])
     stream = traffic.request_stream(cell.mix, cell.cfg["vocab_size"], SEED)
     served = []
     for i in range(n_requests):
@@ -95,7 +98,8 @@ def test_sound_serve_run_is_correct():
 
 
 def test_an_altered_token_is_not_correct(monkeypatch):
-    real = system.build_engine
+    cell = tiny_cell(CLOSED, SERVE_CELL)
+    real = cell.family.adapter.build_engine
 
     def tampering(cfg, weights, eng_cfg):
         eng = real(cfg, weights, eng_cfg)
@@ -106,6 +110,30 @@ def test_an_altered_token_is_not_correct(monkeypatch):
             return (tok + 1) % cfg["vocab_size"], k, v
         eng._decode_fn = altered
         return eng
-    monkeypatch.setattr(system, "build_engine", tampering)
-    res = _run(tiny_cell(CLOSED, SERVE_CELL))
+    monkeypatch.setattr(cell.family.adapter, "build_engine", tampering)
+    res = _run(cell)
     assert res["correct"] is False, res["compared"]
+
+
+@pytest.mark.parametrize("mix,like", [(TRAIN, TRAIN_CELL),
+                                      (CLOSED, SERVE_CELL)])
+def test_limits_readings_say_program_control_and_faults(mix, like, capsys):
+    """``benchmark/limits.py`` at a tiny size: one line a reading, the
+    control's mode taken from the configuration's precision."""
+    import json
+    from benchmark import limits
+    cell = tiny_cell(mix, like)
+    if mix["kind"] == "train_steps":
+        limits.train_readings(cell, [SEED, SEED + 1], 1)
+        want = ["program", "control:float8", "fault:half_batch",
+                "fault:state_unchanged", "program"]
+    else:
+        limits.serve_readings(cell, [SEED], 1, 1.0)
+        want = ["program", "control:float8"]
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    assert [x["what"] for x in lines] == want
+    assert all(x["workload"] == cell.name and x["numbers"] for x in lines)
+    if mix["kind"] == "train_steps":
+        assert lines[3]["numbers"]["delta3_leaf_gap"]["value"] == \
+            pytest.approx(1.0)

@@ -8,6 +8,9 @@ import pytest
 
 from perfbench_tiny import ROOT, manifest
 
+from benchmark.lib.family import families_found, load_family
+from benchmark.lib.weights import get_leaf
+
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -87,7 +90,26 @@ def test_reduced_lists_what_the_file_says_it_changed():
             cfg = json.load(f)
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
         assert not set(c["reduced"]) & set(widths)
-        assert cfg["hidden_size"] == cfg["num_heads"] * cfg["head_dim"]
+        if cfg["model"] == "gpt":
+            assert cfg["hidden_size"] == cfg["num_heads"] * cfg["head_dim"]
+
+
+def test_every_configuration_names_a_family_that_is_there():
+    """``"model"`` in a configuration's file names a directory under
+    ``benchmark/families`` that holds all four roles; the family can say the
+    shapes and the counts of the configuration without the program."""
+    import json
+    man = manifest()
+    for c in man["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["model"] in families_found(ROOT)
+        fam = load_family(ROOT, cfg)
+        shapes = fam.weights.leaf_shapes(cfg)
+        for name in fam.weights.leaf_names(cfg):
+            assert isinstance(get_leaf(shapes, name), tuple), name
+        assert fam.needs.weight_bytes(cfg) > 0
+        assert fam.needs.train_flops_per_token(cfg, 2048) > 0
 
 
 def test_moves_and_cells_line_up():

@@ -37,8 +37,8 @@ def test_entry_has_reader_source_and_cells(name):
     assert entry["source"] == want
     cells = ([SERVE_CELL] if name in SERVE_NEW else []) + \
         ([TRAIN_CELL] if name in TRAIN_NEW else [])
-    assert sorted(entry["workloads"]) == sorted(
-        cells or [SERVE_CELL, TRAIN_CELL])
+    # a later cell may be listed too; these have to be
+    assert set(cells or [SERVE_CELL, TRAIN_CELL]) <= set(entry["workloads"])
     read = R.load_reader(ROOT, name)
     assert callable(read)
     # a program with nothing to read: nothing, not an error
