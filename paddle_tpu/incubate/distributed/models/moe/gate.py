@@ -2,9 +2,10 @@
 
 ref: ``python/paddle/incubate/distributed/models/moe/gate/`` —
 {naive,gshard,switch}_gate.py. Each gate scores tokens over experts and
-produces (combine_weights, dispatch_mask, aux_loss) in the capacity-bucketed
-einsum formulation (the TPU-native dense dispatch, GShard-style) rather than
-the reference's sparse scatter."""
+returns ``(idx [T, k], weight [T, k], aux_loss)``: the experts each token
+goes to and the weight of each. There is no capacity: the layer is dropless
+(``dropless.py``), so a gate never cuts a token off; ``capacity_factor`` is
+accepted for the reference's signature and unused."""
 
 from __future__ import annotations
 
@@ -12,56 +13,42 @@ import jax
 import jax.numpy as jnp
 
 from ..... import nn
-from .....nn import functional as F
 from .....core.random import next_key
 
 __all__ = ["NaiveGate", "GShardGate", "SwitchGate"]
 
 
-def _top1_dispatch(logits, capacity: int):
-    """Common top-1 capacity-bucketed dispatch.
-
-    Returns combine [G, S, E, C], dispatch bool [G, S, E, C], aux loss.
-    G=groups(batch), S=tokens/group, E=experts, C=capacity.
-    """
-    g, s, e = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    expert_idx = jnp.argmax(probs, axis=-1)              # [G, S]
-    expert_mask = jax.nn.one_hot(expert_idx, e)          # [G, S, E]
-    # position of each token within its expert's queue
-    pos_in_expert = (jnp.cumsum(expert_mask, axis=1) - 1.0) * expert_mask
-    keep = pos_in_expert < capacity
-    expert_mask = expert_mask * keep
-    gate_val = (probs * expert_mask).sum(-1)             # [G, S]
-    # aux load-balance loss (GShard eq.)
-    density = expert_mask.mean(axis=1)                   # [G, E]
-    density_proxy = probs.mean(axis=1)
-    aux = (density * density_proxy).sum(-1).mean() * (e * e)
-    pos = jax.nn.one_hot((pos_in_expert.sum(-1)).astype(jnp.int32), capacity)
-    combine = (gate_val[..., None, None] * expert_mask[..., None] *
-               pos[:, :, None, :])                        # [G,S,E,C]
-    dispatch = combine > 0
-    return combine.astype(logits.dtype), dispatch, aux
+def _balance_loss(probs, top1):
+    """GShard's load-balance loss: the share of tokens whose first choice an
+    expert is, times its mean probability, summed and scaled by E^2."""
+    e = probs.shape[-1]
+    density = jnp.mean(jax.nn.one_hot(top1, e), axis=0)
+    return jnp.sum(density * jnp.mean(probs, axis=0)) * e
 
 
 class _GateBase(nn.Layer):
-    def __init__(self, d_model: int, num_experts: int, capacity_factor: float = 1.25):
+    top_k = 1
+
+    def __init__(self, d_model: int, num_experts: int,
+                 capacity_factor: float = 1.25):
         super().__init__()
         self.num_experts = num_experts
         self.capacity_factor = capacity_factor
         self.weight = self.create_parameter((d_model, num_experts))
 
-    def capacity(self, tokens_per_group: int) -> int:
-        return max(4, int(self.capacity_factor * tokens_per_group /
-                          self.num_experts))
+    def _probs(self, x):
+        logits = jnp.matmul(x, self.weight)
+        return jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+
+    def forward(self, x):
+        """x [T, d] -> (idx [T, k], weight [T, k], aux)."""
+        probs = self._probs(x)
+        weight, idx = jax.lax.top_k(probs, self.top_k)
+        return idx, weight, _balance_loss(probs, idx[:, 0])
 
 
 class NaiveGate(_GateBase):
-    """ref naive_gate.py: plain top-1, no noise."""
-
-    def forward(self, x):
-        logits = jnp.matmul(x, self.weight)
-        return _top1_dispatch(logits, self.capacity(x.shape[1]))
+    """ref naive_gate.py: plain top-1 weighted by its probability."""
 
 
 class SwitchGate(_GateBase):
@@ -74,44 +61,19 @@ class SwitchGate(_GateBase):
 
     def forward(self, x):
         if self.training and self.jitter > 0:
-            noise = jax.random.uniform(next_key(), x.shape, minval=1 - self.jitter,
+            noise = jax.random.uniform(next_key(), x.shape,
+                                       minval=1 - self.jitter,
                                        maxval=1 + self.jitter)
             x = x * noise.astype(x.dtype)
-        logits = jnp.matmul(x, self.weight)
-        return _top1_dispatch(logits, self.capacity(x.shape[1]))
+        return super().forward(x)
 
 
 class GShardGate(_GateBase):
-    """ref gshard_gate.py: top-2 with capacity + second-expert sampling."""
+    """ref gshard_gate.py: top-2, the two weights normalised to sum 1."""
+    top_k = 2
 
     def forward(self, x):
-        g, s, _ = x.shape
-        e = self.num_experts
-        cap = self.capacity(s) * 2
-        logits = jnp.matmul(x, self.weight)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        top1 = jnp.argmax(probs, axis=-1)
-        mask1 = jax.nn.one_hot(top1, e)
-        probs2 = probs * (1 - mask1)
-        top2 = jnp.argmax(probs2, axis=-1)
-        mask2 = jax.nn.one_hot(top2, e)
-        # capacity positions: experts fill from top1 stream then top2 stream
-        pos1 = (jnp.cumsum(mask1, axis=1) - 1.0) * mask1
-        used = mask1.sum(axis=1, keepdims=True)
-        pos2 = (jnp.cumsum(mask2, axis=1) - 1.0) * mask2 + used * mask2
-        keep1 = pos1 < cap
-        keep2 = pos2 < cap
-        mask1 = mask1 * keep1
-        mask2 = mask2 * keep2
-        w1 = (probs * mask1).sum(-1)
-        w2 = (probs * mask2).sum(-1)
-        denom = jnp.clip(w1 + w2, 1e-9, None)
-        w1, w2 = w1 / denom, w2 / denom
-        density = mask1.mean(axis=1)
-        density_proxy = probs.mean(axis=1)
-        aux = (density * density_proxy).sum(-1).mean() * (e * e)
-        p1 = jax.nn.one_hot(pos1.sum(-1).astype(jnp.int32), cap)
-        p2 = jax.nn.one_hot(pos2.sum(-1).astype(jnp.int32), cap)
-        combine = (w1[..., None, None] * mask1[..., None] * p1[:, :, None, :] +
-                   w2[..., None, None] * mask2[..., None] * p2[:, :, None, :])
-        return combine.astype(x.dtype), combine > 0, aux
+        idx, weight, aux = super().forward(x)
+        weight = weight / jnp.clip(jnp.sum(weight, axis=-1, keepdims=True),
+                                   1e-9, None)
+        return idx, weight, aux

@@ -107,6 +107,12 @@ def _pick_blocks(sq: int, sk: int, d: int) -> tuple:
         # swept on the 254M GPT bench step (B16 S1024 H8): 1024/1024 =
         # 221.6ms vs 512/512 = 229.4ms (fewer grid steps, bigger MXU tiles)
         tq, tk = 1024, 1024
+    elif d <= 256:
+        # swept on the latent prefill's padded head (192 -> 256, 128 heads,
+        # causal forward; my chip run, PR 30): at S 4096 512/512 = 13.1 ms,
+        # 1024/1024 = 13.2, 256/512 = 21.6, 128/256 = 44.4; at S 1024
+        # 1.94 / 1.85 / 2.48 / 4.02
+        tq, tk = 512, 512
     else:
         tq, tk = 128, 256
 
@@ -326,9 +332,13 @@ def _bias_or_dummy(bias, b, sk):
 
 def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
          seg_q=None, seg_k=None, dropout=0.0, seed=None, bias=None):
-    """q: [BH, S, D]; k,v: [B*HK, S, D] (+ optional [BH, 1, S] int32
-    segment ids) -> (o [BH, Sq, D], lse [BH, 1, Sq] fp32)."""
+    """q: [BH, S, D]; k: [B*HK, S, D]; v: [B*HK, S, DV] (+ optional
+    [BH, 1, S] int32 segment ids) -> (o [BH, Sq, DV], lse [BH, 1, Sq]
+    fp32). ``DV`` is ``D`` wherever a backward follows; the forward alone
+    takes values of another head size (latent attention: 192-wide keys
+    padded to 256 beside 128-wide values)."""
     bh, sq, d = q.shape
+    dv = v.shape[-1]
     sk = k.shape[1]
     h = num_heads
     hk = k.shape[0] // (bh // h)
@@ -364,7 +374,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
             pl.BlockSpec((1, block_k, d),
                          lambda b, p, t: kv_index(b, qi_of(b, p, t),
                                                   kj_of(b, p, t))),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, dv),
                          lambda b, p, t: kv_index(b, qi_of(b, p, t),
                                                   kj_of(b, p, t))),
             pl.BlockSpec((1, 1, block_q),
@@ -377,7 +387,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
                          (b // _h, 0, kj_of(b, p, t))),
         ]
         out_specs = [
-            pl.BlockSpec((1, block_q, d),
+            pl.BlockSpec((1, block_q, dv),
                          lambda b, p, t: (b, qi_of(b, p, t), 0)),
             pl.BlockSpec((1, 1, block_q),
                          lambda b, p, t: (b, 0, qi_of(b, p, t))),
@@ -387,7 +397,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
         in_specs = [
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -395,7 +405,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
                          lambda b, i, j, _h=num_heads: (b // _h, 0, j)),
         ]
         out_specs = [
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ]
     o, lse = pl.pallas_call(
@@ -404,16 +414,16 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),  # output accumulator
         ],
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * sq * sk * d // (2 if causal else 1),
+            flops=2 * bh * sq * sk * (d + dv) // (2 if causal else 1),
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=bh * sq * sk,
         ),
@@ -990,11 +1000,13 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
     b, sq, h, d = query.shape
     sk = key.shape[1]
     hk = key.shape[2]
+    dv = value.shape[3]
     # Head-packed fast path for d=64 dense-head shapes (VERDICT r4 #3):
     # G heads per program on the lane axis — G-fold fewer programs, full-
     # lane DMAs. Skipped when the caller pins blocks (kernel sweeps/tests
     # target a specific grid of the unpacked kernel).
-    if (block_q is None and block_k is None and d == 64 and hk == h
+    if (block_q is None and block_k is None and d == 64 and dv == d
+            and hk == h
             and sq % 128 == 0 and sk % 128 == 0
             and int(_flags.flag("flash_head_pack"))):
         from .flash_attention_packed import (flash_attention_packed,
@@ -1026,7 +1038,7 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
     def to_bhsd(x, s, heads):
-        return x.transpose(0, 2, 1, 3).reshape(b * heads, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * heads, s, x.shape[3])
 
     # Grouped-query KV stays [B*HK, S, D]: the kernels' BlockSpec index map
     # routes each query head to its shared KV tile (no repeat materialized).
@@ -1059,6 +1071,16 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
     bias = None
     if key_bias is not None:
         bias = jnp.asarray(key_bias, jnp.float32).reshape(b, 1, sk)
-    o = _flash_bhsd(q, k, v, seg_q, seg_k, seed, bias, float(scale),
-                    bool(causal), block_q, block_k, h, float(dropout))
-    return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    if dv != d:
+        # values of another head size than the keys: the forward alone (the
+        # backward kernels take one size), so no gradient and no dropout
+        if dropout > 0.0:
+            raise ValueError(
+                f"flash_attention_pallas: values of head size {dv} beside "
+                f"keys of {d} run forward only, without dropout")
+        o, _ = _fwd(q, k, v, float(scale), bool(causal), block_q, block_k, h,
+                    seg_q, seg_k, 0.0, seed, bias)
+    else:
+        o = _flash_bhsd(q, k, v, seg_q, seg_k, seed, bias, float(scale),
+                        bool(causal), block_q, block_k, h, float(dropout))
+    return o.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)

@@ -25,7 +25,18 @@ from ..core import flags
 
 __all__ = ["flash_attention", "flash_attn_unpadded", "reference_attention",
            "single_query_attention", "paged_single_query_attention",
-           "takes_paged_kernel"]
+           "takes_paged_kernel", "multi_query_attention",
+           "latent_attention", "latent_paged_attention"]
+
+
+def _masked_softmax(scores, dtype):
+    """Softmax over the last axis of float32 ``scores`` in which masked
+    entries are ``-inf``; a row masked throughout gives zeros, not NaN."""
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    e = jnp.where(jnp.isfinite(scores),
+                  jnp.exp(scores - jnp.where(jnp.isfinite(m), m, 0.0)), 0.0)
+    return (e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True),
+                            1e-30)).astype(dtype)
 
 
 def reference_attention(q, k, v, causal: bool = False,
@@ -50,11 +61,7 @@ def reference_attention(q, k, v, causal: bool = False,
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     # Masked-row-safe softmax: fully-masked rows (all -inf) produce 0, not
     # NaN — matching the Pallas kernels' handling.
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    e = jnp.where(jnp.isfinite(scores),
-                  jnp.exp(scores - jnp.where(jnp.isfinite(m), m, 0.0)), 0.0)
-    probs = (e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True),
-                             1e-30)).astype(q.dtype)
+    probs = _masked_softmax(scores, q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
@@ -92,11 +99,7 @@ def single_query_attention(q, k, v, lengths=None,
         valid = jnp.arange(sk)[None, :] < jnp.asarray(lengths)[:, None]
         scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
     # Masked-row-safe softmax, matching reference_attention.
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    e = jnp.where(jnp.isfinite(scores),
-                  jnp.exp(scores - jnp.where(jnp.isfinite(m), m, 0.0)), 0.0)
-    probs = (e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True),
-                             1e-30)).astype(q.dtype)
+    probs = _masked_softmax(scores, q.dtype)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, v)
     return out.reshape(b, 1, h, d)
 
@@ -107,22 +110,34 @@ def _platform_of(x) -> str:
     return jax.default_backend()
 
 
-def takes_paged_kernel(q_dtype, k_pool) -> bool:
-    """Does decode attention over ``k_pool`` (``[..., NB, bs, KH, D]``) with
-    queries of ``q_dtype`` take the paged Pallas kernel? As ``_use_pallas``:
-    on a TPU with the flag on and a shape the kernel takes; an unsupported
-    shape ON a TPU is announced once (P005). The serving engine asks too,
-    to count what its decode program reads."""
+def takes_paged_kernel(q_dtype, k_pool, latent_value_dim=None) -> bool:
+    """Does decode attention over ``k_pool`` with queries of ``q_dtype`` take
+    its paged Pallas kernel? ``k_pool`` is a K (or V) pool ``[..., NB, bs,
+    KH, D]`` or, with ``latent_value_dim`` (the part of a row that is its
+    value), a latent pool ``[..., NB, bs, W]``. As ``_use_pallas``: on a TPU
+    with the flag on and a shape the kernel takes; an unsupported shape ON a
+    TPU is announced once (P005). The serving engine asks too, to count what
+    its decode program reads."""
     if not flags.flag("use_pallas_kernels") or _platform_of(k_pool) != "tpu":
+        return False
+    from ..analysis.pallas_check import report_fallback
+    shape = (f"q {jnp.dtype(q_dtype).name} pool{tuple(k_pool.shape)} "
+             f"{k_pool.dtype}")
+    if latent_value_dim is not None:
+        from ._pallas.latent_paged_attention import supported_shapes
+        if supported_shapes(q_dtype, k_pool, latent_value_dim):
+            return True
+        report_fallback(
+            "latent_paged_attention",
+            f"{shape} value_dim {latent_value_dim}",
+            "needs bf16 queries and pool, the row and value_dim multiples "
+            "of 128 and block_size a multiple of 16")
         return False
     from ._pallas.paged_attention import supported_shapes
     if supported_shapes(q_dtype, k_pool):
         return True
-    from ..analysis.pallas_check import report_fallback
     report_fallback(
-        "paged_single_query_attention",
-        f"q {jnp.dtype(q_dtype).name} pool{tuple(k_pool.shape)} "
-        f"{k_pool.dtype}",
+        "paged_single_query_attention", shape,
         "needs bf16 queries and pool, head_dim 128, block_size and kv "
         "heads multiples of 16")
     return False
@@ -165,6 +180,81 @@ def paged_single_query_attention(q, k_pool, v_pool, tables, lengths, *,
     vals = v_pool[tables].reshape(b, mx, *v_pool.shape[2:])
     return single_query_attention(q, keys, vals, lengths=lengths,
                                   scale=scale)
+
+
+def multi_query_attention(q, k, v, pos, scale: Optional[float] = None):
+    """Offset-causal attention for the serving ``extend`` step: ``q`` is
+    ``[B, L, H, D]`` (L query tokens at absolute positions ``pos``
+    [B, L]); ``k``/``v`` are ``[B, Sk, KH, D]`` gathered pages. Query
+    ``(b, i)`` attends keys ``j <= pos[b, i]`` (its own KV was
+    scattered before the gather, so self-attention is included exactly
+    like the decode step's ``lengths = pos + 1`` mask). Same GQA head
+    reshape, f32 score accumulation, and masked-row-safe softmax as
+    :func:`single_query_attention` (numeric agreement with the decode path
+    is what keeps chunked / speculative outputs token-exact against
+    ``model.generate``)."""
+    b, L, h, d = q.shape
+    sk, kh = k.shape[1], k.shape[2]
+    g = h // kh
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    qg = q.reshape(b, L, kh, g, d)
+    scores = jnp.einsum("blkgd,bskd->blkgs", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(sk)[None, None, :] <= pos[:, :, None]   # [B, L, Sk]
+    scores = jnp.where(valid[:, :, None, None, :], scores, -jnp.inf)
+    probs = _masked_softmax(scores, q.dtype)
+    out = jnp.einsum("blkgs,bskd->blkgd", probs, v)
+    return out.reshape(b, L, h, d)
+
+
+def latent_attention(q, rows, pos, *, value_dim: int, scale: float):
+    """Absorbed latent (MLA) attention, dense: ``q [B, L, H, W]`` (each
+    head's absorbed query ``[q_nope W_UK^T | q_rope]``) over the latent rows
+    ``rows [B, Sk, W]`` (``[c_kv | k_rope]`` a token, the same for every
+    head). Query ``(b, i)`` attends rows ``j <= pos[b, i]``; the value of a
+    row is its first ``value_dim`` entries. Returns ``[B, L, H, value_dim]``.
+    f32 scores and masked-row-safe softmax as
+    :func:`single_query_attention`; this is the path off the chip and what
+    the Pallas kernel is checked against."""
+    scores = jnp.einsum("blhw,bsw->blhs", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(rows.shape[1])[None, None, :] <= pos[:, :, None]
+    scores = jnp.where(valid[:, :, None, :], scores, -jnp.inf)
+    probs = _masked_softmax(scores, q.dtype)
+    return jnp.einsum("blhs,bsv->blhv", probs, rows[..., :value_dim])
+
+
+def latent_paged_attention(q, pool, tables, lengths, *, block_size: int,
+                           value_dim: int, scale: float, layer=0):
+    """Decode-step absorbed latent attention read through block tables:
+    ``q [B, 1, H, W]`` against the latent rows that ``tables [B, M]`` names
+    in ``pool`` (the engine's ``[L, NB, block_size, W]`` with ``layer`` the
+    layer to read, or one layer's ``[NB, block_size, W]``), row ``b`` up to
+    its first ``lengths[b]`` rows (0: the row returns 0). Returns
+    ``[B, 1, H, value_dim]``.
+
+    On a TPU, for the shapes
+    ``_pallas.latent_paged_attention.supported_shapes`` takes, this is the
+    Pallas kernel: each row's pages are fetched from HBM up to its own
+    length and no gathered copy exists. Everywhere else it is the dense path
+    the kernel is checked against: gather every table's pages, then
+    :func:`latent_attention` behind a length mask."""
+    if pool.shape[-2] != block_size:
+        raise ValueError(f"pool pages hold {pool.shape[-2]} tokens, "
+                         f"block_size says {block_size}")
+    if takes_paged_kernel(q.dtype, pool, value_dim):
+        from ._pallas.latent_paged_attention import \
+            latent_paged_attention_pallas
+        return latent_paged_attention_pallas(
+            q, pool, tables, lengths, value_dim=value_dim, scale=scale,
+            layer=layer)
+    if pool.ndim == 4:
+        pool = pool[layer]
+    b = q.shape[0]
+    rows = pool[tables].reshape(b, tables.shape[1] * block_size,
+                                pool.shape[-1])
+    return latent_attention(q, rows, (jnp.asarray(lengths) - 1)[:, None],
+                            value_dim=value_dim, scale=scale)
 
 
 def _use_pallas(q, k) -> bool:

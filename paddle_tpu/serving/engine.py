@@ -37,15 +37,15 @@ byte-identical when off):
   first mismatch), with accepted-length histograms feeding the
   autotune cache's choice of gamma.
 
-The prefill step runs the model's flash-attention forward on one
-bucket-padded prompt and scatters the per-layer K/V into the sequence's
-pages; the decode step is a batched single-query pass that writes the
-new token's KV and attends each sequence's pages through its block table
-up to its own context (``ops.flash_attention.paged_single_query_attention``:
-a Pallas kernel on a TPU, gather + ``single_query_attention`` behind a
-length mask elsewhere); the ``extend`` step is the multi-token generalization
-(offset-causal over gathered pages) shared by chunked prefill, suffix
-prefill after a prefix hit, and speculative verification. Executables
+The prefill step runs the model's attention forward on one
+bucket-padded prompt and scatters each layer's page rows into the
+sequence's pages; the decode step is a batched single-query pass that
+writes the new token's page row and attends each sequence's pages through
+its block table up to its own context (a Pallas kernel on a TPU, gather +
+dense attention behind a length mask elsewhere); the ``extend`` step is the
+multi-token generalization (offset-causal over gathered pages) shared by
+chunked prefill, suffix prefill after a prefix hit, and speculative
+verification. Executables
 take the page pool **donated** — the pool is updated in place, never
 copied — and the whole dispatch sequence is declared as a
 :class:`~paddle_tpu.analysis.plan_check.StepPlan` so the
@@ -55,9 +55,48 @@ verify the serving path like every training tier (``lint_graph --model
 serving``). At runtime the same isolation is asserted per dispatch:
 no scatter ever targets a device block the prefix tree holds.
 
-Works with any ``GPTForCausalLM``-shaped model (``.gpt.wte/wpe/h/ln_f``,
-``.logits``); decoding is greedy (argmax), matching ``model.generate``'s
-default.
+**One decode iteration is always in flight.** ``step()`` ends by launching
+the decode program over the resident rows and returns without waiting; the
+next ``step()`` admits (its prefills queue behind that program), takes the
+tokens, commits them, tops up blocks and launches again. So the device runs
+the decode while the host returns finished requests, takes new ones and
+builds their prefills, instead of idling through all of that; what stays
+between two device programs is the wake-up after the wait, the commit and
+the build of the next launch. Host state is fully committed before each
+launch (nothing is guessed about who finishes); the one thing that can
+happen to a row while its token is in flight is that it is cancelled or
+preempted, and then the token is dropped and computed again if the row
+comes back (``_decode_collect``). A request joins the batch at the launch
+after its prefill, so its second token is seen one step after its first.
+
+**The model seam.** The three programs know no model: they ask the model
+for its layer step and for what it caches a token. A model serves by giving
+(``text/models/gpt.py`` and ``text/models/deepseek_v2.py`` both do):
+
+- ``serve_cache_rows()``: the shapes of a token's page rows, one a pool:
+  keys and values a head ``((KH, D), (KH, D))``, or one latent row
+  ``((W,),)`` (:mod:`.paged_cache` builds one pool a row);
+  ``serve_dtype()``; ``serve_latent_value_dim`` (None, or where the pool is
+  latent the part of a row that is its value);
+- ``serve_embed(ids, pos)``, ``serve_layers()``, ``serve_final_norm(x)``,
+  ``logits(hidden)``;
+- a layer: ``serve_project(x, pos) -> (q, rows)`` (the queries and the
+  token's page rows), ``serve_attend_prefill(q, rows)`` (causal, over the
+  prompt itself), ``serve_attend_paged(q, pools, tables, lengths,
+  block_size, layer)`` (one query a row, through the block table),
+  ``serve_attend_extend(q, pools, tables, pos, block_size, layer)``
+  (several queries a row, offset-causal) and ``serve_finish(x, o, real) ->
+  (x, counts or None)`` (the rest of the block);
+- ``serve_counts`` (0, or how many int32 counts the layers' ``serve_finish``
+  return): the prefill and decode programs then return them behind the
+  token, in the one array the host already waits for, and the engine hands
+  them to ``model.serve_record_counts(counts, n_real_tokens)`` (the
+  expert-load counters of a routed-expert model).
+
+The engine writes the rows into the pools, keeps the block tables, and
+calls no model by name; scheduler, allocator, spill, spans and counters are
+the same for every model. Decoding is greedy (argmax), matching
+``model.generate``'s default.
 """
 
 from __future__ import annotations
@@ -66,7 +105,8 @@ import math
 import time
 import types
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Sequence as Seq, Union
+from typing import (Any, Callable, Dict, List, Optional, Sequence as Seq,
+                    Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -79,9 +119,7 @@ from ..observability import live as fleet_live
 from ..observability import metrics, request_timeline, trace
 from ..observability.request_timeline import percentile
 from ..observability.step_monitor import RecompileSentinel
-from ..ops.flash_attention import (flash_attention,
-                                   paged_single_query_attention,
-                                   takes_paged_kernel)
+from ..ops.flash_attention import takes_paged_kernel
 from .buckets import BucketSet, pow2_buckets, pad_axis
 from .paged_cache import (NULL_BLOCK, OutOfBlocksError, PagedKVCache,
                           SpillError)
@@ -155,35 +193,6 @@ def _account(t0_ns: int, end_ns: int, phase: str, seqs) -> None:
         seq.add_phase(phase, dur_s)
 
 
-def _multi_query_attention(q, k, v, pos):
-    """Offset-causal attention for the ``extend`` step: ``q`` is
-    ``[B, L, H, D]`` (L query tokens at absolute positions ``pos``
-    [B, L]); ``k``/``v`` are ``[B, Sk, KH, D]`` gathered pages. Query
-    ``(b, i)`` attends keys ``j <= pos[b, i]`` — its own KV was
-    scattered before the gather, so self-attention is included exactly
-    like the decode step's ``lengths = pos + 1`` mask. Same GQA head
-    reshape, f32 score accumulation, and masked-row-safe softmax as
-    :func:`~paddle_tpu.ops.flash_attention.single_query_attention`
-    (numeric agreement with the decode path is what keeps chunked /
-    speculative outputs token-exact against ``model.generate``)."""
-    b, L, h, d = q.shape
-    sk, kh = k.shape[1], k.shape[2]
-    g = h // kh
-    scale = 1.0 / math.sqrt(d)
-    qg = q.reshape(b, L, kh, g, d)
-    scores = jnp.einsum("blkgd,bskd->blkgs", qg, k,
-                        preferred_element_type=jnp.float32) * scale
-    valid = jnp.arange(sk)[None, None, :] <= pos[:, :, None]   # [B, L, Sk]
-    scores = jnp.where(valid[:, :, None, None, :], scores, -jnp.inf)
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    e = jnp.where(jnp.isfinite(scores),
-                  jnp.exp(scores - jnp.where(jnp.isfinite(m), m, 0.0)), 0.0)
-    probs = (e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True),
-                             1e-30)).astype(q.dtype)
-    out = jnp.einsum("blkgs,bskd->blkgd", probs, v)
-    return out.reshape(b, L, h, d)
-
-
 class _ParamJit:
     """``jax.jit`` of a step that reads ``model``'s weights, with the
     weights an explicit leading argument of the compiled program.
@@ -203,9 +212,11 @@ class _ParamJit:
             with _swapped_state(model, params, None):
                 return raw(*args)
 
-        # every step is (tokens, k_pages, v_pages, ...): the two page
-        # pools are donated (raw args 1, 2 — behind the weights: 2, 3)
-        self.jitted = jax.jit(step, donate_argnums=(2, 3))
+        # every step is (tokens, *pools, ...): the model's page pools are
+        # donated (raw args 1.. — behind the weights: 2..)
+        n_pools = len(model.serve_cache_rows())
+        self.jitted = jax.jit(step,
+                              donate_argnums=tuple(range(2, 2 + n_pools)))
 
     def __call__(self, *args):
         return self.jitted(self.params, *args)
@@ -309,25 +320,24 @@ class ServingEngine:
         self.spec_stats = {"iterations": 0, "proposed": 0, "accepted": 0}
 
         # -- device state ----------------------------------------------------
-        act_dtype = model.gpt.wte.weight.dtype
-        head_dim = cfg.hidden_size // cfg.num_heads
-        self.cache = PagedKVCache(cfg.num_layers, num_blocks,
-                                  self.block_size, cfg.kv_heads, head_dim,
-                                  dtype=act_dtype)
+        self.cache = self._pool_for(model, num_blocks)
+        self._n_pools = len(self.cache.pools)
+        #: counts the decode and prefill programs return behind the token
+        #: (0: none), handed to ``model.serve_record_counts``
+        self._n_counts = int(model.serve_counts)
         if isinstance(self.drafter, ModelDrafter):
             dcfg = self.drafter.model.cfg
             if int(dcfg.vocab_size) != int(cfg.vocab_size):
                 raise ValueError(
                     f"drafter vocab {dcfg.vocab_size} != target vocab "
                     f"{cfg.vocab_size}")
-            self._draft_cache = PagedKVCache(
-                dcfg.num_layers, num_blocks, self.block_size,
-                dcfg.kv_heads, dcfg.hidden_size // dcfg.num_heads,
-                dtype=self.drafter.model.gpt.wte.weight.dtype)
+            self._draft_cache = self._pool_for(self.drafter.model,
+                                               num_blocks)
         #: whether the decode program reads pages through the kernel (what
         #: ``serving.kv_tokens{kind=gathered}`` then counts)
-        self._decode_paged = takes_paged_kernel(self.cache.dtype,
-                                                self.cache.k)
+        self._decode_paged = takes_paged_kernel(
+            self.cache.dtype, self.cache.pools[0],
+            model.serve_latent_value_dim)
         self.prefix = PrefixCache(self.cache, mirror=self._draft_cache) \
             if self.prefix_on else None
         self.sched = FCFSScheduler(max_batch, max_waiting=max_waiting)
@@ -355,6 +365,9 @@ class ServingEngine:
             maxlen=shed_policy.window if shed_policy else 64)
         self._p99: Optional[float] = None   # of _decode_ms, as of _p99_at
         self._p99_at = -1
+        #: the decode iteration in flight: launched at the end of a step,
+        #: its tokens taken at the next (_decode_iteration, _decode_collect)
+        self._ahead: Optional[Tuple] = None
         # a shed policy ACTS on the decode iteration's duration, so its two
         # spans measure in every telemetry mode
         self._acted_span = trace.timed_span if shed_policy is not None \
@@ -410,70 +423,114 @@ class ServingEngine:
     # The bucketed executables
     # ------------------------------------------------------------------
 
-    def _make_prefill(self):
-        m = self.model
-        bs = self.block_size
+    def _pool_for(self, model, num_blocks: int) -> PagedKVCache:
+        """The page pools ``model`` asks for: one a row of its cache spec."""
+        return PagedKVCache(len(model.serve_layers()), num_blocks,
+                            self.block_size, dtype=model.serve_dtype(),
+                            rows=model.serve_cache_rows())
 
-        def prefill(ids, k_pages, v_pages, block_ids, n_tokens):
-            """ids [1, S] bucket-padded; block_ids [S//bs] (null-padded);
-            n_tokens: true prompt length. Writes the prompt KV into the
-            pages and returns the first generated token."""
+    @property
+    def _donated(self):
+        """Positions of the pools among a step's arguments."""
+        return tuple(range(1, 1 + self._n_pools))
+
+    def _undonated(self, args):
+        """A step's arguments but the pools (what its sentinel watches)."""
+        return (args[0],) + tuple(args[1 + self._n_pools:])
+
+    def _take_counts(self, out: np.ndarray, n_tok: int, n_real: int):
+        """Split what a prefill or decode program returned beside the pools
+        into its token(s) and, for a model that counts
+        (``model.serve_counts``), the counts behind them, which go to
+        ``model.serve_record_counts`` with the number of real tokens the
+        program ran."""
+        if not self._n_counts:
+            return out
+        self.model.serve_record_counts(out[n_tok:], n_real)
+        return out[:n_tok]
+
+    @staticmethod
+    def _with_counts(tok, counts):
+        """The program's one result beside the pools: the token(s), and
+        behind them the sum of the counts the model's layers gave (none:
+        the token alone), so that they ride the token's transfer."""
+        if not counts:
+            return tok
+        return jnp.concatenate([tok.reshape(-1),
+                                sum(counts).astype(jnp.int32)])
+
+    def _make_prefill(self, model=None):
+        m = model if model is not None else self.model
+        bs = self.block_size
+        n_pools = len(m.serve_cache_rows())
+        counted = bool(m.serve_counts)
+
+        def prefill(ids, *rest):
+            """ids [1, S] bucket-padded; then the pools; block_ids [S//bs]
+            (null-padded); n_tokens: true prompt length. Writes the
+            prompt's page rows and returns the first generated token."""
+            pools, (block_ids, n_tokens) = list(rest[:n_pools]), \
+                rest[n_pools:]
             s = ids.shape[1]
             pos = jnp.arange(s)[None, :]
-            x = m.gpt.wte(ids) + m.gpt.wpe(pos)
-            for li, blk in enumerate(m.gpt.h):
-                xn = blk.ln_1(x)
-                q, k, v = blk.attn._project_qkv(xn)
-                o = flash_attention(q, k, v, causal=True, training=False)
-                kv_shape = (s // bs, bs) + k.shape[2:]
-                k_pages = k_pages.at[li, block_ids].set(
-                    k[0].reshape(kv_shape).astype(k_pages.dtype))
-                v_pages = v_pages.at[li, block_ids].set(
-                    v[0].reshape(kv_shape).astype(v_pages.dtype))
-                x = x + blk.attn.out_proj(o.reshape(1, s, -1))
-                x = x + blk.mlp(blk.ln_2(x))
-            hidden = m.gpt.ln_f(x)
+            real = pos < n_tokens if counted else None
+            counts = []
+            x = m.serve_embed(ids, pos)
+            for li, layer in enumerate(m.serve_layers()):
+                q, rows = layer.serve_project(x, pos)
+                o = layer.serve_attend_prefill(q, rows)
+                for pi, row in enumerate(rows):
+                    shape = (s // bs, bs) + row.shape[2:]
+                    pools[pi] = pools[pi].at[li, block_ids].set(
+                        row[0].reshape(shape).astype(pools[pi].dtype))
+                x, c = layer.serve_finish(x, o, real)
+                if c is not None:
+                    counts.append(c)
+            hidden = m.serve_final_norm(x)
             last = jax.lax.dynamic_index_in_dim(hidden, n_tokens - 1,
                                                 axis=1, keepdims=True)
             logits = m.logits(last)[0, 0]
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return tok, k_pages, v_pages
+            return (self._with_counts(tok, counts), *pools)
 
         return prefill
 
     def _make_decode(self, model=None):
         m = model if model is not None else self.model
         bs = self.block_size
+        n_pools = len(m.serve_cache_rows())
+        counted = bool(m.serve_counts)
 
-        def decode(tokens, k_pages, v_pages, tables, ctx_lens):
-            """tokens [B] (each sequence's latest token, not yet in KV);
-            tables [B, M] null-padded block tables; ctx_lens [B] tokens
-            already cached (0 = inactive pad row, which harmlessly
-            writes the null block and produces a discarded output).
-            One iteration: write each token's KV at position ctx_len,
-            attend over ctx_len+1 keys, return the next token."""
-            b = tokens.shape[0]
+        def decode(tokens, *rest):
+            """tokens [B] (each sequence's latest token, not yet cached);
+            then the pools; tables [B, M] null-padded block tables;
+            ctx_lens [B] tokens already cached (0 = inactive pad row, which
+            harmlessly writes the null block and produces a discarded
+            output). One iteration: write each token's page row at position
+            ctx_len, attend over ctx_len+1 rows, return the next token."""
+            pools, (tables, ctx_lens) = list(rest[:n_pools]), rest[n_pools:]
             pos = ctx_lens
-            x = m.gpt.wte(tokens[:, None]) + m.gpt.wpe(pos[:, None])
+            real = (ctx_lens > 0)[:, None] if counted else None
+            counts = []
+            pos_col = pos[:, None]
+            x = m.serve_embed(tokens[:, None], pos_col)
             bi = jnp.take_along_axis(tables, (pos // bs)[:, None],
                                      axis=1)[:, 0]
             si = pos % bs
-            for li, blk in enumerate(m.gpt.h):
-                xn = blk.ln_1(x)
-                q, k, v = blk.attn._project_qkv(xn)
-                k_pages = k_pages.at[li, bi, si].set(
-                    k[:, 0].astype(k_pages.dtype))
-                v_pages = v_pages.at[li, bi, si].set(
-                    v[:, 0].astype(v_pages.dtype))
-                o = paged_single_query_attention(
-                    q, k_pages, v_pages, tables, pos + 1, block_size=bs,
-                    layer=li)
-                x = x + blk.attn.out_proj(o.reshape(b, 1, -1))
-                x = x + blk.mlp(blk.ln_2(x))
-            hidden = m.gpt.ln_f(x)
+            for li, layer in enumerate(m.serve_layers()):
+                q, rows = layer.serve_project(x, pos_col)
+                for pi, row in enumerate(rows):
+                    pools[pi] = pools[pi].at[li, bi, si].set(
+                        row[:, 0].astype(pools[pi].dtype))
+                o = layer.serve_attend_paged(q, pools, tables, pos + 1, bs,
+                                             li)
+                x, c = layer.serve_finish(x, o, real)
+                if c is not None:
+                    counts.append(c)
+            hidden = m.serve_final_norm(x)
             logits = m.logits(hidden)[:, 0]
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return tok, k_pages, v_pages
+            return (self._with_counts(tok, counts), *pools)
 
         return decode
 
@@ -483,41 +540,40 @@ class ServingEngine:
         different (B, L) buckets. ``last_only=True`` (the chunk/prefill
         form) projects logits for only each row's final real token —
         the verify form needs the argmax at EVERY position for the
-        accept-prefix rule, the chunk form only the next token."""
+        accept-prefix rule, the chunk form only the next token. It returns
+        no counts (those ride the prefill and decode programs only)."""
         m = model
         bs = self.block_size
+        n_pools = len(m.serve_cache_rows())
 
-        def extend(tokens, k_pages, v_pages, tables, ctx_lens, n_real):
-            """tokens [B, L]; tables [B, M] null-padded; ctx_lens [B]
-            tokens already cached per row; n_real [B] real tokens in
-            this dispatch (padded slots scatter into the null block).
-            Writes tokens[b, i]'s KV at position ctx_lens[b] + i and
-            returns the greedy argmax — [B, L] (every query) or [B]
+        def extend(tokens, *rest):
+            """tokens [B, L]; then the pools; tables [B, M] null-padded;
+            ctx_lens [B] tokens already cached per row; n_real [B] real
+            tokens in this dispatch (padded slots scatter into the null
+            block). Writes tokens[b, i]'s page row at position ctx_lens[b]
+            + i and returns the greedy argmax — [B, L] (every query) or [B]
             (each row's last real query) under ``last_only``."""
+            pools, (tables, ctx_lens, n_real) = list(rest[:n_pools]), \
+                rest[n_pools:]
             b, L = tokens.shape
-            mx = tables.shape[1] * bs
             pos = ctx_lens[:, None] + jnp.arange(L)[None, :]       # [B, L]
             real = jnp.arange(L)[None, :] < n_real[:, None]        # [B, L]
             pos_q = jnp.where(real, pos, 0)
-            x = m.gpt.wte(tokens) + m.gpt.wpe(pos_q)
+            x = m.serve_embed(tokens, pos_q)
             bi = jnp.take_along_axis(
                 tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1),
                 axis=1)
             bi = jnp.where(real, bi, NULL_BLOCK)
             si = pos % bs
-            for li, blk in enumerate(m.gpt.h):
-                xn = blk.ln_1(x)
-                q, k, v = blk.attn._project_qkv(xn)
-                k_pages = k_pages.at[li, bi, si].set(
-                    k.astype(k_pages.dtype))
-                v_pages = v_pages.at[li, bi, si].set(
-                    v.astype(v_pages.dtype))
-                keys = k_pages[li][tables].reshape(b, mx, *k.shape[2:])
-                vals = v_pages[li][tables].reshape(b, mx, *v.shape[2:])
-                o = _multi_query_attention(q, keys, vals, pos_q)
-                x = x + blk.attn.out_proj(o.reshape(b, L, -1))
-                x = x + blk.mlp(blk.ln_2(x))
-            hidden = m.gpt.ln_f(x)
+            for li, layer in enumerate(m.serve_layers()):
+                q, rows = layer.serve_project(x, pos_q)
+                for pi, row in enumerate(rows):
+                    pools[pi] = pools[pi].at[li, bi, si].set(
+                        row.astype(pools[pi].dtype))
+                o = layer.serve_attend_extend(q, pools, tables, pos_q, bs,
+                                              li)
+                x, _ = layer.serve_finish(x, o, None)
+            hidden = m.serve_final_norm(x)
             if last_only:
                 idx = jnp.maximum(n_real - 1, 0)[:, None, None]
                 last = jnp.take_along_axis(
@@ -528,7 +584,7 @@ class ServingEngine:
             else:
                 logits = m.logits(hidden)
                 toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return toks, k_pages, v_pages
+            return (toks, *pools)
 
         return extend
 
@@ -605,38 +661,40 @@ class ServingEngine:
         b0 = self.decode_buckets.sizes[0]
         c = self.cache
         m_blocks = self.max_blocks_per_seq
-        pages = jax.ShapeDtypeStruct(c.k.shape, c.k.dtype)
+        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.pools]
+        donated = self._donated
         i32 = jnp.int32
         pre = jax.make_jaxpr(self._prefill_raw)(
-            jax.ShapeDtypeStruct((1, s0), i32), pages, pages,
+            jax.ShapeDtypeStruct((1, s0), i32), *pages,
             jax.ShapeDtypeStruct((s0 // self.block_size,), i32),
             jax.ShapeDtypeStruct((), i32))
         dec = jax.make_jaxpr(self._decode_raw)(
-            jax.ShapeDtypeStruct((b0,), i32), pages, pages,
+            jax.ShapeDtypeStruct((b0,), i32), *pages,
             jax.ShapeDtypeStruct((b0, m_blocks), i32),
             jax.ShapeDtypeStruct((b0,), i32))
-        out = {"prefill": (pre, (1, 2)), "decode": (dec, (1, 2))}
+        out = {"prefill": (pre, donated), "decode": (dec, donated)}
         if self._chunk_raw is not None:
             out["extend"] = (jax.make_jaxpr(self._chunk_raw)(
-                jax.ShapeDtypeStruct((1, s0), i32), pages, pages,
+                jax.ShapeDtypeStruct((1, s0), i32), *pages,
                 jax.ShapeDtypeStruct((1, m_blocks), i32),
                 jax.ShapeDtypeStruct((1,), i32),
-                jax.ShapeDtypeStruct((1,), i32)), (1, 2))
+                jax.ShapeDtypeStruct((1,), i32)), donated)
         if self._verify_raw is not None:
             L = self.spec_gamma + 1
             out["verify"] = (jax.make_jaxpr(self._verify_raw)(
-                jax.ShapeDtypeStruct((b0, L), i32), pages, pages,
+                jax.ShapeDtypeStruct((b0, L), i32), *pages,
                 jax.ShapeDtypeStruct((b0, m_blocks), i32),
                 jax.ShapeDtypeStruct((b0,), i32),
-                jax.ShapeDtypeStruct((b0,), i32)), (1, 2))
+                jax.ShapeDtypeStruct((b0,), i32)), donated)
         if self._draft_cache is not None:
-            dpages = jax.ShapeDtypeStruct(self._draft_cache.k.shape,
-                                          self._draft_cache.k.dtype)
+            dpages = [jax.ShapeDtypeStruct(p.shape, p.dtype)
+                      for p in self._draft_cache.pools]
             out["draft"] = (jax.make_jaxpr(
                 self._make_decode(self.drafter.model))(
-                    jax.ShapeDtypeStruct((b0,), i32), dpages, dpages,
+                    jax.ShapeDtypeStruct((b0,), i32), *dpages,
                     jax.ShapeDtypeStruct((b0, m_blocks), i32),
-                    jax.ShapeDtypeStruct((b0,), i32)), (1, 2))
+                    jax.ShapeDtypeStruct((b0,), i32)),
+                tuple(range(1, 1 + len(dpages))))
         return out
 
     def compile_decode(self):
@@ -649,13 +707,13 @@ class ServingEngine:
         module must compile with zero collectives (X001)."""
         b0 = self.decode_buckets.sizes[0]
         c = self.cache
-        pages = jax.ShapeDtypeStruct(c.k.shape, c.k.dtype)
+        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.pools]
         i32 = jnp.int32
         compiled = self._decode_fn.lower(
-            jax.ShapeDtypeStruct((b0,), i32), pages, pages,
+            jax.ShapeDtypeStruct((b0,), i32), *pages,
             jax.ShapeDtypeStruct((b0, self.max_blocks_per_seq), i32),
             jax.ShapeDtypeStruct((b0,), i32)).compile()
-        return compiled, 2
+        return compiled, len(pages)
 
     def compile_extend(self, verify: bool = False):
         """AOT lower+compile the extend executable (chunk signature, or
@@ -666,18 +724,18 @@ class ServingEngine:
             raise ValueError("extend executable not armed (enable "
                              "prefix_cache/chunked_prefill/speculative)")
         c = self.cache
-        pages = jax.ShapeDtypeStruct(c.k.shape, c.k.dtype)
+        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.pools]
         i32 = jnp.int32
         if verify:
             b, L = self.decode_buckets.sizes[0], self.spec_gamma + 1
         else:
             b, L = 1, self.prefill_buckets.sizes[0]
         compiled = fn.lower(
-            jax.ShapeDtypeStruct((b, L), i32), pages, pages,
+            jax.ShapeDtypeStruct((b, L), i32), *pages,
             jax.ShapeDtypeStruct((b, self.max_blocks_per_seq), i32),
             jax.ShapeDtypeStruct((b,), i32),
             jax.ShapeDtypeStruct((b,), i32)).compile()
-        return compiled, 2
+        return compiled, len(pages)
 
     def _maybe_lint(self) -> None:
         """FLAGS_static_analysis hook: on first dispatch, lint every
@@ -1116,21 +1174,23 @@ class ServingEngine:
                 ids = pad_axis(seq.request.prompt_ids[None, :], 1, bucket)
                 btab = np.full((nb_bucket,), NULL_BLOCK, np.int32)
                 btab[:len(block_ids)] = block_ids
-                args = (jnp.asarray(ids, jnp.int32), self.cache.k,
-                        self.cache.v, jnp.asarray(btab),
+                args = (jnp.asarray(ids, jnp.int32), *self.cache.pools,
+                        jnp.asarray(btab),
                         jnp.asarray(seq.prompt_len, jnp.int32))
                 self._maybe_lint()
                 self._assert_cow(block_ids)
                 self._sent_prefill.observe_tree(
-                    "serving.prefill", (args[0], args[3], args[4]),
-                    donate=(1, 2), where="serving.prefill")
+                    "serving.prefill", self._undonated(args),
+                    donate=self._donated, where="serving.prefill")
             with trace.span("serve/prefill/launch"):
-                tok, k2, v2 = self._prefill_fn(*args)
+                tok, *pools = self._prefill_fn(*args)
             with trace.span("serve/prefill/wait") as wait:
-                tok = int(tok)  # host sync: the first token exists now
+                # host sync: the first token exists now
+                tok = int(self._take_counts(
+                    np.asarray(tok), 1, seq.prompt_len).reshape(-1)[0])
             _account(sp.t0_ns, wait.end_ns, "prefill", (seq,))
             with trace.span("serve/prefill/commit"):
-                self.cache.swap(k2, v2)
+                self.cache.swap(*pools)
                 seq.block_ids = list(block_ids)
                 seq.block_log.extend(block_ids)
                 seq.ctx_len = seq.prompt_len
@@ -1171,27 +1231,27 @@ class ServingEngine:
                 table = np.full((1, self.max_blocks_per_seq), NULL_BLOCK,
                                 np.int32)
                 table[0, :len(seq.block_ids)] = seq.block_ids
-                args = (jnp.asarray(toks, jnp.int32), self.cache.k,
-                        self.cache.v, jnp.asarray(table),
+                args = (jnp.asarray(toks, jnp.int32), *self.cache.pools,
+                        jnp.asarray(table),
                         jnp.asarray([start], jnp.int32),
                         jnp.asarray([span], jnp.int32))
                 self._maybe_lint()
                 self._assert_cow(self._write_span_ids(seq, start, span))
                 self._sent_chunk.observe_tree(
-                    "serving.extend", (args[0], args[3], args[4], args[5]),
-                    donate=(1, 2), where="serving.extend")
+                    "serving.extend", self._undonated(args),
+                    donate=self._donated, where="serving.extend")
             with trace.span("serve/prefill/launch"):
-                out, k2, v2 = self._chunk_fn(*args)
+                out, *pools = self._chunk_fn(*args)
             with trace.span("serve/prefill/wait") as wait:
                 out = np.asarray(out)   # host sync: the chunk is done
             _account(sp.t0_ns, wait.end_ns, "chunk_prefill", (seq,))
             with trace.span("serve/prefill/commit"):
-                self.cache.swap(k2, v2)
+                self.cache.swap(*pools)
                 if self._draft_extend_fn is not None:
-                    dargs = (args[0], self._draft_cache.k,
-                             self._draft_cache.v, args[3], args[4], args[5])
-                    _, dk, dv = self._draft_extend_fn(*dargs)
-                    self._draft_cache.swap(dk, dv)
+                    _, *dpools = self._draft_extend_fn(
+                        args[0], *self._draft_cache.pools,
+                        *args[1 + self._n_pools:])
+                    self._draft_cache.swap(*dpools)
                     seq.draft_ctx = start + span
                 seq.prefill_pos = start + span
                 seq.ctx_len = seq.prefill_pos
@@ -1224,11 +1284,11 @@ class ServingEngine:
         toks = pad_axis(seq.request.prompt_ids[None, :], 1, L)
         table = np.full((1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
         table[0, :len(seq.block_ids)] = seq.block_ids
-        _, dk, dv = self._draft_extend_fn(
-            jnp.asarray(toks, jnp.int32), self._draft_cache.k,
-            self._draft_cache.v, jnp.asarray(table),
+        _, *dpools = self._draft_extend_fn(
+            jnp.asarray(toks, jnp.int32), *self._draft_cache.pools,
+            jnp.asarray(table),
             jnp.asarray([0], jnp.int32), jnp.asarray([p], jnp.int32))
-        self._draft_cache.swap(dk, dv)
+        self._draft_cache.swap(*dpools)
         seq.draft_ctx = p
 
     def _chunk_iteration(self) -> None:
@@ -1366,12 +1426,18 @@ class ServingEngine:
                     self._cancel(victim, Status.FAILED,
                                  f"KV spill failed: {e}", diagnose=True)
 
-    def _decode_iteration(self) -> List[Sequence]:
+    def _decode_iteration(self) -> None:
+        """Build and launch one decode iteration over the decodable rows and
+        return without waiting: the program runs while the host finishes
+        this step, the caller submits, and the next step admits and builds
+        its prefills. :meth:`_decode_collect` takes its tokens at the next
+        step. (Speculation keeps its own iteration, which waits.)"""
         batch = self._decodable()
         if not batch:
-            return []
+            return
         if self.spec_gamma:
-            return self._spec_iteration(batch)
+            self._spec_iteration(batch)
+            return
         rows = len(batch)
         width = self.decode_buckets.fit(rows)
         m_blocks = self.max_blocks_per_seq
@@ -1381,41 +1447,62 @@ class ServingEngine:
                 tokens = np.zeros((width,), np.int32)
                 tables = np.full((width, m_blocks), NULL_BLOCK, np.int32)
                 lens = np.zeros((width,), np.int32)
+                tokens[:rows] = [seq.out_tokens[-1] for seq in batch]
+                lens[:rows] = [seq.ctx_len for seq in batch]
                 for i, seq in enumerate(batch):
-                    tokens[i] = seq.out_tokens[-1]
-                    tables[i, :len(seq.block_ids)] = seq.block_ids
-                    lens[i] = seq.ctx_len
-                args = (jnp.asarray(tokens), self.cache.k, self.cache.v,
-                        jnp.asarray(tables), jnp.asarray(lens))
+                    tables[i] = seq.table_row(m_blocks, NULL_BLOCK)
+                tokens_d, tables_d, lens_d = jax.device_put(
+                    (tokens, tables, lens))
+                args = (tokens_d, *self.cache.pools, tables_d, lens_d)
             with trace.span("serve/decode/checks"):
                 self._maybe_lint()
-                for seq in batch:
-                    self._assert_cow(
-                        self._write_span_ids(seq, seq.ctx_len, 1))
+                if self.prefix is not None:
+                    for seq in batch:
+                        self._assert_cow(
+                            self._write_span_ids(seq, seq.ctx_len, 1))
                 self._sent_decode.observe_tree(
-                    "serving.decode", (args[0], args[3], args[4]),
-                    donate=(1, 2), where="serving.decode")
+                    "serving.decode", self._undonated(args),
+                    donate=self._donated, where="serving.decode")
             with trace.span("serve/decode/launch"):
-                out, k2, v2 = self._decode_fn(*args)
+                out, *pools = self._decode_fn(*args)
+                # the pools this program returns are the cache from here
+                # on: whatever is launched before its tokens are taken (a
+                # prefill, a spill) runs behind it on them
+                self.cache.swap(*pools)
+        self._ahead = (batch, [seq.preemptions for seq in batch], width,
+                       lens, out, sp.t0_ns)
+
+    def _decode_collect(self) -> None:
+        """Wait for the decode iteration launched a step ago and commit its
+        tokens. A row that was cancelled or preempted while the program ran
+        is skipped: its token is computed again if it comes back."""
+        batch, epochs, width, lens, out, t0_ns = self._ahead
+        self._ahead = None
+        rows = len(batch)
+        with trace.span("serve/decode", rows=rows, width=width):
             with self._acted_span("serve/decode/wait") as wait:
-                out = np.asarray(out)  # host sync per iteration
+                # host sync per iteration
+                out = self._take_counts(np.asarray(out), width, rows)
             with trace.span("serve/decode/commit"):
-                self.cache.swap(k2, v2)
                 # Drill seam: a kill here lands AFTER the iteration's
                 # compute but BEFORE any token is committed/acknowledged —
                 # the relaunch must replay every in-flight request from
                 # scratch, exactly once.
                 _fault_fire("serve.mid_decode")
-                _account(sp.t0_ns, wait.end_ns, "decode", batch)
-                self._decode_done(sp, wait)
+                live = [(seq, tok) for seq, epoch, tok
+                        in zip(batch, epochs, out[:rows].tolist())
+                        if seq.status is Status.RUNNING
+                        and seq.preemptions == epoch]
+                _account(t0_ns, wait.end_ns, "decode",
+                         [seq for seq, _ in live])
+                self._decode_done(t0_ns, wait)
                 self._kv_count(lens, self._decode_paged)
                 # one commit stamp a step, shared by its rows; none
                 # under FLAGS_telemetry=off
                 now_ns = time.perf_counter_ns() if trace.enabled() else 0
                 finished: List[Sequence] = []
-                for i, seq in enumerate(batch):
+                for seq, tok in live:
                     seq.ctx_len += 1
-                    tok = int(out[i])
                     seq.out_tokens.append(tok)
                     if now_ns:
                         seq.token_t_ns.append(now_ns)
@@ -1423,13 +1510,12 @@ class ServingEngine:
                         finished.append(seq)
                 for seq in finished:
                     self._finish(seq)
-        return finished
 
-    def _decode_done(self, root, wait) -> None:
-        """The decode iteration's wall time, root's start to the arrival
-        of its tokens, to the policy's window and the histogram."""
+    def _decode_done(self, t0_ns: int, wait) -> None:
+        """The decode iteration's wall time, the start of its build to the
+        arrival of its tokens, to the policy's window and the histogram."""
         if wait.end_ns:
-            ms = (wait.end_ns - root.t0_ns) / 1e6
+            ms = (wait.end_ns - t0_ns) / 1e6
             self._decode_ms.append(ms)
             self._m.decode_step_ms.observe(ms)
 
@@ -1481,12 +1567,11 @@ class ServingEngine:
                      jnp.asarray(ctxs))
             if t == 0:
                 self._sent_draft.observe_tree(
-                    "serving.draft", dargs, donate=(1, 2),
+                    "serving.draft", dargs, donate=self._donated,
                     where="serving.draft")
-            out, dk, dv = self._draft_decode_fn(
-                dargs[0], self._draft_cache.k,
-                self._draft_cache.v, dargs[1], dargs[2])
-            self._draft_cache.swap(dk, dv)
+            out, *dpools = self._draft_decode_fn(
+                dargs[0], *self._draft_cache.pools, dargs[1], dargs[2])
+            self._draft_cache.swap(*dpools)
             out = np.asarray(out)
             for i in range(len(batch)):
                 catchup = len(feeds[i]) - 1
@@ -1523,7 +1608,7 @@ class ServingEngine:
                     tokens[i, :len(fed)] = fed
                     lens[i] = seq.ctx_len
                     n_real[i] = len(fed)
-                args = (jnp.asarray(tokens), self.cache.k, self.cache.v,
+                args = (jnp.asarray(tokens), *self.cache.pools,
                         jnp.asarray(tables), jnp.asarray(lens),
                         jnp.asarray(n_real))
             with trace.span("serve/decode/checks"):
@@ -1532,19 +1617,19 @@ class ServingEngine:
                     self._assert_cow(self._write_span_ids(
                         seq, seq.ctx_len, int(n_real[i])))
                 self._sent_verify.observe_tree(
-                    "serving.verify", (args[0], args[3], args[4], args[5]),
-                    donate=(1, 2), where="serving.verify")
+                    "serving.verify", self._undonated(args),
+                    donate=self._donated, where="serving.verify")
             with trace.span("serve/decode/launch"):
-                out, k2, v2 = self._verify_fn(*args)
+                out, *pools = self._verify_fn(*args)
             with self._acted_span("serve/decode/wait") as wait:
                 out = np.asarray(out)
             with trace.span("serve/decode/commit"):
-                self.cache.swap(k2, v2)
+                self.cache.swap(*pools)
                 _fault_fire("serve.mid_decode")
                 _account(draft.t0_ns, draft.end_ns, "draft", batch)
                 if draft.end_ns:
                     _account(draft.end_ns, wait.end_ns, "verify", batch)
-                self._decode_done(sp, wait)
+                self._decode_done(sp.t0_ns, wait)
                 self._kv_count(lens)
                 finished = self._spec_commit(batch, proposals, out)
         return finished
@@ -1640,10 +1725,14 @@ class ServingEngine:
         """One scheduler iteration: expire deadlines, consult the shed
         policy, admit whatever fits (prefill / restore at token
         granularity), run one prefill chunk under the chunked budget,
-        top up decode blocks (preempting under pressure), run one decode
-        iteration. Returns every sequence that reached a terminal state
-        this iteration — FINISHED, and also EXPIRED / SHED / FAILED
-        retirements."""
+        take the tokens of the decode iteration the last step launched,
+        top up decode blocks (preempting under pressure), launch the next
+        decode iteration. The decode program runs while this step returns
+        and the next one admits, so a row's token is seen a step after the
+        launch that computed it, and a drained engine needs one step more
+        than it has tokens to give. Returns every sequence that reached a
+        terminal state this iteration — FINISHED, and also EXPIRED / SHED /
+        FAILED retirements."""
         n0 = len(self.sched.finished)
         with trace.span("serve/step", iteration=self.n_iterations) as root:
             if root:        # the queue lengths only for a span that records
@@ -1661,6 +1750,8 @@ class ServingEngine:
             if self.chunk_tokens:
                 with trace.span("serve/chunk"):
                     self._chunk_iteration()
+            if self._ahead is not None:
+                self._decode_collect()
             with trace.span("serve/ensure_blocks"):
                 self._ensure_decode_blocks()
             self._decode_iteration()

@@ -3,8 +3,11 @@
 The PagedAttention idea (vLLM, SOSP'23) applied to our stack: instead of
 one contiguous ``[B, max_len, KH, D]`` cache per sequence (whose max_len
 reservation wastes ~60-80% of KV memory on real traffic), the KV store
-is a pool of fixed-size *blocks* — ``[L, num_blocks, block_size, KH, D]``
-per k and v — and each sequence owns an ordered block list. Allocation
+is a pool of fixed-size *blocks* — ``[L, num_blocks, block_size, *row]``
+for each kind of row the model caches a token (keys and values a head:
+two pools of ``[KH, D]`` rows; a latent-attention model: one pool of its
+latent row, no value pool; the model's ``serve_cache_rows()`` says which) —
+and each sequence owns an ordered block list. Allocation
 is a min-id free list (deterministic: the same request schedule always
 produces the same block assignment, which the tests pin), fragmentation
 is impossible (every block is the same shape), and capacity pressure is
@@ -29,6 +32,7 @@ body (rule J012).
 from __future__ import annotations
 
 import functools
+import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -62,7 +66,7 @@ class SpillError(RuntimeError):
 
 
 class BlockAllocator:
-    """Min-id free list over ``num_blocks`` KV blocks (block 0 reserved).
+    """Min-id free heap over ``num_blocks`` KV blocks (block 0 reserved).
 
     Lowest-id-first allocation keeps the assignment deterministic under a
     fixed request schedule and re-uses freed blocks immediately (hot
@@ -85,6 +89,8 @@ class BlockAllocator:
                              f"got {num_blocks}")
         self.num_blocks = int(num_blocks)
         self._reserved = frozenset(int(r) for r in reserved)
+        # a sorted list is a valid heap; alloc pops, free pushes: a grant
+        # costs its own size, not the pool's
         self._free = sorted(set(range(self.num_blocks)) - self._reserved)
         self._used: set = set()
         self._refs: Dict[int, int] = {}
@@ -111,7 +117,7 @@ class BlockAllocator:
             raise ValueError(f"alloc({n})")
         if n > len(self._free):
             return None
-        got, self._free = self._free[:n], self._free[n:]
+        got = [heapq.heappop(self._free) for _ in range(n)]
         self._used.update(got)
         for i in got:
             self._refs[i] = 1
@@ -130,24 +136,22 @@ class BlockAllocator:
         """Drop one owner per block; last-owner blocks return to the
         free list."""
         ids = [int(i) for i in ids]
+        repeated = len(set(ids)) != len(ids)
         for i in ids:
             if i in self._reserved:
                 raise ValueError(f"freeing reserved block {i}")
             if i not in self._used:
                 raise ValueError(f"double-free of block {i}")
-            if ids.count(i) > self._refs[i]:
+            if repeated and ids.count(i) > self._refs[i]:
                 raise ValueError(
                     f"double-free of block {i} (repeated past its "
                     f"refcount in one free call)")
-        released = []
         for i in ids:
             self._refs[i] -= 1
             if self._refs[i] == 0:
                 del self._refs[i]
                 self._used.discard(i)
-                released.append(i)
-        if released:
-            self._free = sorted(self._free + released)
+                heapq.heappush(self._free, i)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -162,44 +166,77 @@ def _gather_blocks(pages, ids):
 
 
 class PagedKVCache:
-    """The device page pool for one model: k/v arrays of shape
-    ``[n_layers, num_blocks, block_size, kv_heads, head_dim]``.
+    """The device page pool for one model: one array a kind of row the model
+    caches for a token, each ``[n_layers, num_blocks, block_size, *row]``.
+
+    ``rows`` is the model's row spec (``model.serve_cache_rows()``): a tuple
+    of per-token row shapes, one a pool. A model that caches keys and values
+    a head gives ``((kv_heads, head_dim), (kv_heads, head_dim))`` (what
+    ``kv_heads``/``head_dim`` alone build; the pools are then also ``.k`` and
+    ``.v``); a latent-attention model gives one row of its latent width,
+    ``((576,),)``, and has no second pool. Block tables, the allocator, spill
+    and restore are the same for every spec: they move whole blocks of every
+    pool together.
 
     The pool arrays are owned here but *written* by the serving engine's
     prefill/decode executables, which take them as donated arguments and
-    return the updated pool — :meth:`swap` re-homes the references. Spill
-    and restore move whole per-sequence block lists between the pool and
+    return the updated pools; :meth:`swap` re-homes the references. Spill
+    and restore move whole per-sequence block lists between the pools and
     the host memory tier.
     """
 
     def __init__(self, n_layers: int, num_blocks: int, block_size: int,
-                 kv_heads: int, head_dim: int, dtype=jnp.float32):
+                 kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None, dtype=jnp.float32,
+                 rows: Optional[Sequence[Sequence[int]]] = None):
+        if rows is None:
+            rows = ((int(kv_heads), int(head_dim)),) * 2
+        self.rows = tuple(tuple(int(d) for d in r) for r in rows)
         self.n_layers = int(n_layers)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        self.kv_heads = int(kv_heads)
-        self.head_dim = int(head_dim)
         self.dtype = jnp.dtype(dtype)
-        shape = (n_layers, num_blocks, block_size, kv_heads, head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        self.pools = tuple(
+            jnp.zeros((n_layers, num_blocks, block_size) + r, self.dtype)
+            for r in self.rows)
         self.allocator = BlockAllocator(num_blocks)
         self.host_kind = host_memory_kind()
 
+    # the two pools of a keys-and-values spec, by their old names
+    @property
+    def k(self):
+        return self.pools[0]
+
+    @k.setter
+    def k(self, value):
+        self.pools = (value,) + self.pools[1:]
+
+    @property
+    def v(self):
+        return self.pools[1]
+
+    @v.setter
+    def v(self, value):
+        self.pools = self.pools[:1] + (value,) + self.pools[2:]
+
     @property
     def bytes_per_block(self) -> int:
-        return (2 * self.n_layers * self.block_size * self.kv_heads *
-                self.head_dim * self.dtype.itemsize)
+        per_token = sum(int(np.prod(r)) for r in self.rows)
+        return (self.n_layers * self.block_size * per_token
+                * self.dtype.itemsize)
 
-    def swap(self, k, v) -> None:
+    def swap(self, *pools) -> None:
         """Adopt the pool arrays an executable returned (the old ones were
         donated into it)."""
-        self.k, self.v = k, v
+        if len(pools) != len(self.pools):
+            raise ValueError(f"swap of {len(pools)} pools into "
+                             f"{len(self.pools)}")
+        self.pools = tuple(pools)
 
     # -- spill / restore -----------------------------------------------------
 
     def _to_host(self, x: jax.Array):
-        """Commit one gathered KV stripe to the host memory tier
+        """Commit one gathered stripe to the host memory tier
         (``pinned_host``/``unpinned_host`` sharding when the runtime
         exposes one, plain host numpy otherwise)."""
         if self.host_kind is None:
@@ -207,26 +244,29 @@ class PagedKVCache:
         tgt = x.sharding.with_memory_kind(self.host_kind)
         return jax.device_put(x, tgt)
 
+    def _gather_to_host(self, block_ids: Sequence[int]) -> Tuple:
+        ids = jnp.asarray(list(block_ids), jnp.int32)
+        return tuple(self._to_host(_gather_blocks(p, ids))
+                     for p in self.pools)
+
     def spill(self, block_ids: Sequence[int]) -> Tuple:
         """Gather ``block_ids`` to host and free them. Returns the opaque
-        host KV pair :meth:`restore` takes; the device blocks are
-        reusable immediately after.
+        host tuple (one array a pool) :meth:`restore` takes; the device
+        blocks are reusable immediately after.
 
         A host allocation/transfer failure raises :class:`SpillError`
-        (the blocks stay allocated — the caller owns the cleanup); the
+        (the blocks stay allocated: the caller owns the cleanup); the
         ``serve.mid_spill`` fire point lets the fault drill kill or
         perturb the process inside the spill window, before the blocks
         are freed."""
-        ids = jnp.asarray(list(block_ids), jnp.int32)
         try:
-            k_host = self._to_host(_gather_blocks(self.k, ids))
-            v_host = self._to_host(_gather_blocks(self.v, ids))
+            host = self._gather_to_host(block_ids)
             _fault_fire("serve.mid_spill")
             if self.host_kind is not None:
                 # Host commit must complete before the blocks are handed
-                # out again — a donated overwrite racing the D2H would
+                # out again: a donated overwrite racing the D2H would
                 # tear the copy.
-                jax.block_until_ready((k_host, v_host))
+                jax.block_until_ready(host)
         except SpillError:
             raise
         except (RuntimeError, MemoryError, ValueError) as e:
@@ -236,44 +276,41 @@ class PagedKVCache:
         self.allocator.free(list(block_ids))
         metrics.counter("serving.kv_spills",
                         "sequence KV spills to host memory").inc()
-        return (k_host, v_host)
+        return host
 
     def snapshot(self, block_ids: Sequence[int]) -> Tuple:
-        """Gather ``block_ids`` to the host tier WITHOUT freeing them —
+        """Gather ``block_ids`` to the host tier WITHOUT freeing them:
         the prefix tree's eviction spill (the tree drops its device hold
         separately once the copy is committed) and the drafter pool's
         mirror spill (whose blocks are never allocator-owned). Same
         bitwise round-trip contract as :meth:`spill`."""
-        ids = jnp.asarray(list(block_ids), jnp.int32)
         try:
-            k_host = self._to_host(_gather_blocks(self.k, ids))
-            v_host = self._to_host(_gather_blocks(self.v, ids))
+            host = self._gather_to_host(block_ids)
             if self.host_kind is not None:
-                jax.block_until_ready((k_host, v_host))
+                jax.block_until_ready(host)
         except (RuntimeError, MemoryError, ValueError) as e:
             raise SpillError(
                 f"host snapshot of {len(block_ids)} block(s) failed: {e}"
             ) from e
-        return (k_host, v_host)
+        return host
 
     def restore(self, host_kv: Tuple, block_ids: Sequence[int]) -> None:
-        """Scatter a spilled KV pair into freshly allocated blocks (ids
-        may differ from the spilled ones — the block table is rewritten
+        """Scatter a spilled tuple into freshly allocated blocks (ids
+        may differ from the spilled ones: the block table is rewritten
         by the caller). Bitwise: the round trip is a copy, not a cast."""
-        k_host, v_host = host_kv
         ids = jnp.asarray(list(block_ids), jnp.int32)
-        if int(ids.shape[0]) != int(k_host.shape[1]):
+        if int(ids.shape[0]) != int(host_kv[0].shape[1]):
             raise ValueError(
-                f"restore of {k_host.shape[1]} blocks into "
+                f"restore of {host_kv[0].shape[1]} blocks into "
                 f"{ids.shape[0]} ids")
-        self.k = _scatter_blocks(self.k, ids, jnp.asarray(k_host, self.dtype))
-        self.v = _scatter_blocks(self.v, ids, jnp.asarray(v_host, self.dtype))
+        self.pools = tuple(
+            _scatter_blocks(p, ids, jnp.asarray(h, self.dtype))
+            for p, h in zip(self.pools, host_kv))
         metrics.counter("serving.kv_restores",
                         "sequence KV restores from host memory").inc()
 
-    def read_blocks(self, block_ids: Sequence[int]) -> Tuple[np.ndarray,
-                                                             np.ndarray]:
-        """Host copies of the given blocks (tests / debugging)."""
+    def read_blocks(self, block_ids: Sequence[int]) -> Tuple[np.ndarray, ...]:
+        """Host copies of the given blocks, one array a pool (tests /
+        debugging)."""
         ids = jnp.asarray(list(block_ids), jnp.int32)
-        return (np.asarray(_gather_blocks(self.k, ids)),
-                np.asarray(_gather_blocks(self.v, ids)))
+        return tuple(np.asarray(_gather_blocks(p, ids)) for p in self.pools)

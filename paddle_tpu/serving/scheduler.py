@@ -125,6 +125,11 @@ class Sequence:
     #: read per engine step, shared by the rows of that step)
     token_t_ns: List[int] = field(default_factory=list)
     phase_s: Dict[str, float] = field(default_factory=dict)
+    # block_ids as a null-padded table row, kept beside the list so that a
+    # decode step copies a row instead of converting a list (table_row)
+    _tab: Any = field(default=None, repr=False, compare=False)
+    _tab_of: Any = field(default=None, repr=False, compare=False)
+    _tab_n: int = field(default=0, repr=False, compare=False)
 
     @property
     def rid(self) -> str:
@@ -146,6 +151,21 @@ class Sequence:
 
     def add_phase(self, name: str, dur_s: float) -> None:
         self.phase_s[name] = self.phase_s.get(name, 0.0) + dur_s
+
+    def table_row(self, width: int, null: int) -> np.ndarray:
+        """``block_ids`` null-padded to ``width``. The engine replaces the
+        list or appends to it, never edits it in place: a new list rebuilds
+        the row, a longer one appends its tail."""
+        ids, n = self.block_ids, len(self.block_ids)
+        if (self._tab is None or self._tab_of is not ids
+                or len(self._tab) != width or n < self._tab_n):
+            self._tab = np.full((width,), null, np.int32)
+            self._tab[:n] = ids
+            self._tab_of = ids
+        elif n > self._tab_n:
+            self._tab[self._tab_n:n] = ids[self._tab_n:]
+        self._tab_n = n
+        return self._tab
 
     def is_finished_by(self, token: int) -> bool:
         eos = self.request.eos_token_id

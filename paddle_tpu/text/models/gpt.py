@@ -28,6 +28,8 @@ from ...distributed.fleet.layers.mpu.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
     ParallelCrossEntropy, _constrain, MP_AXIS)
 from ...ops import flash_attention
+from ...ops.flash_attention import (multi_query_attention,
+                                    paged_single_query_attention)
 
 __all__ = ["GPTConfig", "GPT", "GPTForCausalLM", "gpt3_1p3b", "gpt_tiny"]
 
@@ -283,6 +285,33 @@ class GPTBlock(nn.Layer):
         x = x + self.mlp(self.ln_2(x))
         return x, cache
 
+    # -- the serving engine's layer step (serving/engine.py) ---------------
+
+    def serve_project(self, x, pos):
+        """-> (q, the token's page rows (k, v)); positions were added at
+        the embedding."""
+        q, k, v = self.attn._project_qkv(self.ln_1(x))
+        return q, (k, v)
+
+    def serve_attend_prefill(self, q, rows):
+        return flash_attention(q, *rows, causal=True, training=False)
+
+    def serve_attend_paged(self, q, pools, tables, lengths, block_size,
+                           layer):
+        return paged_single_query_attention(
+            q, *pools, tables, lengths, block_size=block_size, layer=layer)
+
+    def serve_attend_extend(self, q, pools, tables, pos, block_size, layer):
+        b, mx = tables.shape[0], tables.shape[1] * block_size
+        keys, vals = (p[layer][tables].reshape(b, mx, *p.shape[3:])
+                      for p in pools)
+        return multi_query_attention(q, keys, vals, pos)
+
+    def serve_finish(self, x, o, real):
+        """The rest of the block after attention; no counts."""
+        x = x + self.attn.out_proj(o.reshape(x.shape[0], x.shape[1], -1))
+        return x + self.mlp(self.ln_2(x)), None
+
 
 class GPT(nn.Layer):
     def __init__(self, cfg: GPTConfig):
@@ -350,6 +379,28 @@ class GPTForCausalLM(nn.Layer):
             return logits
         loss = self.loss_fn(logits, labels)
         return jnp.mean(loss)
+
+    # -- the serving engine's seam (serving/engine.py) ---------------------
+
+    serve_counts = 0        # the programs return nothing beside the token
+    serve_latent_value_dim = None       # keys and values, not a latent row
+
+    def serve_cache_rows(self):
+        """A token's page rows: keys and values a kv head."""
+        row = (self.cfg.kv_heads, self.cfg.hidden_size // self.cfg.num_heads)
+        return (row, row)
+
+    def serve_dtype(self):
+        return self.gpt.wte.weight.dtype
+
+    def serve_layers(self):
+        return list(self.gpt.h)
+
+    def serve_embed(self, ids, pos):
+        return self.gpt.wte(ids) + self.gpt.wpe(pos)
+
+    def serve_final_norm(self, x):
+        return self.gpt.ln_f(x)
 
     def generate(self, input_ids, max_new_tokens: int = 32,
                  do_sample: bool = False, temperature: float = 1.0,

@@ -331,3 +331,123 @@ def test_serving_decode_program_with_paged_kernel_compiles(one_chip,
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+
+
+# The latent serving cell's decode shapes (benchmark/traffic/ctx4k-closed.json
+# on deepseek-v2-ep16-l5): 256 rows, tables of 320 pages of 16 tokens, 128
+# heads against a latent row of 576 stored as 640, bf16, a pool of 49,152
+# pages in 5 layers (4.7 GiB).
+CELL_LATENT = dict(b=256, m=320, bs=16, h=128, w=640, v=512, layers=5,
+                   nb=49152)
+
+
+def test_latent_decode_kernel_compiles(one_chip):
+    """The absorbed latent (MLA) decode kernel alone, at its cell's shapes:
+    a 327 KB block table in scalar memory, the whole pool handed over in
+    HBM, a page moved by one aligned DMA (a row of 576 is refused: the
+    chip lays it out as 640 and cannot slice it)."""
+    from paddle_tpu.ops._pallas.latent_paged_attention import (
+        latent_paged_attention_pallas, supported_shapes)
+    c = CELL_LATENT
+    pool = ((c["layers"], c["nb"], c["bs"], c["w"]), jnp.bfloat16)
+    assert supported_shapes(jnp.bfloat16, jax.ShapeDtypeStruct(*pool),
+                            c["v"])
+
+    def fn(q, pool, tables, lengths, layer):
+        return latent_paged_attention_pallas(
+            q, pool, tables, lengths, value_dim=c["v"], scale=0.1147,
+            layer=layer)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((c["b"], 1, c["h"], c["w"]), jnp.bfloat16), pool,
+        ((c["b"], c["m"]), jnp.int32), ((c["b"],), jnp.int32),
+        ((), jnp.int32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    # nothing the size of a layer's pool (1 GB) is ever materialised
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+
+def test_latent_serving_decode_program_compiles(one_chip, monkeypatch):
+    """The engine's decode program over a latent pool as the chip runs it,
+    one dense and one expert layer at the published widths (weights as
+    zeros: nothing runs): one latent kernel call a layer sharing one
+    lowered function, the grouped expert products as XLA's own
+    ``ragged-dot`` calls, the one pool donated and aliased, and no gathered
+    copy of it among the temporaries."""
+    import importlib
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.text.models.deepseek_v2 import (DeepseekV2Config,
+                                                    DeepseekV2ForCausalLM)
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+
+    c = CELL_LATENT
+    model = DeepseekV2ForCausalLM(DeepseekV2Config(
+        vocab_size=12800, num_hidden_layers=2, experts_held=(0, 10),
+        dtype="bfloat16", init_weights=False))
+    eng = ServingEngine(model, block_size=c["bs"], num_blocks=c["m"] + 1,
+                        max_batch=c["b"], max_seq_len=c["m"] * c["bs"],
+                        prefill_buckets=[4096], decode_buckets=[c["b"]])
+    assert eng._decode_paged and eng.cache.rows == ((c["w"],),)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._decode_fn.params)
+    pool = jax.ShapeDtypeStruct((2, c["nb"], c["bs"], c["w"]), jnp.bfloat16,
+                                sharding=one_chip)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    lowered = eng._decode_fn.jitted.lower(
+        params, i32((c["b"],)), pool, i32((c["b"], c["m"])), i32((c["b"],)))
+    mlir = lowered.as_text()
+    assert mlir.count("func.func private @_latent_paged_call") == 1
+    assert mlir.count("call @_latent_paged_call") == 2
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("%latent_paged_attention") >= 2
+    assert "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * c["nb"] * c["bs"] * c["w"] * 2
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_latent_serving_prefill_program_compiles(one_chip, monkeypatch):
+    """The engine's prefill program at the cell's longest bucket, one dense
+    and one expert layer at the published widths (weights as zeros: nothing
+    runs): one flash forward a layer, keys padded to 256 beside values of
+    128, so no 256-wide value or output exists."""
+    import importlib
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.text.models.deepseek_v2 import (DeepseekV2Config,
+                                                    DeepseekV2ForCausalLM)
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+
+    c = CELL_LATENT
+    s = 4096
+    model = DeepseekV2ForCausalLM(DeepseekV2Config(
+        vocab_size=12800, num_hidden_layers=2, experts_held=(0, 10),
+        dtype="bfloat16", init_weights=False))
+    eng = ServingEngine(model, block_size=c["bs"], num_blocks=c["m"] + 1,
+                        max_batch=c["b"], max_seq_len=c["m"] * c["bs"],
+                        prefill_buckets=[s], decode_buckets=[c["b"]])
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._prefill_fn.params)
+    pool = jax.ShapeDtypeStruct((2, c["nb"], c["bs"], c["w"]), jnp.bfloat16,
+                                sharding=one_chip)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    lowered = eng._prefill_fn.jitted.lower(
+        params, i32((1, s)), pool, i32((s // c["bs"],)), i32(()))
+    mlir = lowered.as_text()
+    heads = model.cfg.num_attention_heads
+    assert f"tensor<{heads}x{s}x128xbf16>" in mlir      # values and output
+    assert f"tensor<{heads}x{s}x256xbf16>" in mlir      # padded q and k
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 2

@@ -72,6 +72,28 @@ def test_pallas_kernel_interpret_matches_reference(causal):
             np.testing.assert_allclose(a, b, atol=5e-4)
 
 
+@pytest.mark.parametrize("causal,blocks", [(False, (128, 128)),
+                                           (True, (128, 128)),
+                                           (True, (64, 64))])
+def test_pallas_forward_takes_values_of_another_head_size(causal, blocks):
+    """Latent attention's prefill: keys of 256 beside values of 128, forward
+    only (plain and triangular enumeration of the blocks)."""
+    with interpreted_pallas() as fa:
+        rng = np.random.default_rng(2)
+        q, k = (jnp.asarray(rng.standard_normal((1, 256, 2, 256)),
+                            jnp.float32) for _ in range(2))
+        v = jnp.asarray(rng.standard_normal((1, 256, 2, 128)), jnp.float32)
+        out = fa.flash_attention_pallas(q, k, v, causal=causal,
+                                        scale=0.07, block_q=blocks[0],
+                                        block_k=blocks[1])
+        assert out.shape == (1, 256, 2, 128)
+        np.testing.assert_allclose(
+            out, reference_attention(q, k, v, causal=causal, scale=0.07),
+            atol=2e-5)
+        with pytest.raises(ValueError, match="forward only"):
+            fa.flash_attention_pallas(q, k, v, dropout=0.1)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_pallas_kernel_interpret_bf16(causal):
     """The production dtype: bf16 inputs, MXU-native dots, fp32 accumulation.

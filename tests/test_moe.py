@@ -1,4 +1,5 @@
-"""MoE expert-parallel tests (GShard dense dispatch on the CPU mesh)."""
+"""MoE expert-parallel tests: the dropless layer, whole and as one rank's
+share of the experts, on the CPU mesh."""
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +77,67 @@ def test_moe_sharded_matches_single_device():
     single = run(dict(dp=1, devices=jax.devices()[:1]))
     ep = run(dict(mp=4, dp=2))  # expert dim rides the mp axis
     np.testing.assert_allclose(single, ep, rtol=1e-4, atol=1e-5)
+
+
+def _dense_moe(layer, x):
+    """Every token through its gate's experts, one pair at a time."""
+    from paddle_tpu.nn import functional as F
+    flat = x.reshape(-1, x.shape[-1])
+    idx, weight, _ = layer.gate(flat)
+    out = np.zeros(flat.shape, np.float32)
+    for t in range(flat.shape[0]):
+        for j in range(idx.shape[1]):
+            e = int(idx[t, j]) - layer.first
+            if not 0 <= e < layer.count:
+                continue
+            h = F.gelu(flat[t] @ layer.experts.w1[e] + layer.experts.b1[e, 0])
+            out[t] += float(weight[t, j]) * np.asarray(
+                h @ layer.experts.w2[e] + layer.experts.b2[e, 0])
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("gate", ["naive", "gshard", "switch"])
+def test_moe_is_dropless_at_an_imbalanced_load(gate):
+    """All tokens to one expert (the gate's other columns pushed far down):
+    every one of them is computed; a capacity bucket would have cut all but
+    a few off."""
+    paddle.seed(3)
+    layer = MoELayer(d_model=8, d_hidden=16, num_experts=4, gate=gate)
+    layer.eval()
+    x = jnp.abs(_x(b=2, s=32, seed=5)) + 0.1
+    w = np.full((8, 4), -5.0, np.float32)
+    w[:, 2] = 5.0
+    layer.gate.weight = jnp.asarray(w)
+    y = layer(x)
+    load = np.asarray(layer.expert_load)
+    assert load[2] == 64 and load.sum() == 64 * layer.gate.top_k
+    np.testing.assert_allclose(y, _dense_moe(layer, x), rtol=1e-4, atol=1e-5)
+
+
+def test_moe_shares_add_up_to_the_whole_layer():
+    """Four ranks of two experts each: what each computes for the experts it
+    holds, summed, is the layer that holds all eight."""
+    paddle.seed(4)
+    whole = MoELayer(d_model=8, d_hidden=16, num_experts=8, gate="gshard")
+    whole.eval()
+    x = _x(b=2, s=24, seed=6)
+    want = whole(x)
+    assert int(np.asarray(whole.expert_load).sum()) == 2 * 48
+    total = 0.0
+    for rank in range(4):
+        share = MoELayer(d_model=8, d_hidden=16, num_experts=8, gate="gshard",
+                         experts_held=(2 * rank, 2))
+        share.eval()
+        share.gate.weight = whole.gate.weight
+        sl = slice(2 * rank, 2 * rank + 2)
+        for name in ("w1", "b1", "w2", "b2"):
+            setattr(share.experts, name, getattr(whole.experts, name)[sl])
+        total = total + share(x)
+        np.testing.assert_array_equal(share.expert_load,
+                                      np.asarray(whole.expert_load)[sl])
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
+    with pytest.raises(ValueError, match="experts_held"):
+        MoELayer(d_model=8, d_hidden=16, num_experts=8, experts_held=(7, 2))
 
 
 def test_moe_trains():

@@ -247,6 +247,67 @@ class TestPreemption:
             "schedule was expected to preempt at least once"
 
 
+class TestDecodeInFlight:
+    """A step launches its decode iteration and the next step takes the
+    tokens; a row may leave in between."""
+
+    def _engine(self, model):
+        return ServingEngine(model, block_size=4, num_blocks=32, max_batch=4,
+                             max_seq_len=32)
+
+    def test_a_step_returns_with_its_decode_launched(self):
+        model = micro_model(max_position_embeddings=32)
+        engine = self._engine(model)
+        req = ragged_requests(1, lo=8, hi=8, max_new=4, seed=5)[0]
+        seq = engine.submit(req)
+        engine.step()                       # prefill's token, decode launched
+        assert len(seq.out_tokens) == 1 and engine._ahead is not None
+        engine.step()
+        assert len(seq.out_tokens) == 2 and seq.ctx_len == 9
+        while engine.sched.n_pending:
+            engine.step()
+        assert engine._ahead is None
+        np.testing.assert_array_equal(seq.output, ref_generate(model, req))
+
+    def test_preempted_in_flight_row_is_recomputed_exactly(self):
+        model = micro_model(max_position_embeddings=32)
+        engine = self._engine(model)
+        requests = ragged_requests(3, lo=7, hi=9, max_new=8, seed=3)
+        seqs = [engine.submit(r) for r in requests]
+        victim = seqs[1]
+        engine.step()
+        while victim.ctx_len % 4:           # the write in flight opens a block
+            engine.step()
+        assert victim in engine._ahead[0]
+        n_out = len(victim.out_tokens)
+        engine._preempt(victim)             # its next token is in flight
+        engine.step()                       # restored at once: room is left
+        assert victim.preemptions == 1 and len(victim.out_tokens) == n_out
+        while engine.sched.n_pending:
+            engine.step()
+        for r, seq in zip(requests, seqs):
+            np.testing.assert_array_equal(seq.output, ref_generate(model, r))
+        assert engine.cache.allocator.n_used == 0
+
+    def test_cancelled_in_flight_row_leaves_the_others_exact(self):
+        model = micro_model(max_position_embeddings=32)
+        engine = self._engine(model)
+        requests = ragged_requests(3, lo=7, hi=9, max_new=8, seed=4)
+        seqs = [engine.submit(r) for r in requests]
+        for _ in range(2):
+            engine.step()
+        gone = seqs[0]
+        assert gone in engine._ahead[0]
+        n_out = len(gone.out_tokens)
+        engine._cancel(gone, Status.EXPIRED, "test: cancelled in flight")
+        while engine.sched.n_pending:
+            engine.step()
+        assert gone.status is Status.EXPIRED and len(gone.out_tokens) == n_out
+        for r, seq in zip(requests[1:], seqs[1:]):
+            np.testing.assert_array_equal(seq.output, ref_generate(model, r))
+        assert engine.cache.allocator.n_used == 0
+
+
 class TestGQA:
     def test_grouped_kv_heads_match_generate(self):
         model = micro_model(num_heads=4, num_kv_heads=2)
