@@ -12,6 +12,15 @@ uneven, cuts a pair off; rows past the pairs really held belong to no group
 and are never read back. Pairs routed to experts held elsewhere add nothing
 here: on one chip the layer runs without its exchange, and nothing stands in
 for the absent chips.
+
+That is the *grouped* form, made for prefill, where thousands of tokens meet
+the held experts. A grouped product costs at least one row tile for every
+expert that has a pair, so when the whole call has no more tokens than one
+such tile (a decode step) each expert's tile may as well be the batch
+itself: the *dense* form runs every held expert over every token and gives
+a pair that was not routed the weight zero. No sort, no gather; the same
+pairs, the same rounding points, the same loads. ``expert_form`` picks from
+the token count alone.
 """
 
 from __future__ import annotations
@@ -22,7 +31,32 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["Route", "dropless_route", "group_limited_topk",
-           "dropless_glu_experts"]
+           "dropless_glu_experts", "expert_form", "EXPERT_FORMS",
+           "grouped_glu_experts", "dense_glu_experts", "DENSE_MAX_TOKENS"]
+
+#: The most tokens a call may have and still take the dense form: the row
+#: tile XLA:TPU's ``ragged_dot`` gives every expert that has a pair
+#: (``ragged_dot_tiling="512,512,512"`` in the compiled program). Each form
+#: alone on a TPU v5e at E 10, d 5120, f 1536, top-6 of 160 (9.6 pairs an
+#: expert at T 256), bfloat16, in ms a call (median of 5 x 100 calls by the
+#: host's clock; chip run of PR 31):
+#:
+#:     T         128    256    384    512    768   1024   2048
+#:     grouped  1.04   1.66   1.15   1.86   2.02   2.35   4.17
+#:     dense    0.65   0.75   1.09   1.48   2.14   3.01   6.54
+#:
+#: They cross between 512 and 768 (near 700 by the lines through those
+#: points). One gate product at T 256: 0.53 ms grouped, 0.25 dense, beside
+#: 0.19 ms of weight read and 0.20 of products at the chip's peaks.
+DENSE_MAX_TOKENS = 512
+
+
+def expert_form(tokens: int) -> str:
+    """The form ``dropless_glu_experts`` takes for a call of ``tokens``
+    tokens, ``"dense"`` or ``"grouped"``. Both cost a multiple of the held
+    experts, so their number does not enter; neither does the load, which
+    is not known when the program is traced."""
+    return "dense" if tokens <= DENSE_MAX_TOKENS else "grouped"
 
 
 class Route(NamedTuple):
@@ -89,6 +123,42 @@ def group_limited_topk(scores, top_k: int, n_group: int = 1,
     return idx, val
 
 
+def grouped_glu_experts(x, idx, weight, w_gate, w_up, w_down, *,
+                        first: int = 0, activation=jax.nn.silu):
+    """``dropless_glu_experts`` (below) by sorting the held pairs by expert
+    and grouped products over the sorted rows."""
+    route = dropless_route(idx, w_gate.shape[0], first)
+    xs = route.gather(x)
+    gs = route.group_sizes
+    mid = activation(jax.lax.ragged_dot(xs, w_gate, gs)) \
+        * jax.lax.ragged_dot(xs, w_up, gs)
+    out = jax.lax.ragged_dot(mid.astype(x.dtype), w_down, gs)
+    return route.combine(out, weight), gs
+
+
+def dense_glu_experts(x, idx, weight, w_gate, w_up, w_down, *,
+                      first: int = 0, activation=jax.nn.silu):
+    """``dropless_glu_experts`` by running every held expert over every
+    token: ``c [T, E]`` holds each pair's weight, and zero where the token
+    did not pick the expert (an ``idx`` of -1 and an expert held elsewhere
+    match nothing), so such a product adds exactly nothing."""
+    count = w_gate.shape[0]
+    hit = (idx - first)[..., None] == jnp.arange(count, dtype=idx.dtype)
+    c = jnp.sum(jnp.where(hit, weight.astype(jnp.float32)[..., None], 0.0),
+                axis=1)                                          # [T, E]
+    load = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+    mid = activation(jnp.einsum("td,edf->etf", x, w_gate)) \
+        * jnp.einsum("td,edf->etf", x, w_up)
+    out = jnp.einsum("etf,efd->etd", mid.astype(x.dtype), w_down)
+    # elementwise, not a product: float32 on the MXU would round c to bf16
+    y = jnp.sum(c.T[..., None] * out.astype(jnp.float32), axis=0)
+    return y, load
+
+
+#: What ``expert_form`` names.
+EXPERT_FORMS = {"grouped": grouped_glu_experts, "dense": dense_glu_experts}
+
+
 def dropless_glu_experts(x, idx, weight, w_gate, w_up, w_down, *,
                          first: int = 0, activation=jax.nn.silu):
     """Gated-linear-unit experts over the pairs held here.
@@ -97,11 +167,9 @@ def dropless_glu_experts(x, idx, weight, w_gate, w_up, w_down, *,
     weights; ``w_gate``/``w_up [E, d, f]``, ``w_down [E, f, d]`` the experts
     held (expert ``e`` of the stack is expert ``first + e`` of the gate).
     Returns ``(y [T, d] float32, load [E] int32)``: each token's weighted sum
-    over its held experts, and how many pairs each held expert got."""
-    route = dropless_route(idx, w_gate.shape[0], first)
-    xs = route.gather(x)
-    gs = route.group_sizes
-    mid = activation(jax.lax.ragged_dot(xs, w_gate, gs)) \
-        * jax.lax.ragged_dot(xs, w_up, gs)
-    out = jax.lax.ragged_dot(mid.astype(x.dtype), w_down, gs)
-    return route.combine(out, weight), gs
+    over its held experts, and how many pairs each held expert got. Each
+    product accumulates in float32 and is stored in ``x.dtype``, in either
+    form (``expert_form`` of ``T``)."""
+    return EXPERT_FORMS[expert_form(x.shape[0])](
+        x, idx, weight, w_gate, w_up, w_down, first=first,
+        activation=activation)

@@ -90,8 +90,9 @@ for its layer step and for what it caches a token. A model serves by giving
 - ``serve_counts`` (0, or how many int32 counts the layers' ``serve_finish``
   return): the prefill and decode programs then return them behind the
   token, in the one array the host already waits for, and the engine hands
-  them to ``model.serve_record_counts(counts, n_real_tokens)`` (the
-  expert-load counters of a routed-expert model).
+  them to ``model.serve_record_counts(counts, n_real_tokens, n_slots)``
+  (the expert counters of a routed-expert model; ``n_slots`` is the
+  program's static token count, its bucket).
 
 The engine writes the rows into the pools, keeps the block tables, and
 calls no model by name; scheduler, allocator, spill, spans and counters are
@@ -438,15 +439,16 @@ class ServingEngine:
         """A step's arguments but the pools (what its sentinel watches)."""
         return (args[0],) + tuple(args[1 + self._n_pools:])
 
-    def _take_counts(self, out: np.ndarray, n_tok: int, n_real: int):
+    def _take_counts(self, out: np.ndarray, n_tok: int, n_real: int,
+                     n_slots: int):
         """Split what a prefill or decode program returned beside the pools
         into its token(s) and, for a model that counts
         (``model.serve_counts``), the counts behind them, which go to
         ``model.serve_record_counts`` with the number of real tokens the
-        program ran."""
+        program ran and the ``n_slots`` it was traced for (its bucket)."""
         if not self._n_counts:
             return out
-        self.model.serve_record_counts(out[n_tok:], n_real)
+        self.model.serve_record_counts(out[n_tok:], n_real, n_slots)
         return out[:n_tok]
 
     @staticmethod
@@ -1187,7 +1189,8 @@ class ServingEngine:
             with trace.span("serve/prefill/wait") as wait:
                 # host sync: the first token exists now
                 tok = int(self._take_counts(
-                    np.asarray(tok), 1, seq.prompt_len).reshape(-1)[0])
+                    np.asarray(tok), 1, seq.prompt_len,
+                    bucket).reshape(-1)[0])
             _account(sp.t0_ns, wait.end_ns, "prefill", (seq,))
             with trace.span("serve/prefill/commit"):
                 self.cache.swap(*pools)
@@ -1482,7 +1485,7 @@ class ServingEngine:
         with trace.span("serve/decode", rows=rows, width=width):
             with self._acted_span("serve/decode/wait") as wait:
                 # host sync per iteration
-                out = self._take_counts(np.asarray(out), width, rows)
+                out = self._take_counts(np.asarray(out), width, rows, width)
             with trace.span("serve/decode/commit"):
                 # Drill seam: a kill here lands AFTER the iteration's
                 # compute but BEFORE any token is committed/acknowledged —

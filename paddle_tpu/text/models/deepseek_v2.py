@@ -43,7 +43,7 @@ from ... import nn
 from ...nn import initializer as I
 from ...nn.layer import ParamAttr
 from ...incubate.distributed.models.moe.dropless import (
-    dropless_glu_experts, group_limited_topk)
+    dropless_glu_experts, expert_form, group_limited_topk)
 from ...observability import metrics
 from ...ops.flash_attention import (flash_attention, latent_attention,
                                     latent_paged_attention)
@@ -506,12 +506,20 @@ class DeepseekV2ForCausalLM(nn.Layer):
         entries (what the engine hands ``takes_paged_kernel``)."""
         return self.cfg.kv_lora_rank
 
-    def serve_record_counts(self, load: np.ndarray, n_tokens: int) -> None:
+    def serve_record_counts(self, load: np.ndarray, n_tokens: int,
+                            n_slots: int) -> None:
         """The counters behind the programs' counts: ``n_tokens`` real tokens
         went through every expert layer, ``load[e]`` of their pairs fell to
-        held expert ``e``."""
+        held expert ``e``; the program was traced for ``n_slots`` tokens,
+        which is what chose its expert layers' form."""
         cfg = self.cfg
         n_moe = sum(1 for l in self.model.layers if l.is_moe)
+        metrics.counter(
+            "serving.moe_expert_calls",
+            "expert layers the launched prefill and decode programs ran, "
+            "by the form their token count selects (form=dense: every held "
+            "expert over the whole batch; form=grouped: sorted pairs)"
+        ).labels(form=expert_form(n_slots)).inc(n_moe)
         pairs = metrics.counter(
             "serving.moe_assignments",
             "(token, expert) pairs the router made (kind=routed: tokens x "

@@ -12,9 +12,11 @@ off around these compiles (a deviceless executable cannot be read back).
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -369,13 +371,25 @@ def test_latent_decode_kernel_compiles(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
 
 
+def _largest_moved(text):
+    """The most bytes any one copy or transpose of a compiled program
+    writes (its result's shape; fused computations included)."""
+    sizes = [0]
+    for m in re.finditer(r"= [a-z]+(\d+)\[([\d,]*)\]\S* (?:copy|transpose)\(",
+                         text):
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        sizes.append(int(np.prod(dims, dtype=np.int64)) * int(m.group(1)) // 8)
+    return max(sizes)
+
+
 def test_latent_serving_decode_program_compiles(one_chip, monkeypatch):
     """The engine's decode program over a latent pool as the chip runs it,
     one dense and one expert layer at the published widths (weights as
     zeros: nothing runs): one latent kernel call a layer sharing one
-    lowered function, the grouped expert products as XLA's own
-    ``ragged-dot`` calls, the one pool donated and aliased, and no gathered
-    copy of it among the temporaries."""
+    lowered function; the expert layer in its dense form at the bucket of
+    256 (no ``ragged-dot`` call, and the held experts' stacks read where
+    they lie: no copy or transpose as large as one); the one pool donated
+    and aliased, and no gathered copy of it among the temporaries."""
     import importlib
     from paddle_tpu.serving import ServingEngine
     from paddle_tpu.text.models.deepseek_v2 import (DeepseekV2Config,
@@ -408,7 +422,12 @@ def test_latent_serving_decode_program_compiles(one_chip, monkeypatch):
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("%latent_paged_attention") >= 2
-    assert "ragged-dot" in text
+    assert "ragged-dot" not in text
+    # the router's top-k sorts; the expert layer itself no longer does
+    assert not [line for line in text.splitlines()
+                if " sort(" in line and "moe/experts" in line]
+    # one stack of the held experts: 10 x 5120 x 1536 in bf16, 157 MB
+    assert _largest_moved(text) < model.model.layers[1].mlp.w_gate.nbytes
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * c["nb"] * c["bs"] * c["w"] * 2
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
@@ -418,7 +437,9 @@ def test_latent_serving_prefill_program_compiles(one_chip, monkeypatch):
     """The engine's prefill program at the cell's longest bucket, one dense
     and one expert layer at the published widths (weights as zeros: nothing
     runs): one flash forward a layer, keys padded to 256 beside values of
-    128, so no 256-wide value or output exists."""
+    128, so no 256-wide value or output exists; 4,096 tokens are more than
+    the dense form takes, so the expert layer's three products are grouped
+    (XLA's own ``ragged-dot`` calls over the sorted pairs)."""
     import importlib
     from paddle_tpu.serving import ServingEngine
     from paddle_tpu.text.models.deepseek_v2 import (DeepseekV2Config,
@@ -451,3 +472,4 @@ def test_latent_serving_prefill_program_compiles(one_chip, monkeypatch):
     assert f"tensor<{heads}x{s}x256xbf16>" in mlir      # padded q and k
     text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3

@@ -7,8 +7,10 @@ the program), on seeded weights from the benchmark's generator.
 full-forward logits; (b) absorbed decode attention equals the unabsorbed;
 (c) the 8 shares' routed parts, the shared experts counted once, add up to
 the uncut reference's expert layer; (d) group-limited routing picks the
-reference's experts, and no pair is dropped at any load. The latent decode
-kernel runs here in interpret mode against the dense path.
+reference's experts, and no pair is dropped at any load, in either form of
+the expert layer (grouped products over sorted pairs; every held expert
+over the whole batch), which agree. The latent decode kernel runs here in
+interpret mode against the dense path.
 """
 
 import importlib
@@ -29,7 +31,9 @@ import paddle_tpu as paddle  # noqa: E402
 from benchmark.lib import weights as LW  # noqa: E402
 from benchmark.lib.family import load_family  # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe.dropless import (  # noqa: E402
-    dropless_glu_experts, dropless_route, group_limited_topk)
+    DENSE_MAX_TOKENS, EXPERT_FORMS, dropless_glu_experts, dropless_route,
+    expert_form, group_limited_topk)
+from paddle_tpu.observability import metrics  # noqa: E402
 from paddle_tpu.ops._pallas.latent_paged_attention import (  # noqa: E402
     latent_paged_attention_pallas, supported_shapes)
 from paddle_tpu.serving import Request, ServingEngine  # noqa: E402
@@ -77,6 +81,23 @@ def ref_logits(fam, cfg, w, ids):
     x = ref.forward(w, np.asarray(ids, np.int32))
     return np.asarray(ref._head(x, jnp.arange(len(ids)), w["lnf_g"],
                                 w["head"]))
+
+
+def _expert_calls():
+    calls = metrics.counter("serving.moe_expert_calls")
+    return {form: calls.labels(form=form).get() for form in EXPERT_FORMS}
+
+
+def assert_greedy_by_the_reference(fam, cfg, w, reqs, res):
+    """Each request's served tokens are the reference's best wherever its
+    best logit leads by more than rounding."""
+    for q in reqs:
+        out = np.asarray(res[q.rid].output)
+        assert len(out) == len(q.prompt_ids) + q.max_new_tokens
+        lg = ref_logits(fam, cfg, w, out)[len(q.prompt_ids) - 1:-1]
+        served = out[len(q.prompt_ids):]
+        gap = lg.max(axis=-1) - lg[np.arange(len(served)), served]
+        assert gap.max() < 1e-4, (q.rid, gap)
 
 
 # -- (a) prefill, then decode through the latent paged cache ------------------
@@ -150,7 +171,7 @@ def test_engine_serves_the_references_greedy_tokens(built):
     """The whole engine (scheduler, allocator, refills: five requests through
     two rows) serves, token for token, the greedy continuation of the
     reference's logits, wherever the reference's best logit leads by more
-    than rounding."""
+    than rounding. Every expert layer runs in its dense form."""
     fam, cfg, w, model = built
     rng = np.random.default_rng(5)
     reqs = [Request(rid=f"r{i}", max_new_tokens=int(rng.integers(3, 8)),
@@ -159,16 +180,15 @@ def test_engine_serves_the_references_greedy_tokens(built):
             for i in range(5)]
     eng = ServingEngine(model, block_size=BS, num_blocks=33, max_batch=2,
                         max_seq_len=32)
+    before = _expert_calls()
     with jax.default_matmul_precision("highest"):
         res = eng.serve(reqs)
     assert len(res) == 5
-    for q in reqs:
-        out = np.asarray(res[q.rid].output)
-        assert len(out) == len(q.prompt_ids) + q.max_new_tokens
-        lg = ref_logits(fam, cfg, w, out)[len(q.prompt_ids) - 1:-1]
-        served = out[len(q.prompt_ids):]
-        gap = lg.max(axis=-1) - lg[np.arange(len(served)), served]
-        assert gap.max() < 1e-4, (q.rid, gap)
+    # no program here has more tokens than the dense form takes
+    after = _expert_calls()
+    assert after["grouped"] == before["grouped"]
+    assert after["dense"] > before["dense"]
+    assert_greedy_by_the_reference(fam, cfg, w, reqs, res)
 
 
 # -- (b) absorbed equals unabsorbed ------------------------------------------------
@@ -289,8 +309,9 @@ def test_group_limited_routing_picks_the_references_experts():
     assert sorted(np.asarray(got)[0]) == [0, 1]     # group 0 only
 
 
+@pytest.mark.parametrize("form", list(EXPERT_FORMS))
 @pytest.mark.parametrize("load", ["one_expert", "uneven", "none_held"])
-def test_no_pair_is_dropped_at_any_load(load):
+def test_no_pair_is_dropped_at_any_load(load, form):
     """Every (token, expert) pair routed to a held expert is computed: all
     tokens to one expert, a skewed load, and a load that misses the share."""
     rng = np.random.default_rng(1)
@@ -308,8 +329,8 @@ def test_no_pair_is_dropped_at_any_load(load):
         idx = np.stack([np.full(t, 1), np.full(t, 30)], axis=1)
     weight = jnp.asarray(rng.uniform(0.5, 2.0, (t, 2)), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        y, got_load = dropless_glu_experts(x, jnp.asarray(idx), weight, wg,
-                                           wu, wd, first=first)
+        y, got_load = EXPERT_FORMS[form](x, jnp.asarray(idx), weight, wg,
+                                         wu, wd, first=first)
         want = np.zeros((t, d), np.float32)
         for tok in range(t):
             for j in range(2):
@@ -324,6 +345,99 @@ def test_no_pair_is_dropped_at_any_load(load):
     np.testing.assert_allclose(y, want, atol=1e-5, rtol=1e-5)
     route = dropless_route(jnp.asarray(idx), e, first)
     assert route.token.shape == (t * 2,)    # room for every pair, always
+
+
+def _routing_case(case, t, rng):
+    """``(idx [t, k], experts held, first)`` of a router over 16 experts."""
+    e, first, k = 4, 8, 3
+    if case == "even":
+        first = 0
+        idx = (np.arange(t)[:, None] + np.arange(k)[None, :]) % e
+    elif case == "one_expert":
+        idx = np.stack([np.full(t, first + 2), np.full(t, 1), np.full(t, 15)],
+                       axis=1)
+    elif case == "none_held":
+        idx = np.stack([np.full(t, 1), np.full(t, 5), np.full(t, 14)], axis=1)
+    elif case == "first_gt_0":
+        idx = np.stack([rng.permutation(16)[:k] for _ in range(t)])
+    elif case == "padded":
+        idx = np.stack([rng.permutation(16)[:k] for _ in range(t)])
+        idx[rng.random(t) < 0.4] = -1        # how padding is routed nowhere
+        idx[-1] = -1
+    else:                                    # more picks than experts held
+        assert case == "k_gt_e"
+        e, k = 2, 5
+        idx = np.stack([rng.permutation(16)[:k] for _ in range(t)])
+        idx[::3, 0], idx[::3, 1] = first, first + 1
+    return idx.astype(np.int32), e, first
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tokens", [24, DENSE_MAX_TOKENS + 8])
+@pytest.mark.parametrize("case", ["even", "one_expert", "none_held",
+                                  "first_gt_0", "padded", "k_gt_e"])
+def test_the_two_forms_of_the_expert_layer_agree(case, tokens, dtype):
+    """The grouped and the dense form, called directly on the same inputs
+    at token counts on both sides of the threshold: the same loads exactly,
+    the same sums to float32 round-off (each form rounds a bfloat16 product
+    once, in another order of summation); ``dropless_glu_experts`` is the
+    one ``expert_form`` names for the count."""
+    rng = np.random.default_rng(tokens)
+    d, f = 16, 8
+    idx, e, first = _routing_case(case, tokens, rng)
+    dt = jnp.dtype(dtype)
+    x = jnp.asarray(rng.standard_normal((tokens, d)), dt)
+    wg, wu = (jnp.asarray(rng.standard_normal((e, d, f)) * 0.3, dt)
+              for _ in range(2))
+    wd = jnp.asarray(rng.standard_normal((e, f, d)) * 0.3, dt)
+    weight = jnp.asarray(rng.uniform(0.5, 2.0, idx.shape), jnp.float32)
+    args = (x, jnp.asarray(idx), weight, wg, wu, wd)
+    with jax.default_matmul_precision("highest"):
+        got = {name: fn(*args, first=first) for name, fn in
+               EXPERT_FORMS.items()}
+        picked = dropless_glu_experts(*args, first=first)
+    (yg, lg), (yd, ld) = got["grouped"], got["dense"]
+    assert yg.dtype == yd.dtype == jnp.float32 and ld.dtype == jnp.int32
+    np.testing.assert_array_equal(lg, ld)
+    np.testing.assert_array_equal(
+        ld, [(idx == first + j).sum() for j in range(e)])
+    tol = 1e-5 if dtype == "float32" else 3e-2
+    np.testing.assert_allclose(yd, yg, atol=tol, rtol=tol)
+    untouched = ~((idx >= first) & (idx < first + e)).any(axis=1)
+    assert not np.asarray(yd)[untouched].any()      # exactly zero, not small
+    form = expert_form(tokens)
+    assert form == ("dense" if tokens <= DENSE_MAX_TOKENS else "grouped")
+    np.testing.assert_array_equal(picked[0], got[form][0])
+    np.testing.assert_array_equal(picked[1], got[form][1])
+
+
+def test_engine_serves_the_references_tokens_through_both_forms(built):
+    """Prompts longer than the dense form's most tokens, so that each
+    prefill program (bucket 1024) runs the grouped form and each decode
+    program (bucket 2) the dense one: token for token the reference's greedy
+    continuation, and ``serving.moe_expert_calls`` moves by the expert
+    layers of the programs launched, each under its form."""
+    fam, cfg, w, model = built
+    rng = np.random.default_rng(8)
+    reqs = [Request(rid=f"r{i}", max_new_tokens=3 + i,
+                    prompt_ids=rng.integers(0, cfg["vocab_size"],
+                                            DENSE_MAX_TOKENS + 9 + 20 * i))
+            for i in range(2)]
+    eng = ServingEngine(model, block_size=16, num_blocks=129, max_batch=2,
+                        max_seq_len=1024, prefill_buckets=[1024],
+                        decode_buckets=[2])
+    before = _expert_calls()
+    with jax.default_matmul_precision("highest"):
+        res = eng.serve(reqs)
+    moved = {form: n - before[form] for form, n in _expert_calls().items()}
+    moe_layers = sum(1 for l in model.model.layers if l.is_moe)
+    assert moe_layers == 2
+    # a prefill a request; the two rows decode together, so the longer
+    # answer sets the number of decode programs
+    assert moved == {"grouped": 2 * moe_layers,
+                     "dense": (max(q.max_new_tokens for q in reqs) - 1)
+                     * moe_layers}
+    assert_greedy_by_the_reference(fam, cfg, w, reqs, res)
 
 
 # -- the latent decode kernel, interpreted ---------------------------------------------
