@@ -158,8 +158,7 @@ class CommSpec:
     # reduced_from_bytes is the flat-over-DCN blowup C004 catches.
     reduced_from_bytes: int = 0
     ici_size: int = 1
-    # One-direction per-rank payload crossing the link per step (the
-    # number the bench's multislice_dcn_bytes_per_step sums).
+    # One-direction per-rank payload crossing the link per step.
     payload_bytes: int = 0
 
     @property

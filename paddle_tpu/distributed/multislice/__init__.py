@@ -18,8 +18,8 @@ data-center network. This package makes the two link classes explicit:
 
 ``framework.sharded.TrainStep`` consumes the reducer behind
 ``FLAGS_multislice=off|flat|hierarchical``; ``tools/lint_graph.py
---model multislice`` and the ``BENCH_MULTISLICE`` bench leg verify and
-measure the composition chiplessly on the CPU mesh; the guarded drill
+--model multislice`` and ``tests/test_multislice.py`` verify the
+composition chiplessly on the CPU mesh; the guarded drill
 trainer (``fault/_trainer.py`` health mode) beats the monitor per step.
 """
 

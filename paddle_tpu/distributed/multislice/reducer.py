@@ -27,8 +27,7 @@ order) and **bitwise identical** to the flat per-axis baseline
 changes *where* each shard's identical rank-order sum is computed, not
 its association. The flat baseline still moves the whole bucket over
 DCN; the hierarchical plan moves 1/ici_size of it. That pairing is what
-the 2-slice dryrun (``tests/test_multislice.py``, ``bench.py``
-``BENCH_MULTISLICE``) asserts bitwise.
+the 2-slice dryrun (``tests/test_multislice.py``) asserts bitwise.
 """
 
 from __future__ import annotations
@@ -127,9 +126,8 @@ class HierarchicalGradReducer(BucketedGradReducer):
     def dcn_bytes_per_step(self, grads: Dict[str, Any], ici_size: int,
                            dcn_size: int,
                            mode: str = "hierarchical") -> int:
-        """Per-rank bytes crossing DCN in one reduction pass (the
-        ``multislice_dcn_bytes_per_step`` bench metric): the sum of the
-        dcn-class stages' payloads."""
+        """Per-rank bytes crossing DCN in one reduction pass: the sum of
+        the dcn-class stages' payloads."""
         return sum(s.payload_bytes
                    for s in self.hop_plan(grads, ici_size, dcn_size, mode)
                    if s.link == "dcn")

@@ -31,8 +31,9 @@ The loops are **unrolled** (the hop count is static and small), not
 collective-permute start/done of hop *t+1* with hop *t*'s matmul when
 both live in one straight-line block — a While body would serialize them.
 A chunk-count knob (``chunks`` sub-pieces per hop matmul) controls the
-scheduler's interleave granularity; the winner per (op, mesh, shape) is
-autotuned into the persistent kernel cache (``ops/_pallas/autotune.py``).
+scheduler's interleave granularity; :func:`pick_chunks` takes it from
+``FLAGS_comm_overlap_chunks`` or, per (op, mesh, shape), from the
+persistent kernel cache (``ops/_pallas/autotune.py``).
 
 **ZeRO-3 gather-ahead** (:func:`zero_gather_ahead`). GSPMD gathers
 fsdp-sharded params at first use — nothing is in flight ahead of the
@@ -64,7 +65,6 @@ other mesh axis left to GSPMD (partial-auto).
 
 from __future__ import annotations
 
-import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -79,7 +79,7 @@ __all__ = [
     "overlap_mode", "tp_enabled", "zero_enabled", "dp_enabled",
     "shard_map_compat", "can_decompose",
     "allgather_matmul", "matmul_reduce_scatter",
-    "pick_chunks", "tune_overlap_chunks",
+    "pick_chunks",
     "spec_without_axis", "zero_gather_ahead", "gather_ahead_plan",
     "BucketedGradReducer", "MP_AXIS", "GATHER_AHEAD_DEPTH",
     "SP_COMM_SPECS",
@@ -207,11 +207,8 @@ def _comm_span(op: str, spec, *operands):
 
 
 # ---------------------------------------------------------------------------
-# Chunk-count autotune (persistent cache)
+# Chunk count (flag, else the persistent cache)
 # ---------------------------------------------------------------------------
-
-_CHUNK_CANDIDATES = (1, 2, 4)
-
 
 def _chunks_key(op: str, n: int, x_shape, w_shape, dtype) -> str:
     return (f"{op}|n{n}|x{'x'.join(str(int(d)) for d in x_shape)}"
@@ -233,47 +230,6 @@ def pick_chunks(op: str, n: int, x_shape, w_shape, dtype,
         if c > 0 and s_local % c == 0:
             return c
     return 1
-
-
-def tune_overlap_chunks(op: str, x, w, b=None, mesh=None,
-                        axis: str = MP_AXIS,
-                        candidates: Sequence[int] = _CHUNK_CANDIDATES,
-                        warmup: int = 1, iters: int = 10) -> int:
-    """Measure the decomposed op at each sub-chunk count on the real
-    devices and persist the winner (keyed op × axis size × shapes ×
-    dtype × chip) in the kernel-autotune cache."""
-    import time
-    from ..ops._pallas.autotune import get_cache
-    mesh = _mesh_or_hybrid(mesh)
-    n = mesh.shape[axis]
-    fn = {ALLGATHER_MATMUL: allgather_matmul,
-          MATMUL_REDUCE_SCATTER: matmul_reduce_scatter}[op]
-    s_local = (x.shape[1] // n) if op == ALLGATHER_MATMUL \
-        else (x.shape[1] // n)
-    best_c, best_ms = 1, float("inf")
-    for c in candidates:
-        if s_local % c:
-            continue
-        run = jax.jit(lambda xx, ww: fn(xx, ww, b, mesh=mesh, axis=axis,
-                                        chunks=c))
-        try:
-            jax.block_until_ready(run(x, w))  # compile + warm
-            for _ in range(max(warmup - 1, 0)):
-                jax.block_until_ready(run(x, w))
-            t0 = time.perf_counter()  # repo-lint: allow R001
-            for _ in range(iters):
-                out = run(x, w)
-            jax.block_until_ready(out)
-            ms = (time.perf_counter() - t0) * 1e3 / iters  # repo-lint: allow R001
-        except Exception:
-            continue
-        if ms < best_ms:
-            best_c, best_ms = c, ms
-    if math.isfinite(best_ms):
-        get_cache().put("comm_overlap",
-                        _chunks_key(op, n, x.shape, w.shape, x.dtype),
-                        {"chunks": best_c}, best_ms)
-    return best_c
 
 
 # ---------------------------------------------------------------------------

@@ -690,8 +690,8 @@ def make_pipeline_train_step(pl, opt, hcg=None, n_microbatch: int = 1,
             return new_params, new_state, loss
         # Telemetry: dispatches are fingerprinted through the recompile
         # sentinel and timed as compile/device phases; .lower passes
-        # through, so compiled-cost introspection (bench rooflines) still
-        # reaches the executable.
+        # through, so compiled-cost introspection still reaches the
+        # executable.
         from ..observability.step_monitor import instrument_jitted
         return instrument_jitted(
             _step, name=f"pipeline_train_step:{loss_of.__name__}",

@@ -17,7 +17,7 @@ connects them into a story a production run can rely on:
 - :mod:`~paddle_tpu.fault.drill` — the end-to-end
   train→kill→relaunch→resume drill (``tools/fault_drill.py``) that asserts
   bitwise loss parity against an uninterrupted run and emits the goodput
-  record ``bench.py`` carries into ``BENCH_*.json``;
+  record (``tests/test_fault_drill.py``);
 - :mod:`~paddle_tpu.fault.health` /
   :mod:`~paddle_tpu.fault.guardian` — the training-health tier for runs
   that are *alive and wrong*: the fused step sentinel (NaN/spike/

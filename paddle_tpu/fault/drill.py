@@ -8,7 +8,7 @@ number of steps uninterrupted and checks **bitwise** loss parity — the
 proof that checkpoint + PRNG + batch-cursor state capture is complete.
 The run's goodput record (useful step time / wall time including
 restarts, restart count, lost steps, checkpoint save/restore durations)
-is what ``bench.py`` emits into ``BENCH_*.json``.
+is part of the drill's report.
 
 CLI: ``tools/fault_drill.py`` (``--quick`` is the tier-1-safe mode the
 test suite runs as a subprocess).

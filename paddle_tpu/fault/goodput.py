@@ -70,8 +70,8 @@ def parse_train_log(lines: Iterable[str]) -> Dict[str, Any]:
 
 def compute_goodput(log: Dict[str, Any], wall_s: float,
                     restarts: Optional[int] = None) -> Dict[str, Any]:
-    """Aggregate one fault-injected run's log into the goodput record the
-    bench JSON carries. ``log`` is :func:`parse_train_log` output; if
+    """Aggregate one fault-injected run's log into the goodput record of
+    the drill's report. ``log`` is :func:`parse_train_log` output; if
     ``restarts`` is None it is inferred from the ``start`` events (every
     incarnation logs one)."""
     steps = log["steps"]

@@ -24,12 +24,12 @@ update compute, turning HBM *capacity* into host-link *bandwidth*:
 - capacity plan: params, f32 masters, and grads stay resident (they are
   all touched by fwd/bwd, not just the update); see
   :class:`CapacityPlan` and ``tools/hbm_budget.py`` for the static
-  accounting the bench asserts before launching.
+  accounting.
 
 Wiring: ``FLAGS_offload_optimizer=off|moments`` (registry below) is read
 by ``framework.sharded.TrainStep`` (splits its compiled step into a
-grad-only jit plus a :class:`StreamingUpdate`) and usable directly, as
-``bench.py``'s single-chip GPT-1.3B measured run does. Any optimizer
+grad-only jit plus a :class:`StreamingUpdate`) and usable directly
+(``tests/test_offload.py``). Any optimizer
 that classifies its state via ``Optimizer.offloadable_state_keys()``
 participates; ``SGD(multi_precision=True)`` has no moments and is the
 zero-transfer resident baseline (≈6 B/param).

@@ -39,9 +39,9 @@ low-overhead measurement layer that is always there (gated by
 
 Wiring: ``framework.sharded.TrainStep``, ``framework.offload``,
 ``distributed.pipeline_schedule``, ``io.dataloader`` and ``hapi`` report
-into the process-wide timeline (``step_monitor.current()``); ``bench.py``
-A/Bs the overhead (``telemetry_overhead_pct``) and exports each run's
-timeline; ``tools/trace_view.py`` renders the JSONL. See OBSERVABILITY.md.
+into the process-wide timeline (``step_monitor.current()``);
+``tools/trace_view.py`` renders a ``trace.export_jsonl`` dump. See
+OBSERVABILITY.md.
 """
 
 from . import metrics  # noqa: F401

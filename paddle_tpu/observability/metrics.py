@@ -10,8 +10,8 @@ registry as the new telemetry series and shows up in both expositions:
 - :func:`prometheus_text` — Prometheus text format (names sanitized,
   histogram ``_bucket``/``_sum``/``_count`` with cumulative ``le``), for
   scraping a long-running trainer;
-- :func:`snapshot` — JSON-able nested dict, for one-shot dumps into bench
-  records and epoch logs.
+- :func:`snapshot` — JSON-able nested dict, for one-shot dumps into
+  reports and epoch logs.
 
 Histograms use **fixed log-scale buckets** (powers of two spanning
 ~1e-6..1e6) so two processes — or two snapshots of one process — always
@@ -321,7 +321,7 @@ class Registry:
         ``include_buckets=True`` additionally attaches each histogram
         series' raw per-bucket counts under ``"buckets"`` (exact-merge
         food for the fleet aggregator); the default keeps the compact
-        count/sum/avg/min/max shape bench records already embed."""
+        count/sum/avg/min/max shape."""
         out: Dict[str, Any] = {}
         for fam in self.families():
             series = []

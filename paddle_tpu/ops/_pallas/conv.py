@@ -24,18 +24,16 @@ byte-dominant ResNet shape classes:
   BN+ReLU prologue *recomputed in-kernel* from the raw input
   (flash-attention-style remat — only the pre-BN tensor is ever saved).
 
-Routing: ``FLAGS_pallas_conv`` (default OFF until a measured win — see
-the ``BENCH_PALLAS_CONV=1`` A/B hook in ``bench.py``) swaps these kernels
-into the deferred-BN units of ``nn/fused_conv_bn.py``; unsupported shapes
-(groups, dilation, other kernel sizes, over-VMEM configs) fall back to
-the lax path inside the same custom_vjp boundaries. On non-TPU backends
+Routing: ``FLAGS_pallas_conv`` (default OFF: no measured win) swaps
+these kernels into the deferred-BN units of ``nn/fused_conv_bn.py``;
+unsupported shapes (groups, dilation, other kernel sizes, over-VMEM
+configs) fall back to the lax path inside the same custom_vjp boundaries. On non-TPU backends
 the kernels run in Pallas interpret mode, so the whole family is
 CPU-verifiable (tier-1 parity tests in ``tests/test_pallas_conv.py``).
 
 Block configs consult the persistent device-time autotune cache
 (``ops/_pallas/autotune.py``; keys ``pallas_conv1x1`` / ``pallas_conv3x3``)
-before the static divisor tables; ``tune_conv_shapes`` sweeps and
-persists winners on a real chip. Declared configurations are checked
+before the static divisor tables. Declared configurations are checked
 against the TPU constraints (16MB scoped VMEM incl. im2col tiles,
 (8,128) tiles, grid divisibility) by ``analysis/pallas_check.py``.
 """
@@ -55,7 +53,7 @@ from ...core import flags as _flags
 
 __all__ = [
     "conv2d", "conv2d_fwd", "conv2d_dgrad", "conv2d_wgrad", "supports",
-    "pallas_conv_enabled", "tune_conv_shapes", "RESNET50_TOP3_SHAPES",
+    "pallas_conv_enabled", "RESNET50_TOP3_SHAPES",
 ]
 
 if "pallas_conv" not in _flags.get_flags():
@@ -63,7 +61,7 @@ if "pallas_conv" not in _flags.get_flags():
         "pallas_conv", 0,
         "route supported convs (1x1-as-matmul, NHWC 3x3 s1/s2) through "
         "the Pallas conv kernel family with in-kernel BN epilogues "
-        "(default off until a measured win; A/B via BENCH_PALLAS_CONV=1)")
+        "(default off until a measured win)")
 
 # The three byte-dominant conv shape classes of the r5 ResNet-50 profile
 # (tools/resnet_bytes.py, batch 256, bw-derived GB/step: the stage-1
@@ -697,48 +695,3 @@ def supports(x_shape, w_shape, stride=(1, 1), padding=(0, 0),
         if any(d.severity == "error" for d in check_kernel_spec(spec)):
             return False
     return True
-
-
-# ---------------------------------------------------------------------------
-# Autotune registration (device rounds; persists winners in the cache)
-# ---------------------------------------------------------------------------
-
-def tune_conv_shapes(shapes=None, dtype=jnp.bfloat16, warmup: int = 1,
-                     iters: int = 3):
-    """Sweep block candidates for the byte-dominant ResNet conv shapes on
-    the attached device and persist winners in the autotune cache (the
-    ``_pick_block_*`` selectors consult it before the divisor tables).
-    Returns {(kernel, key): winning_block}."""
-    import numpy as np
-    from .autotune import autotune
-    out = {}
-    rng = np.random.default_rng(0)
-    for kind, n, h, w, cin, cout, s_ in (shapes or RESNET50_TOP3_SHAPES):
-        x = jnp.asarray(rng.standard_normal((n, h, w, cin)), dtype)
-        k = 1 if kind == "conv1x1" else 3
-        wgt = jnp.asarray(rng.standard_normal((cout, cin, k, k)) * 0.05,
-                          dtype)
-        scale = jnp.ones((cin,), jnp.float32)
-        shift = jnp.zeros((cin,), jnp.float32)
-        stride = (s_, s_)
-        pad = (0, 0) if k == 1 else (1, 1)
-
-        def run(blk, _x=x, _w=wgt, _k=k, _stride=stride, _pad=pad):
-            kw = {"block_m": blk} if _k == 1 else {"block_h": blk}
-            fn = jax.jit(functools.partial(
-                conv2d_fwd, act="relu", stride=_stride, padding=_pad,
-                stats=True, **kw))
-            return fn(_x, _w, scale, shift)
-
-        if k == 1:
-            m = n * ((h + s_ - 1) // s_) * ((w + s_ - 1) // s_)
-            kernel, key = "pallas_conv1x1", _mm_key(m, cin, cout, dtype)
-            cands = [b for b in _MM_BLOCKS if m % b == 0]
-        else:
-            ho = (h + 2 - 3) // s_ + 1
-            kernel, key = "pallas_conv3x3", _c3_key(n, h, w, cin, cout, s_,
-                                                    dtype)
-            cands = [b for b in _C3_BLOCKS if ho % b == 0]
-        out[(kernel, key)] = autotune(kernel, key, cands, run,
-                                      warmup=warmup, iters=iters)
-    return out

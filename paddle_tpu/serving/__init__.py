@@ -15,13 +15,11 @@ overload load shedding (:class:`ShedPolicy`), per-request failure
 isolation (F003 — pool exhaustion and spill errors never cross the
 engine loop), and the exactly-once :class:`RequestJournal` the serve
 drill (``tools/serve_drill.py``) kills the process against.
-``bench.py`` (``BENCH_SERVE``) measures tokens/s and p50/p99 request
-latency against the sequential one-shot baseline plus SLO attainment
-and shed rate from a fault-injected overload trace;
-``tools/serve_bench.py`` replays request traces (``--deadline-ms`` /
-``--fail-on-slo`` is the CI gate form); ``lint_graph --model serving``
-statically verifies the prefill/decode programs and the declared
-dispatch plan.
+The benchmark's serving cells (``BENCHMARK.json``, ``benchmark/run.py``)
+measure tokens/s and inter-token latency on the chip;
+``tests/test_serving.py`` holds the SLO attainment and shed-rate
+arithmetic; ``lint_graph --model serving`` statically verifies the
+prefill/decode programs and the declared dispatch plan.
 """
 
 from .buckets import BucketSet, pow2_buckets  # noqa: F401

@@ -17,8 +17,7 @@ asserts the serving resilience contract:
   ``model.generate`` on the same prompt (greedy), kills or not.
 
 CLI: ``tools/serve_drill.py`` (``--quick`` is the tier-1-safe mode
-``tests/test_serve_drill.py`` runs as a subprocess); ``bench.py``
-(``BENCH_SERVE``) embeds the recovery stats next to the SLO metrics.
+``tests/test_serve_drill.py`` runs as a subprocess).
 """
 
 from __future__ import annotations

@@ -919,8 +919,8 @@ class ServingEngine:
         return self._p99
 
     def reset_peaks(self) -> None:
-        """Restart the peak-blocks watermarks (bench arms measure the
-        steady state, not the warmup)."""
+        """Restart the peak-blocks watermarks (to read the steady
+        state, not the warmup)."""
         self.peak_blocks_used = 0
         self.peak_live_blocks = 0
         self._gauges()

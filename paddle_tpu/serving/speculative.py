@@ -125,9 +125,8 @@ def pick_gamma(target_desc: str, drafter_desc: str,
 
 def store_gamma(target_desc: str, drafter_desc: str, gamma: int,
                 measured_ms: float = 0.0) -> int:
-    """Persist a measured-winner gamma directly (the bench's gamma
-    sweep stores the throughput-best arm; :func:`tune_gamma` is the
-    accepted-length heuristic for when no sweep ran)."""
+    """Persist a measured-winner gamma directly (:func:`tune_gamma` is
+    the accepted-length heuristic for when no sweep ran)."""
     from ..ops._pallas.autotune import get_cache
     gamma = int(gamma)
     get_cache().put(_TUNE_KERNEL, _cache_key(target_desc, drafter_desc),

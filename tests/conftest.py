@@ -31,6 +31,16 @@ def _seed_everything():
     yield
 
 
+@pytest.fixture(autouse=True)
+def _reset_mesh():
+    """No test hands the next one on its worker a hybrid mesh: a file that
+    sets one (``set_hybrid_mesh``, ``fleet.init``, or a collective's
+    implicit world mesh) would otherwise decide how later files compile."""
+    yield
+    from paddle_tpu.distributed.topology import set_hybrid_mesh
+    set_hybrid_mesh(None)
+
+
 @pytest.fixture
 def mesh8():
     """Fresh 8-device mesh helper; tests parametrize axis shapes."""

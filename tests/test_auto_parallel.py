@@ -9,7 +9,6 @@ numerics.
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -19,12 +18,6 @@ from paddle_tpu.distributed.auto_parallel import (Engine, ProcessMesh,
                                                   shard_tensor, shard_op)
 from paddle_tpu.distributed.topology import (create_hybrid_mesh,
                                              set_hybrid_mesh)
-
-
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    yield
-    set_hybrid_mesh(None)
 
 
 def test_process_mesh_basics():

@@ -4,18 +4,10 @@ trial loop) on the 8-device CPU mesh."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.distributed.auto_tuner import (AutoTuner, GridSearch,
                                                HistoryRecorder)
-from paddle_tpu.distributed.topology import set_hybrid_mesh
-
-
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    yield
-    set_hybrid_mesh(None)
 
 
 def test_grid_search_prunes_invalid():

@@ -12,7 +12,6 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -21,12 +20,6 @@ from paddle_tpu.distributed.checkpoint import (load_sharded, load_state,
                                                save_sharded, save_state)
 from paddle_tpu.distributed.topology import create_hybrid_mesh, set_hybrid_mesh
 from paddle_tpu.framework.functional import get_params
-
-
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    yield
-    set_hybrid_mesh(None)
 
 
 def _params_on_mesh_a():

@@ -48,22 +48,6 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _no_ambient_mesh():
-    """These compiles are for ONE described chip. A hybrid mesh of CPU
-    devices that an earlier test file left in this worker would put a
-    ``shard_map`` or a sharding constraint over eight CPU devices into the
-    program, which cannot be lowered beside arguments on the described
-    chip (ROADMAP C11: nine names of this file failed that way, or passed,
-    by what the worker had run before)."""
-    from paddle_tpu.distributed.topology import (get_hybrid_mesh,
-                                                 set_hybrid_mesh)
-    prev = get_hybrid_mesh()
-    set_hybrid_mesh(None)
-    yield
-    set_hybrid_mesh(prev)
-
-
 def _compile(fn, one_chip, *shapes):
     """Lower ``fn`` on ShapeDtypeStructs placed on the described chip and
     compile it; returns the compiled text."""

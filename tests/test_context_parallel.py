@@ -15,12 +15,6 @@ from paddle_tpu.distributed.topology import (create_hybrid_mesh,
 from paddle_tpu.ops.flash_attention import reference_attention
 
 
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    yield
-    set_hybrid_mesh(None)
-
-
 def _qkv(b=2, s=64, h=4, d=16, seed=0):
     rng = np.random.default_rng(seed)
     mk = lambda: jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)

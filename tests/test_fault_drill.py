@@ -35,7 +35,7 @@ def test_quick_drill_subprocess(tmp_path):
     fired_kinds = {e.split("@")[0] for e in report["fired_events"]}
     assert fired_kinds == {"mid_step", "mid_ckpt_write"}
 
-    # the measured goodput record the bench JSON carries
+    # the measured goodput record
     g = report["goodput_record"]
     assert 0.0 < g["goodput"] <= 1.0
     assert g["restarts"] == 2            # one relaunch per kill

@@ -431,28 +431,6 @@ def test_lint_graph_json_rule_index(capsys):
         assert entry["count"] == sum(entry["ids"].values())
 
 
-def test_bench_hlo_verify_helper(monkeypatch):
-    """bench.py's per-leg X pass: a clean single-chip step reports zero
-    undeclared collectives, and _emit carries the two fields."""
-    import io, json
-    from contextlib import redirect_stdout
-    import bench
-
-    # _emit reports the chip's peak, and the CPU is in no peak table
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "197")
-
-    compiled = aot_compile(lambda a: a @ a + 1, jnp.ones((32, 32)))
-    bench._hlo_verify_compiled(compiled)
-    assert bench._HLO_VERIFY["hlo_undeclared_collectives"] == 0
-    assert bench._HLO_VERIFY["hlo_verify_ms"] is not None
-    buf = io.StringIO()
-    with redirect_stdout(buf):
-        bench._emit("test_metric", 1.0, "unit", 0.0, {})
-    rec = json.loads(buf.getvalue())
-    assert rec["extra"]["hlo_undeclared_collectives"] == 0
-    assert "hlo_verify_ms" in rec["extra"]
-
-
 def test_hlo_rules_registered():
     ids = {r.rule_id for r in hlo_check.all_hlo_rules()}
     assert ids == {"X001", "X002", "X003", "X004", "X005"}

@@ -13,12 +13,6 @@ from paddle_tpu.framework.functional import functional_call, get_params
 from paddle_tpu.incubate.distributed.models.moe.moe_layer import MoELayer
 
 
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    yield
-    set_hybrid_mesh(None)
-
-
 def _x(b=2, s=16, d=8, seed=0):
     rng = np.random.default_rng(seed)
     return jnp.asarray(rng.standard_normal((b, s, d)), jnp.float32)

@@ -59,7 +59,6 @@ def ms_flags():
     prev = core_flags.get_flags(["multislice", "multislice_dcn_bucket_mb"])
     yield
     core_flags.set_flags(prev)
-    set_hybrid_mesh(None)
 
 
 # ---------------------------------------------------------------------------

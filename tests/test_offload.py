@@ -319,8 +319,8 @@ def test_hbm_budget_known_depths():
 
     n, _, _ = hbm_budget.gpt_param_counts(24, 2048, 2048, 50304)
     assert n == 1315819520  # exact count of the built 1.3B model
-    # L=12 resident Adam fits (the BENCH_r05 measured point); L=24 does
-    # not (the 18.4 GB wall); offloading the moments makes L=24 fit.
+    # L=12 resident Adam fits (the benchmark's training cell runs it); L=24
+    # does not (the 18.4 GB wall); offloading the moments makes L=24 fit.
     assert hbm_budget.gpt_plan(layers=12)["fits"]
     assert not hbm_budget.gpt_plan(layers=24)["fits"]
     b, plan = hbm_budget.choose_batch(layers=24, optimizer="adamw",

@@ -274,11 +274,13 @@ def _roofline_reader():
             peaks, readers, trace)
 
 
-def _serve_ctx(readers, peaks, tr, steps):
+def _serve_ctx(root, readers, peaks, tr, steps):
+    from benchmark.lib.family import load_family
+    cfg = {"model": "gpt", "num_layers": 2, "hidden_size": 2048}
     return readers.Ctx(
-        run={"traced": {"steps": steps}},
-        cfg={"num_layers": 2, "hidden_size": 2048}, mix={}, cell={}, chips=1,
-        peaks=peaks.PEAKS["TPU v5 lite"], trace=tr, win=(0.0, 1.0))
+        run={"traced": {"steps": steps}}, cfg=cfg, mix={}, cell={}, chips=1,
+        peaks=peaks.PEAKS["TPU v5 lite"], family=load_family(root, cfg),
+        trace=tr, win=(0.0, 1.0))
 
 
 def test_roofline_reader_counts_only_the_decode_programs_custom_calls():
@@ -286,7 +288,7 @@ def test_roofline_reader_counts_only_the_decode_programs_custom_calls():
     decode program with one kernel call a layer. The kernel's least time is
     K and V of the rows' real contexts at the bandwidth peak; the flash call
     and a decode program's other ops are not its time."""
-    _, read, peaks, readers, TR = _roofline_reader()
+    root, read, peaks, readers, TR = _roofline_reader()
     call = ('%paged_single_query_attention.{} = bf16[32,16,128] custom-call'
             '(...), custom_call_target="tpu_custom_call"')
     flash = '%flash.1 = bf16[16,512,128] custom-call(...), ' \
@@ -305,7 +307,7 @@ def test_roofline_reader_counts_only_the_decode_programs_custom_calls():
     tr = TR.Trace([TR.Device("/device:TPU:0", ops, mods)], spans)
     steps = [{"prefills": [300], "decode_ctx": [301, 500]},
              {"prefills": [], "decode_ctx": [302, 501]}]
-    got = read(_serve_ctx(readers, peaks, tr, steps))
+    got = read(_serve_ctx(root, readers, peaks, tr, steps))
     keys = (301 + 500 + 302 + 501) * 2                 # a layer each
     least = keys * 2 * 2048 * 2 / 819e9                # K and V, bf16
     assert got["value"] == pytest.approx(100 * least / 160e-6, rel=1e-9)
@@ -314,7 +316,7 @@ def test_roofline_reader_counts_only_the_decode_programs_custom_calls():
     assert got["ms_per_call"] == pytest.approx(0.04)
     # the gather-and-dense decode program has no such call: nothing to read
     tr.devices[0].ops = [e for e in ops if "paged" not in e.name]
-    assert read(_serve_ctx(readers, peaks, tr, steps)) is None
+    assert read(_serve_ctx(root, readers, peaks, tr, steps)) is None
 
 
 def test_roofline_reader_reads_nothing_in_a_trace_of_the_gather_program(
@@ -332,7 +334,7 @@ def test_roofline_reader_reads_nothing_in_a_trace_of_the_gather_program(
     tr = TR.load(str(dst))
     steps = [{"prefills": [400, 900, 400, 900], "decode_ctx": [401] * 4}] \
         + [{"prefills": [], "decode_ctx": [402 + i] * 4} for i in range(4)]
-    ctx = _serve_ctx(readers, peaks, tr, steps)
+    ctx = _serve_ctx(root, readers, peaks, tr, steps)
     ctx.win = (tr.spans[0].start, tr.spans[-1].end)
     assert len(readers.decode_programs(ctx)) == 5
     assert read(ctx) is None

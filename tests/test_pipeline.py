@@ -22,12 +22,6 @@ from paddle_tpu.framework.functional import get_params, set_params
 from paddle_tpu.optimizer import AdamW
 
 
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    yield
-    set_hybrid_mesh(None)
-
-
 def test_spmd_pipeline_matches_sequential():
     S, n_micro, mb, d = 4, 8, 2, 16
     mesh = create_hybrid_mesh(pp=S, dp=2)

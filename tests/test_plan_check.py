@@ -33,13 +33,12 @@ def errors_of(diags):
 
 
 @pytest.fixture(autouse=True)
-def _restore_flags_and_mesh():
+def _restore_flags():
     prev = {k: core_flags.flag(k)
             for k in ("offload_optimizer", "comm_overlap",
                       "cp_nested_ring")}
     yield
     core_flags.set_flags(prev)
-    set_hybrid_mesh(None)
 
 
 def _micro_ts(offload="off", comm_overlap="off", remat=False):

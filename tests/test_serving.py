@@ -7,8 +7,8 @@ per-request failure isolation, cancellation hygiene, and the
 exactly-once request journal.
 
 Everything runs on the CPU mesh with micro GPT configs — this file is
-the tier-1-safe quick serving gate (the full sweep lives in bench.py
-under BENCH_SERVE; the subprocess kill drill in test_serve_drill.py).
+the tier-1-safe quick serving gate (the subprocess kill drill is in
+test_serve_drill.py).
 """
 
 import json
@@ -679,37 +679,6 @@ class TestServingPlan:
         ])
         diags = plan_check.check_plan(plan)
         assert any(d.rule == "D001" for d in diags)
-
-
-# ---------------------------------------------------------------------------
-# serve_bench CLI (in-process replay)
-# ---------------------------------------------------------------------------
-
-class TestServeBenchCLI:
-    def test_replay_json_summary(self, tmp_path, capsys):
-        import importlib.util
-        import os
-        spec = importlib.util.spec_from_file_location(
-            "serve_bench", os.path.join(os.path.dirname(__file__), "..",
-                                        "tools", "serve_bench.py"))
-        sb = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(sb)
-        trace = tmp_path / "trace.jsonl"
-        trace.write_text("\n".join(
-            json.dumps({"rid": f"q{i}", "prompt_len": 4 + 3 * i,
-                        "max_new_tokens": 3}) for i in range(3)))
-        timeline = tmp_path / "req.jsonl"
-        rc = sb.main(["--trace", str(trace), "--json", "--layers", "1",
-                      "--hidden", "32", "--heads", "2", "--vocab", "64",
-                      "--max-pos", "32", "--num-blocks", "16",
-                      "--timeline", str(timeline)])
-        assert rc == 0
-        report = json.loads(capsys.readouterr().out)
-        assert report["requests"] == 3 and report["new_tokens"] == 9
-        assert report["tokens_per_s"] > 0
-        assert report["p99_ms"] >= report["p50_ms"]
-        assert not report["compile_report"]["o001_fired"]
-        assert len(timeline.read_text().splitlines()) == 3
 
 
 # ---------------------------------------------------------------------------

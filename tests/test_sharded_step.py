@@ -8,11 +8,9 @@ comparison, without processes — the mesh is the cluster)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.distributed.topology import (create_hybrid_mesh,
-                                             set_hybrid_mesh)
+from paddle_tpu.distributed.topology import create_hybrid_mesh
 from paddle_tpu.framework.functional import functional_call, get_params
 from paddle_tpu.framework.sharded import (infer_param_specs,
                                           make_sharded_train_step)
@@ -20,12 +18,6 @@ from paddle_tpu.optimizer import AdamW, SGD
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
 from jax.sharding import Mesh, PartitionSpec as P
-
-
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    yield
-    set_hybrid_mesh(None)
 
 
 def _tiny_gpt(seed=0):

@@ -27,13 +27,6 @@ from paddle_tpu.core import flags
 from paddle_tpu.framework import step_pipeline as sp
 
 
-@pytest.fixture(autouse=True)
-def _reset_mesh():
-    from paddle_tpu.distributed.topology import set_hybrid_mesh
-    yield
-    set_hybrid_mesh(None)
-
-
 def _all_combo_hashes():
     out = {}
     for i, combo in enumerate(plan_check.iter_tier_combos()):

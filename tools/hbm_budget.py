@@ -10,14 +10,11 @@ by ``framework/offload.py``:
   moments) | activation checkpoints (remat: one block-boundary tensor per
   layer) | remat working set | logits/CE transient
 
-``bench.py`` calls :func:`gpt_plan` before launching the full-depth
-GPT-1.3B measured run, records the plan in the emitted JSON ``extra``,
-and uses :func:`choose_batch` to pick the largest batch that fits. The
-arithmetic is validated against the depths that are KNOWN to fit or not:
-L=12 resident Adam at batch 4 fits (BENCH_r05 measured point), L=24
-resident Adam does not (18.4 GB state > 15.75 GB — the reason the
-flagship number was an extrapolation for two rounds), L=24 offloaded
-Adam and L=24 SGD-no-moment must.
+The arithmetic is validated against the depths that are KNOWN to fit or
+not: L=12 resident Adam at batch 4 fits (the benchmark's cell
+``train.gpt3-1.3b-l12.b4s2048`` runs it), L=24 resident Adam does not
+(18.4 GB state > 15.75 GB), L=24 offloaded Adam and L=24 SGD-no-moment
+must.
 
 CLI:
     python tools/hbm_budget.py --layers 24 --offload moments
@@ -107,7 +104,7 @@ def gpt_plan(layers: int = 24, hidden: int = 2048, heads: int = 16,
 
 def choose_batch(candidates=(4, 2, 1), **kwargs):
     """Largest candidate batch whose plan fits (None if none do), plus
-    that plan — bench's pre-launch gate."""
+    that plan."""
     for b in candidates:
         plan = gpt_plan(batch=b, **kwargs)
         if plan["fits"]:

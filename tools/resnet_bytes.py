@@ -6,8 +6,7 @@ Usage: python tools/resnet_bytes.py [fused|pallas|plain]
 ``pallas`` additionally routes the fused units through the Pallas conv
 kernel family (FLAGS_pallas_conv — ops/_pallas/conv.py). The top-3
 byte-dominant conv shape classes this profile identified (r5, batch 256)
-are recorded as ``RESNET50_TOP3_SHAPES`` in that module; the per-shape
-kernel A/B against them runs via ``BENCH_PALLAS_CONV=1 python bench.py``.
+are recorded as ``RESNET50_TOP3_SHAPES`` in that module.
 """
 import functools
 import glob

@@ -6,19 +6,18 @@ per step: ``{"kind": "step", "step": N, "phases": {...}, "total_ms": ..,
 "hbm_peak_gb": ..}``), optionally interleaved with ``trace.export_jsonl``
 span records (``{"kind": "span", "name": .., "t0_ns": .., "dur_ns": ..,
 "id": .., "parent": ..}``; files from before ISSUE 26 carry ``dur_us`` and no
-ids) — bench runs write both into one file. Spans with ids also get a table
+ids). Spans with ids also get a table
 of SELF time by span name: each span's duration less what its children
 cover, which is where a serving step's or a train step's host time actually
 goes. The tool reads files and imports nothing of the package.
 
-    python tools/trace_view.py BENCH_timeline.jsonl
+    python tools/trace_view.py spans.jsonl               # a trace.export_jsonl dump
     python tools/trace_view.py run.jsonl --json          # machine output
     python tools/trace_view.py run.jsonl --factor 2.5    # anomaly knob
 
 Anomaly rule: a step whose ``total_ms`` exceeds ``factor`` (default 3x)
 times the rolling median of the preceding ``window`` steps is flagged —
-the post-hoc version of bench.py's roofline guard, usable on any recorded
-run without knowing the model.
+usable on any recorded run without knowing the model.
 """
 
 from __future__ import annotations
