@@ -55,19 +55,32 @@ verify the serving path like every training tier (``lint_graph --model
 serving``). At runtime the same isolation is asserted per dispatch:
 no scatter ever targets a device block the prefix tree holds.
 
-**One decode iteration is always in flight.** ``step()`` ends by launching
-the decode program over the resident rows and returns without waiting; the
-next ``step()`` admits (its prefills queue behind that program), takes the
-tokens, commits them, tops up blocks and launches again. So the device runs
-the decode while the host returns finished requests, takes new ones and
-builds their prefills, instead of idling through all of that; what stays
-between two device programs is the wake-up after the wait, the commit and
-the build of the next launch. Host state is fully committed before each
-launch (nothing is guessed about who finishes); the one thing that can
-happen to a row while its token is in flight is that it is cancelled or
-preempted, and then the token is dropped and computed again if the row
-comes back (``_decode_collect``). A request joins the batch at the launch
-after its prefill, so its second token is seen one step after its first.
+**One decode iteration is always in flight, and the next is launched before
+its tokens are taken.** ``step()`` admits (the prefills queue behind the
+decode program the last step launched), tops up blocks, builds and launches
+the next decode iteration, and only then waits for the last one's tokens and
+commits them. A row that was in the last launch reads its token on the
+device, from that launch's result (the decode program takes the previous
+result ``prev`` and a row map ``src``: ``tokens = where(src >= 0, prev[src],
+tokens)``); a row that was not (fresh from a prefill, restored, back after a
+preemption) is fed by the host as before, in the same launch of the same
+program. So on a step that admits nothing the device goes from one decode
+program into the next, and the wake-up after the wait, the commit, the
+top-up, the build and the launch all happen beside it; on a step that admits,
+the prefill still waits for its first token and the device idles from there
+to the launch. The host decides a launch's rows before it knows the tokens in
+flight, with one guess: a row that reaches ``max_new_tokens`` with the token
+in flight is known to end and is left out, but a token that ends a request
+some other way (``eos_token_id``) is not known, so such a row runs once more
+and that token is dropped when it arrives. The same happens to a row
+cancelled or preempted while its token is in flight: dropped, and computed
+again if the row comes back (``_decode_collect``). A launch whose bucket
+differs from the one in flight takes the old order for that once (tokens
+first, every row fed by the host): each bucket has one compiled program,
+whose ``prev`` has that bucket's shape. ``serving.decode_rows{fed}`` counts
+the rows by where their token came from, and the dropped tokens. A request
+joins the batch at the launch after its prefill, so its second token is seen
+one step after its first.
 
 **The model seam.** The three programs know no model: they ask the model
 for its layer step and for what it caches a token. A model serves by giving
@@ -106,8 +119,8 @@ import math
 import time
 import types
 from collections import deque
-from typing import (Any, Callable, Dict, List, Optional, Sequence as Seq,
-                    Tuple, Union)
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence as Seq, Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -157,9 +170,17 @@ def _meters() -> types.SimpleNamespace:
         "serving.prefill_tokens",
         "prompt tokens prefilled (kind=real) and the bucket lengths they "
         "were padded to (kind=bucket)")
+    fed = metrics.counter(
+        "serving.decode_rows",
+        "rows of the launched decode iterations by where their token came "
+        "from (fed=device: the launch in flight, on the device; fed=host), "
+        "and tokens in flight that were thrown away (fed=dropped)")
     return types.SimpleNamespace(
         kv_needed=kv.labels(kind="needed"),
         kv_gathered=kv.labels(kind="gathered"),
+        fed_device=fed.labels(fed="device"),
+        fed_host=fed.labels(fed="host"),
+        fed_dropped=fed.labels(fed="dropped"),
         prefill_real=pf.labels(kind="real"),
         prefill_bucket=pf.labels(kind="bucket"),
         queue_depth=metrics.gauge(
@@ -192,6 +213,27 @@ def _account(t0_ns: int, end_ns: int, phase: str, seqs) -> None:
     dur_s = (end_ns - t0_ns) * 1e-9
     for seq in seqs:
         seq.add_phase(phase, dur_s)
+
+
+def _still_rows(seq: Sequence, epoch: int) -> bool:
+    """Whether a row launched at preemption count ``epoch`` still is its
+    sequence's: not cancelled, finished or preempted since."""
+    return seq.status is Status.RUNNING and seq.preemptions == epoch
+
+
+class _Launch(NamedTuple):
+    """A decode iteration on the device whose tokens the host has not taken:
+    its rows, each row's preemption count at the launch, the bucket, the
+    contexts as launched, the program's result (still on the device: the
+    next launch of this bucket reads its tokens there) and when the
+    iteration began."""
+    batch: List[Sequence]
+    epochs: List[int]
+    width: int
+    lens: np.ndarray
+    out: Any
+    t0_ns: int
+    row_of: Dict[int, int]      # id(sequence) -> its row
 
 
 class _ParamJit:
@@ -366,9 +408,12 @@ class ServingEngine:
             maxlen=shed_policy.window if shed_policy else 64)
         self._p99: Optional[float] = None   # of _decode_ms, as of _p99_at
         self._p99_at = -1
-        #: the decode iteration in flight: launched at the end of a step,
-        #: its tokens taken at the next (_decode_iteration, _decode_collect)
-        self._ahead: Optional[Tuple] = None
+        #: the decode iteration in flight: launched in one step, its tokens
+        #: taken in the next, after that step's own launch
+        #: (_decode_iteration, _decode_collect)
+        self._ahead: Optional[_Launch] = None
+        #: bucket -> what a launch with no predecessor reads as ``prev``
+        self._no_prev: Dict[int, Any] = {}
         # a shed policy ACTS on the decode iteration's duration, so its two
         # spans measure in every telemetry mode
         self._acted_span = trace.timed_span if shed_policy is not None \
@@ -509,8 +554,19 @@ class ServingEngine:
             ctx_lens [B] tokens already cached (0 = inactive pad row, which
             harmlessly writes the null block and produces a discarded
             output). One iteration: write each token's page row at position
-            ctx_len, attend over ctx_len+1 rows, return the next token."""
-            pools, (tables, ctx_lens) = list(rest[:n_pools]), rest[n_pools:]
+            ctx_len, attend over ctx_len+1 rows, return the next token.
+
+            The engine's own decode also takes ``prev``, what the previous
+            launch of this bucket returned (tokens first), and ``src`` [B]:
+            row i reads its token from ``prev[src[i]]`` where ``src[i] >= 0``
+            (the row that sequence held in that launch, whose token the host
+            has not seen yet) and from ``tokens[i]`` where it is -1."""
+            pools = list(rest[:n_pools])
+            tables, ctx_lens, *fed = rest[n_pools:]
+            if fed:
+                prev, src = fed
+                tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
+                                   tokens)
             pos = ctx_lens
             real = (ctx_lens > 0)[:, None] if counted else None
             counts = []
@@ -626,8 +682,10 @@ class ServingEngine:
                 donates=("kv_pages",),
                 writes=("kv_pages", "next_tokens")))
         nodes += [
+            # resident rows read their token from the last launch's result
             PlanNode("serve.decode",
-                     reads=("weights", "block_tables", "ctx_lens"),
+                     reads=("weights", "block_tables", "ctx_lens",
+                            "next_tokens"),
                      donates=("kv_pages",),
                      writes=("kv_pages", "next_tokens")),
             PlanNode("serve.spill", reads=("kv_pages",),
@@ -672,8 +730,7 @@ class ServingEngine:
             jax.ShapeDtypeStruct((), i32))
         dec = jax.make_jaxpr(self._decode_raw)(
             jax.ShapeDtypeStruct((b0,), i32), *pages,
-            jax.ShapeDtypeStruct((b0, m_blocks), i32),
-            jax.ShapeDtypeStruct((b0,), i32))
+            *self._decode_tail_spec(b0))
         out = {"prefill": (pre, donated), "decode": (dec, donated)}
         if self._chunk_raw is not None:
             out["extend"] = (jax.make_jaxpr(self._chunk_raw)(
@@ -699,6 +756,16 @@ class ServingEngine:
                 tuple(range(1, 1 + len(dpages))))
         return out
 
+    def _decode_tail_spec(self, width: int):
+        """The decode program's arguments behind the pools at bucket
+        ``width``: tables, contexts, the previous launch's result and the
+        row map."""
+        i32 = jnp.int32
+        return (jax.ShapeDtypeStruct((width, self.max_blocks_per_seq), i32),
+                jax.ShapeDtypeStruct((width,), i32),
+                jax.ShapeDtypeStruct((width + self._n_counts,), i32),
+                jax.ShapeDtypeStruct((width,), i32))
+
     def compile_decode(self):
         """AOT lower+compile the decode executable at its smallest
         bucket — the compiled-HLO verifier's serving input
@@ -713,8 +780,7 @@ class ServingEngine:
         i32 = jnp.int32
         compiled = self._decode_fn.lower(
             jax.ShapeDtypeStruct((b0,), i32), *pages,
-            jax.ShapeDtypeStruct((b0, self.max_blocks_per_seq), i32),
-            jax.ShapeDtypeStruct((b0,), i32)).compile()
+            *self._decode_tail_spec(b0)).compile()
         return compiled, len(pages)
 
     def compile_extend(self, verify: bool = False):
@@ -1394,18 +1460,37 @@ class ServingEngine:
         decodable)."""
         return [s for s in self.sched.iteration_batch() if s.out_tokens]
 
+    def _flight_row(self, seq: Sequence) -> int:
+        """The row of the launch in flight whose token ``seq`` will be given,
+        or -1: it holds none, or the row was cancelled or preempted since and
+        its token will be dropped."""
+        ahead = self._ahead
+        if ahead is None:
+            return -1
+        row = ahead.row_of.get(id(seq), -1)
+        if row >= 0 and not _still_rows(seq, ahead.epochs[row]):
+            return -1
+        return row
+
     def _ensure_decode_blocks(self) -> None:
-        """Every decodable sequence needs real blocks through position
-        ctx_len (+ gamma under speculation) before the next iteration;
-        preempt (lowest-priority, most-private-blocks, youngest) to make
-        room. Pool exhaustion with nothing left to preempt fails *that*
-        sequence (F003) — :class:`OutOfBlocksError` never crosses the
-        engine loop."""
+        """Every sequence of the next launch needs real blocks through the
+        position it writes there (+ gamma under speculation): ``ctx_len``,
+        or one further for a row whose token is in flight (a row whose last
+        token that is needs none); preempt (lowest-priority,
+        most-private-blocks, youngest) to make room. Pool exhaustion with
+        nothing left to preempt fails *that* sequence (F003) —
+        :class:`OutOfBlocksError` never crosses the engine loop."""
         lookahead = self.spec_gamma if self.spec_gamma else 0
         for seq in list(self.sched.running):
             if seq.status is not Status.RUNNING or not seq.out_tokens:
                 continue
-            needed = (seq.ctx_len + lookahead) // self.block_size + 1
+            reach = seq.ctx_len + lookahead
+            if len(seq.block_ids) > (reach + 1) // self.block_size:
+                continue        # enough even one position further
+            ahead = int(self._flight_row(seq) >= 0)
+            if ahead and seq.length_reached(1):
+                continue
+            needed = (reach + ahead) // self.block_size + 1
             while len(seq.block_ids) < needed:
                 got = self._alloc(1)
                 if got is not None:
@@ -1429,40 +1514,95 @@ class ServingEngine:
                     self._cancel(victim, Status.FAILED,
                                  f"KV spill failed: {e}", diagnose=True)
 
+    def _decode_rows(self) -> List[Tuple[Sequence, int]]:
+        """Who decodes next, each with the row of the launch in flight that
+        holds its token (-1: the host has it). Decided before that launch's
+        tokens are known: a row that reaches ``max_new_tokens`` with the
+        token in flight is left out; whether that token ends a request some
+        other way is not known, and such a row runs once too often
+        (:meth:`_decode_collect` drops the token)."""
+        rows = []
+        for seq in self._decodable():
+            src = self._flight_row(seq)
+            if src >= 0 and seq.length_reached(1):
+                continue
+            rows.append((seq, src))
+        return rows
+
     def _decode_iteration(self) -> None:
-        """Build and launch one decode iteration over the decodable rows and
-        return without waiting: the program runs while the host finishes
-        this step, the caller submits, and the next step admits and builds
-        its prefills. :meth:`_decode_collect` takes its tokens at the next
-        step. (Speculation keeps its own iteration, which waits.)"""
-        batch = self._decodable()
-        if not batch:
-            return
+        """Launch the next decode iteration, then take the tokens of the one
+        the last step launched: the new program is queued behind the old one
+        while that still runs, and the wake-up, the commit and the next
+        step's build happen beside the device. Where the next launch falls
+        into another bucket than the one in flight (or there is nothing to
+        launch) the tokens are taken first and every row is fed by the host:
+        one compiled program a bucket, whose ``prev`` has that bucket's
+        shape. (Speculation keeps its own iteration, which waits.)"""
         if self.spec_gamma:
-            self._spec_iteration(batch)
+            batch = self._decodable()
+            if batch:
+                self._spec_iteration(batch)
             return
-        rows = len(batch)
-        width = self.decode_buckets.fit(rows)
+        rows, prev = self._decode_rows(), self._ahead
+        if prev is not None and not (
+                rows and self.decode_buckets.fit(len(rows)) == prev.width):
+            self._ahead = None
+            self._decode_collect(prev)
+            rows, prev = self._decode_rows(), None
+        self._ahead = self._decode_launch(rows) if rows else None
+        if prev is not None:
+            self._decode_collect(prev)
+
+    def _no_prev_for(self, width: int):
+        """What a launch with no predecessor of its bucket reads as ``prev``
+        (every ``src`` is -1, so its values are not read): zeros of the
+        result's shape, placed as a program's results are (the pools are the
+        last program's), so that the jitted program sees one signature."""
+        blank = self._no_prev.get(width)
+        if blank is None:
+            like = self.cache.pools[0]
+            blank = jax.device_put(
+                np.zeros((width + self._n_counts,), np.int32),
+                like.sharding if like.committed else None)
+            self._no_prev[width] = blank
+        return blank
+
+    def _decode_launch(self, rows: List[Tuple[Sequence, int]]) -> _Launch:
+        """Build and launch one decode iteration over ``rows`` and return
+        without waiting. A row the launch in flight holds reads its token
+        from that launch's result on the device and stands one token further
+        than the host has committed."""
+        batch = [seq for seq, _ in rows]
+        n = len(batch)
+        width = self.decode_buckets.fit(n)
         m_blocks = self.max_blocks_per_seq
-        with self._acted_span("serve/decode", rows=rows,
-                              width=width) as sp:
+        with self._acted_span("serve/decode", rows=n, width=width) as sp:
             with trace.span("serve/decode/build"):
                 tokens = np.zeros((width,), np.int32)
+                src = np.full((width,), -1, np.int32)
                 tables = np.full((width, m_blocks), NULL_BLOCK, np.int32)
                 lens = np.zeros((width,), np.int32)
-                tokens[:rows] = [seq.out_tokens[-1] for seq in batch]
-                lens[:rows] = [seq.ctx_len for seq in batch]
+                src[:n] = [row for _, row in rows]
+                # (the program does not read the host's token where src >= 0)
+                tokens[:n] = [seq.out_tokens[-1] for seq in batch]
+                lens[:n] = [seq.ctx_len for seq in batch]
+                lens[:n] += src[:n] >= 0
                 for i, seq in enumerate(batch):
                     tables[i] = seq.table_row(m_blocks, NULL_BLOCK)
-                tokens_d, tables_d, lens_d = jax.device_put(
-                    (tokens, tables, lens))
-                args = (tokens_d, *self.cache.pools, tables_d, lens_d)
+                prev = self._ahead.out if self._ahead is not None \
+                    else self._no_prev_for(width)
+                tokens_d, tables_d, lens_d, src_d = jax.device_put(
+                    (tokens, tables, lens, src))
+                args = (tokens_d, *self.cache.pools, tables_d, lens_d, prev,
+                        src_d)
+                n_device = int((src >= 0).sum())
+                self._m.fed_device.inc(n_device)
+                self._m.fed_host.inc(n - n_device)
             with trace.span("serve/decode/checks"):
                 self._maybe_lint()
                 if self.prefix is not None:
-                    for seq in batch:
-                        self._assert_cow(
-                            self._write_span_ids(seq, seq.ctx_len, 1))
+                    for seq, pos in zip(batch, lens.tolist()):
+                        self._assert_cow(self._write_span_ids(seq, pos, 1))
                 self._sent_decode.observe_tree(
                     "serving.decode", self._undonated(args),
                     donate=self._donated, where="serving.decode")
@@ -1472,20 +1612,25 @@ class ServingEngine:
                 # on: whatever is launched before its tokens are taken (a
                 # prefill, a spill) runs behind it on them
                 self.cache.swap(*pools)
-        self._ahead = (batch, [seq.preemptions for seq in batch], width,
-                       lens, out, sp.t0_ns)
+        return _Launch(batch, [seq.preemptions for seq in batch], width,
+                       lens, out, sp.t0_ns,
+                       {id(seq): i for i, seq in enumerate(batch)})
 
-    def _decode_collect(self) -> None:
-        """Wait for the decode iteration launched a step ago and commit its
-        tokens. A row that was cancelled or preempted while the program ran
-        is skipped: its token is computed again if it comes back."""
-        batch, epochs, width, lens, out, t0_ns = self._ahead
-        self._ahead = None
+    def _decode_collect(self, launch: _Launch) -> None:
+        """Wait for a launched decode iteration and commit its tokens. A row
+        that was cancelled or preempted while the program ran, or that the
+        previous launch's token finished (an end-of-sequence token: the one
+        ending the host cannot see ahead), is skipped: its token is dropped,
+        and computed again if the row comes back."""
+        batch, epochs, width, lens, out, t0_ns, _ = launch
         rows = len(batch)
         with trace.span("serve/decode", rows=rows, width=width):
             with self._acted_span("serve/decode/wait") as wait:
                 # host sync per iteration
                 out = self._take_counts(np.asarray(out), width, rows, width)
+            if self._ahead is not None and wait.end_ns > self._ahead.t0_ns:
+                # the launch queued behind this one began on the device now
+                self._ahead = self._ahead._replace(t0_ns=wait.end_ns)
             with trace.span("serve/decode/commit"):
                 # Drill seam: a kill here lands AFTER the iteration's
                 # compute but BEFORE any token is committed/acknowledged —
@@ -1494,8 +1639,9 @@ class ServingEngine:
                 _fault_fire("serve.mid_decode")
                 live = [(seq, tok) for seq, epoch, tok
                         in zip(batch, epochs, out[:rows].tolist())
-                        if seq.status is Status.RUNNING
-                        and seq.preemptions == epoch]
+                        if _still_rows(seq, epoch)]
+                if len(live) < rows:
+                    self._m.fed_dropped.inc(rows - len(live))
                 _account(t0_ns, wait.end_ns, "decode",
                          [seq for seq, _ in live])
                 self._decode_done(t0_ns, wait)
@@ -1727,14 +1873,14 @@ class ServingEngine:
     def step(self) -> List[Sequence]:
         """One scheduler iteration: expire deadlines, consult the shed
         policy, admit whatever fits (prefill / restore at token
-        granularity), run one prefill chunk under the chunked budget,
-        take the tokens of the decode iteration the last step launched,
-        top up decode blocks (preempting under pressure), launch the next
-        decode iteration. The decode program runs while this step returns
-        and the next one admits, so a row's token is seen a step after the
-        launch that computed it, and a drained engine needs one step more
-        than it has tokens to give. Returns every sequence that reached a
-        terminal state this iteration — FINISHED, and also EXPIRED / SHED /
+        granularity), run one prefill chunk under the chunked budget, top up
+        decode blocks (preempting under pressure), launch the next decode
+        iteration, then take the tokens of the one the last step launched.
+        The decode program runs while this step returns and the next one
+        admits, builds and launches, so a row's token is seen a step after
+        the launch that computed it, and a drained engine needs one step
+        more than it has tokens to give. Returns every sequence that reached
+        a terminal state this iteration — FINISHED, and also EXPIRED / SHED /
         FAILED retirements."""
         n0 = len(self.sched.finished)
         with trace.span("serve/step", iteration=self.n_iterations) as root:
@@ -1753,8 +1899,6 @@ class ServingEngine:
             if self.chunk_tokens:
                 with trace.span("serve/chunk"):
                     self._chunk_iteration()
-            if self._ahead is not None:
-                self._decode_collect()
             with trace.span("serve/ensure_blocks"):
                 self._ensure_decode_blocks()
             self._decode_iteration()

@@ -167,10 +167,15 @@ class Sequence:
         self._tab_n = n
         return self._tab
 
+    def length_reached(self, ahead: int = 0) -> bool:
+        """The length rule of :meth:`is_finished_by`, ``ahead`` tokens from
+        now: known before the tokens are (the engine leaves a row whose last
+        token is in flight out of the next launch)."""
+        return self.n_generated + ahead >= self.request.max_new_tokens
+
     def is_finished_by(self, token: int) -> bool:
         eos = self.request.eos_token_id
-        return ((eos is not None and token == eos) or
-                self.n_generated >= self.request.max_new_tokens)
+        return (eos is not None and token == eos) or self.length_reached()
 
     def full_output(self) -> np.ndarray:
         return np.concatenate([self.request.prompt_ids,

@@ -232,7 +232,9 @@ def test_serving_decode_program_compiles(one_chip):
                             sharding=one_chip)
     compiled = eng._decode_fn.jitted.lower(
         params, i32((8,)), pages, pages,
-        i32((8, eng.max_blocks_per_seq)), i32((8,))).compile()
+        i32((8, eng.max_blocks_per_seq)), i32((8,)),
+        # the last launch's result and the rows that read their token there
+        i32((8,)), i32((8,))).compile()
     # the 50304x2048 bf16 embedding is an argument of the program, not a
     # literal inside it
     assert len(compiled.as_text()) < 5_000_000
@@ -309,7 +311,7 @@ def test_serving_decode_program_with_paged_kernel_compiles(one_chip,
                             sharding=one_chip)
     lowered = eng._decode_fn.jitted.lower(
         params, i32((c["b"],)), pages, pages, i32((c["b"], c["m"])),
-        i32((c["b"],)))
+        i32((c["b"],)), i32((c["b"],)), i32((c["b"],)))
     mlir = lowered.as_text()
     assert mlir.count("func.func private @_paged_call") == 1
     assert mlir.count("call @_paged_call") == 2
@@ -399,7 +401,10 @@ def test_latent_serving_decode_program_compiles(one_chip, monkeypatch):
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
                             sharding=one_chip)
     lowered = eng._decode_fn.jitted.lower(
-        params, i32((c["b"],)), pool, i32((c["b"], c["m"])), i32((c["b"],)))
+        params, i32((c["b"],)), pool, i32((c["b"], c["m"])), i32((c["b"],)),
+        # the last launch's result (tokens, then the experts' loads) and the
+        # rows that read their token there
+        i32((c["b"] + eng._n_counts,)), i32((c["b"],)))
     mlir = lowered.as_text()
     assert mlir.count("func.func private @_latent_paged_call") == 1
     assert mlir.count("call @_latent_paged_call") == 2

@@ -188,7 +188,9 @@ def test_serving_programs_take_weights_as_arguments():
     n_weights = len(jax.tree_util.tree_leaves(eng._decode_fn.params))
     assert n_weights > 0 and donated == 2
     n_args = len(jax.tree_util.tree_leaves(compiled.args_info))
-    assert n_args == n_weights + 5
+    # tokens, two pools, tables, contexts, the last launch's result, the
+    # row map
+    assert n_args == n_weights + 7
 
 
 def test_flash_kernel_runs_per_shard_on_a_hybrid_mesh(monkeypatch):

@@ -308,6 +308,244 @@ class TestDecodeInFlight:
         assert engine.cache.allocator.n_used == 0
 
 
+class _Launches:
+    """Wraps an engine's decode program and keeps what each launch was
+    handed behind the pools: contexts, ``prev`` and the row map ``src``."""
+
+    def __init__(self, engine):
+        self.src, self.lens, self.prev = [], [], []
+        inner = engine._decode_fn
+
+        def recording(*args):
+            self.lens.append(np.asarray(args[-3]))
+            self.prev.append(args[-2])
+            self.src.append(np.asarray(args[-1]))
+            return inner(*args)
+        engine._decode_fn = recording
+
+
+def _fed(kind):
+    return metrics.counter("serving.decode_rows").labels(fed=kind).get()
+
+
+def _decode_spans(recs):
+    """The ring's ``serve/decode`` parts in order: (step iteration, name,
+    t0_ns, end_ns)."""
+    by_id = {r["id"]: r for r in recs}
+    out = []
+    for r in recs:
+        if r["name"].startswith(("serve/decode/", "serve/prefill/")):
+            top = r
+            while top["parent"] is not None:
+                top = by_id[top["parent"]]
+            out.append((top["attrs"]["iteration"], r["name"], r["t0_ns"],
+                        r["t0_ns"] + r["dur_ns"]))
+    return sorted(out, key=lambda x: x[2])
+
+
+class TestTokensFedOnTheDevice:
+    """``step()`` launches decode k before it takes decode k-1's tokens: a
+    row of k-1 reads its token from that launch's result on the device."""
+
+    def _engine(self, model, **kw):
+        kw.setdefault("decode_buckets", [4])
+        return ServingEngine(model, block_size=4, num_blocks=48, max_batch=4,
+                             max_seq_len=32, **kw)
+
+    def test_ragged_lengths_and_joiners_match_generate(self):
+        """Rows finish at different lengths and requests join mid-stream, so
+        launches mix device-fed and host-fed rows and a row's place moves
+        (``src`` is not the identity); every output equals ``generate``."""
+        model = micro_model(max_position_embeddings=32)
+        engine = self._engine(model)
+        seen = _Launches(engine)
+        metrics.reset_all()
+        rng = np.random.default_rng(11)
+        requests = [Request(rid=f"m{i}",
+                            prompt_ids=rng.integers(0, 128,
+                                                    int(rng.integers(3, 12))),
+                            max_new_tokens=int(n))
+                    for i, n in enumerate([3, 9, 5, 12, 2, 7, 4, 10, 6])]
+        seqs = [engine.submit(r) for r in requests[:4]]
+        later = list(requests[4:])
+        while engine.sched.n_pending or later:
+            engine.step()
+            if later and engine.n_iterations % 2 == 0:
+                seqs.append(engine.submit(later.pop(0)))
+        for r, seq in zip(requests, seqs):
+            np.testing.assert_array_equal(seq.output, ref_generate(model, r))
+        assert engine.cache.allocator.n_used == 0
+        # some launch mixes the two feeds, and some row changed its place
+        assert any((s >= 0).any() and (s[:int((l > 0).sum())] < 0).any()
+                   for s, l in zip(seen.src, seen.lens))
+        assert any(((s >= 0) & (s != np.arange(4))).any() for s in seen.src)
+        n_rows = sum(len(q.out_tokens) - 1 for q in seqs)
+        assert _fed("device") + _fed("host") == n_rows
+        assert _fed("device") > _fed("host") > 0 and _fed("dropped") == 0
+        # a device-fed row stands one token further than the host had
+        # committed, and is handed the launch in flight as it stands
+        for lens, src, prev in zip(seen.lens[1:], seen.src[1:],
+                                   seen.prev[1:]):
+            assert prev.shape == (4,) and (lens[src >= 0] > 0).all()
+        rep = engine.compile_report()
+        assert rep["decode_signatures"] == 1 and not rep["o001_fired"], rep
+
+    @pytest.mark.parametrize("prefix_cache", [False, True])
+    def test_end_of_sequence_token_costs_one_dropped_row(self, prefix_cache):
+        """A token that ends a request cannot be seen ahead: the row runs
+        once too often, that token is dropped, the output ends at the
+        end-of-sequence token and the other rows stay exact. With the prefix
+        cache on, the write of the row that ran on passes D005's assertion
+        (it lands in a block the sequence held at the launch)."""
+        model = micro_model(max_position_embeddings=32)
+        requests = ragged_requests(3, lo=7, hi=9, max_new=10, seed=7)
+        ref = ref_generate(model, requests[1])
+        n = requests[1].prompt_ids.size
+        new = ref[n:].tolist()
+        # the first token that is new at its place, past the prefill's and
+        # short of the length: it fires in a decode launch
+        at = next(j for j in range(1, len(new) - 2) if new[j] not in new[:j])
+        requests[1].eos_token_id = int(new[at])
+        engine = self._engine(model, prefix_cache=prefix_cache)
+        metrics.reset_all()
+        seqs = [engine.submit(r) for r in requests]
+        while engine.sched.n_pending:
+            engine.step()
+        assert seqs[1].status is Status.FINISHED
+        np.testing.assert_array_equal(seqs[1].output, ref[:n + at + 1])
+        for i in (0, 2):
+            np.testing.assert_array_equal(seqs[i].output,
+                                          ref_generate(model, requests[i]))
+        assert _fed("dropped") == 1
+        n_rows = sum(len(q.out_tokens) - 1 for q in seqs)
+        assert _fed("device") + _fed("host") == n_rows + 1
+        if prefix_cache:
+            assert_allocator_pristine_shared(engine)
+        else:
+            assert engine.cache.allocator.n_used == 0
+
+    @pytest.mark.parametrize("committed", [False, True])
+    def test_nothing_compiles_after_a_warm_up_of_two_token_requests(
+            self, committed):
+        """The benchmark warms an engine with requests of two tokens: one
+        prefill and one decode whose rows the host feeds. The launches after
+        it, fed on the device from a real result, run the same compiled
+        program, whether the weights (and with them every result) are
+        committed to their device or not."""
+        import jax
+        from jax._src import monitoring
+        model = micro_model(max_position_embeddings=32)
+        if committed:
+            from paddle_tpu.framework.functional import get_params, set_params
+            set_params(model, {k: jax.device_put(v, jax.devices()[0])
+                               for k, v in get_params(model).items()})
+        engine = ServingEngine(model, block_size=4, num_blocks=48,
+                               max_batch=4, max_seq_len=32,
+                               prefill_buckets=[16], decode_buckets=[4])
+        rng = np.random.default_rng(0)
+        # as ``warm_engine`` does; both prompts in the one prefill bucket,
+        # whose program sees a fresh pool once and a program's result after
+        for i, length in enumerate([2, 9]):
+            engine.submit(Request(rid=f"warm{i}",
+                                  prompt_ids=rng.integers(0, 128, length),
+                                  max_new_tokens=2))
+        while engine.sched.n_pending:
+            engine.step()
+        compiled = []
+
+        def on(event, duration, **_):
+            if "backend_compile" in event:
+                compiled.append(event)
+        monitoring.register_event_duration_secs_listener(on)
+        try:
+            requests = ragged_requests(9, lo=3, hi=14, max_new=9, seed=8)
+            for r, n in zip(requests, [9, 4, 7, 2, 9, 5, 3, 8, 6]):
+                r.max_new_tokens = n
+            results = engine.serve(requests)
+        finally:
+            monitoring.unregister_event_duration_listener(on)
+        assert compiled == []
+        for r in requests:
+            np.testing.assert_array_equal(results[r.rid].output,
+                                          ref_generate(model, r))
+        rep = engine.compile_report()
+        assert rep["within_budget"] and not rep["o001_fired"], rep
+
+    def test_launch_goes_out_before_the_wait_unless_the_step_admits(self):
+        """The ring: in a step that admits nothing, ``serve/decode/launch``
+        of iteration k lies before the end of ``serve/decode/wait`` of k-1;
+        in a step that admits, it lies after the prefill's commit (and still
+        before that wait)."""
+        from paddle_tpu.observability import trace
+        model = micro_model(max_position_embeddings=32)
+        engine = self._engine(model)
+        requests = ragged_requests(3, lo=7, hi=9, max_new=8, seed=3)
+        trace.clear()
+        engine.submit(requests[0])
+        engine.submit(requests[1])
+        for _ in range(3):
+            engine.step()
+        engine.submit(requests[2])
+        joined = engine.n_iterations        # the step that admits it
+        while engine.sched.n_pending:
+            engine.step()
+        spans = _decode_spans(trace.spans())
+        by_step = {}
+        for it, name, t0, end in spans:
+            by_step.setdefault(it, []).append((name, t0, end))
+        quiet = admitting = 0
+        for it, parts in by_step.items():
+            names = [p[0] for p in parts]
+            if "serve/decode/launch" not in names or \
+                    "serve/decode/wait" not in names:
+                continue
+            launch = parts[names.index("serve/decode/launch")]
+            wait = parts[names.index("serve/decode/wait")]
+            assert launch[2] <= wait[2]     # launched before the wait ends
+            if "serve/prefill/commit" in names:
+                commit = parts[names.index("serve/prefill/commit")]
+                assert commit[2] <= launch[1]
+                admitting += 1
+            else:
+                assert names.index("serve/decode/launch") < \
+                    names.index("serve/decode/wait")
+                quiet += 1
+        assert quiet > 0 and admitting > 0
+        assert "serve/prefill/commit" in [p[0] for p in by_step[joined]]
+
+    def test_a_launch_after_a_bucket_change_is_fed_by_the_host(self):
+        """Two decode buckets: where the next launch falls into another
+        bucket than the one in flight, the tokens are taken first and every
+        row of that launch is fed by the host; outputs stay exact and each
+        bucket compiles once."""
+        model = micro_model(max_position_embeddings=32)
+        engine = self._engine(model, decode_buckets=[2, 4])
+        seen = _Launches(engine)
+        metrics.reset_all()
+        requests = ragged_requests(4, lo=5, hi=9, max_new=10, seed=9)
+        for r, n in zip(requests, [10, 3, 6, 8]):
+            r.max_new_tokens = n
+        seqs = [engine.submit(r) for r in requests]
+        while engine.sched.n_pending:
+            engine.step()
+        for r, seq in zip(requests, seqs):
+            np.testing.assert_array_equal(seq.output, ref_generate(model, r))
+        widths = [len(s) for s in seen.src]
+        assert set(widths) == {2, 4}
+        changes = [i for i in range(1, len(widths))
+                   if widths[i] != widths[i - 1]]
+        assert changes
+        for i in changes:
+            assert (seen.src[i] == -1).all()
+        # and between changes the rows read on the device
+        assert any((s >= 0).any() for s in seen.src)
+        for src, prev in zip(seen.src, seen.prev):
+            assert prev.shape == src.shape
+        assert _fed("dropped") == 0
+        rep = engine.compile_report()
+        assert rep["decode_signatures"] == 2 and not rep["o001_fired"], rep
+
+
 class TestGQA:
     def test_grouped_kv_heads_match_generate(self):
         model = micro_model(num_heads=4, num_kv_heads=2)

@@ -21,8 +21,8 @@ from paddle_tpu.text.models.gpt import GPTForCausalLM, gpt_tiny
 
 STEP_CHILDREN = {"serve/expire_shed", "serve/admit", "serve/chunk",
                  "serve/ensure_blocks", "serve/decode", "serve/gauges"}
-# a step has up to two ``serve/decode`` spans: it takes the tokens of the
-# iteration the last step launched, then builds and launches the next
+# a step has up to two ``serve/decode`` spans: it builds and launches the
+# next iteration, then takes the tokens of the one the last step launched
 DECODE_TAKE = ["serve/decode/wait", "serve/decode/commit"]
 DECODE_LAUNCH = ["serve/decode/build", "serve/decode/checks",
                  "serve/decode/launch"]
@@ -225,9 +225,12 @@ def test_engine_step_span_tree(model):
                 assert k["attrs"]["width"] == 4
                 assert 1 <= k["attrs"]["rows"] <= 4
         decodes = [k for k in mine if k["name"] == "serve/decode"]
-        if len(decodes) == 2:        # taken before the blocks are topped up
-            assert names.index("serve/ensure_blocks") == \
-                names.index("serve/decode") + 1
+        if decodes:                  # the blocks are topped up first
+            assert names.index("serve/ensure_blocks") + 1 == \
+                names.index("serve/decode")
+        if len(decodes) == 2:        # one bucket: launched, then taken
+            assert [d["name"] for d in kids[decodes[0]["id"]]] == \
+                DECODE_LAUNCH
     assert n_take == n_launch > 0    # every launch is taken, a step later
     prefills = [r for r in recs if r["name"] == "serve/prefill"]
     assert sorted(p["attrs"]["rid"] for p in prefills) == \
@@ -265,12 +268,16 @@ def test_kv_and_prefill_counters_count_what_was_fed(model):
                and kids[r["id"]][0]["name"] == "serve/decode/build"]
     assert c.labels(kind="gathered").get() == \
         len(decodes) * 4 * eng.max_blocks_per_seq * eng.block_size
-    # rows a dispatch and dispatches a step are the spans' to give: no
-    # counter family repeats them
+    # rows a dispatch and dispatches a step are the spans' to give; the
+    # counter says where each row's token came from
     assert sum(d["attrs"]["rows"] for d in decodes) == rows
     assert eng.n_iterations == len(fed)
-    snap = metrics.snapshot()
-    assert "serving.decode_rows" not in snap and "serving.steps" not in snap
+    by_feed = metrics.counter("serving.decode_rows")
+    assert by_feed.labels(fed="device").get() \
+        + by_feed.labels(fed="host").get() == rows
+    assert by_feed.labels(fed="device").get() > 0
+    assert by_feed.labels(fed="dropped").get() == 0
+    assert "serving.steps" not in metrics.snapshot()
     pf = metrics.counter("serving.prefill_tokens")
     assert pf.labels(kind="real").get() == sum(r.prompt_ids.size for r in reqs)
     assert pf.labels(kind="bucket").get() == 5 * 16
@@ -310,10 +317,14 @@ def test_span_exits_feed_the_histograms_and_the_policy_window(model):
     pre = {r["attrs"]["rid"]: r for r in recs if r["name"] == "serve/prefill"}
     for seq in eng.sched.finished:
         assert 0 < seq.phase_s["prefill"] * 1e9 < pre[seq.rid]["dur_ns"]
-    # an iteration's time: the start of its build to its tokens' arrival
-    wait = [r for r in recs if r["name"] == "serve/decode/wait"][-1]
+    # an iteration's time: the start of its build, or the arrival of the
+    # tokens of the iteration it was queued behind if that came later, to
+    # its own tokens' arrival
+    before, wait = [r for r in recs if r["name"] == "serve/decode/wait"][-2:]
+    began = max(launched[-1]["t0_ns"], before["t0_ns"] + before["dur_ns"])
+    assert before["t0_ns"] > launched[-1]["t0_ns"]
     assert eng._decode_ms[-1] == pytest.approx(
-        (wait["t0_ns"] + wait["dur_ns"] - launched[-1]["t0_ns"]) / 1e6)
+        (wait["t0_ns"] + wait["dur_ns"] - began) / 1e6)
     # the p99 gauge is sorted for a reader only: no policy, no exporter
     assert metrics.gauge("serving.decode_p99_ms").get() == 0
 
