@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["Route", "dropless_route", "group_limited_topk",
-           "dropless_glu_experts", "expert_form", "EXPERT_FORMS",
+           "renormalised_topk", "record_held_pairs", "dropless_glu_experts", "expert_form", "EXPERT_FORMS",
            "grouped_glu_experts", "dense_glu_experts", "DENSE_MAX_TOKENS"]
 
 #: The most tokens a call may have and still take the dense form: the row
@@ -48,6 +48,19 @@ __all__ = ["Route", "dropless_route", "group_limited_topk",
 #: They cross between 512 and 768 (near 700 by the lines through those
 #: points). One gate product at T 256: 0.53 ms grouped, 0.25 dense, beside
 #: 0.19 ms of weight read and 0.20 of products at the chip's peaks.
+#:
+#: The same at E 16, d 2048, f 768, top-8 of 128 renormalised (one expected
+#: pair a token; 32 pairs an expert at T 512, which is a whole pass of the
+#: block-diffusion serving cell; chip run of PR 34):
+#:
+#:     T         128    256    512    768   1024
+#:     grouped  0.62   0.69   0.73   0.84   0.89
+#:     dense    0.23   0.31   0.47   0.74   1.00
+#:
+#: They cross between 768 and 1024 (near 850). Both forms do 16 times the
+#: needed products there; at T 512 the dense form takes 2.5 times what
+#: reading the 151 MB of held experts takes. The constant stays where the
+#: grouped form's tile puts it: one choice for both cells.
 DENSE_MAX_TOKENS = 512
 
 
@@ -121,6 +134,46 @@ def group_limited_topk(scores, top_k: int, n_group: int = 1,
         scores = jnp.where(jnp.repeat(keep, per, axis=1), scores, 0.0)
     val, idx = jax.lax.top_k(scores, top_k)
     return idx, val
+
+
+def renormalised_topk(probs, top_k: int, renormalise: bool = True):
+    """Plain top-k of ``probs [T, E]`` (a float32 softmax over every expert):
+    ``(idx, weight)`` each ``[T, k]``, the weights divided by their sum where
+    ``renormalise`` (``norm_topk_prob`` of the Qwen3-MoE family), so that a
+    token's experts weigh 1 together whatever the router left to the
+    others."""
+    val, idx = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        val = val / jnp.sum(val, axis=-1, keepdims=True)
+    return idx, val
+
+
+def record_held_pairs(load, n_tokens: int, n_slots: int, *, top_k: int,
+                      n_layers: int, first: int) -> None:
+    """The serving counters behind the counts a routed-expert model's
+    programs return (``model.serve_record_counts``): ``n_tokens`` real tokens
+    went through ``n_layers`` expert layers, ``load[e]`` of their pairs fell
+    to held expert ``first + e``; the program was traced for ``n_slots``
+    tokens, which is what chose its expert layers' form."""
+    from .....observability import metrics
+    metrics.counter(
+        "serving.moe_expert_calls",
+        "expert layers the launched prefill and decode programs ran, "
+        "by the form their token count selects (form=dense: every held "
+        "expert over the whole batch; form=grouped: sorted pairs)"
+    ).labels(form=expert_form(n_slots)).inc(n_layers)
+    pairs = metrics.counter(
+        "serving.moe_assignments",
+        "(token, expert) pairs the router made (kind=routed: tokens x "
+        "top-k x expert layers) and those that fell to experts held "
+        "here (kind=held)")
+    pairs.labels(kind="routed").inc(int(n_tokens) * top_k * n_layers)
+    pairs.labels(kind="held").inc(int(load.sum()))
+    by_expert = metrics.counter(
+        "serving.moe_expert_load",
+        "(token, expert) pairs that fell to each held expert")
+    for i, n in enumerate(load):
+        by_expert.labels(expert=first + i).inc(int(n))
 
 
 def grouped_glu_experts(x, idx, weight, w_gate, w_up, w_down, *,

@@ -171,14 +171,20 @@ def dropout_keep_dense(bh, sq, sk, seed, rate: float):
     return keep.astype(jnp.float32) * (1.0 / (1.0 - rate))
 
 
-def _causal_mask(s, qi, kj, block_q, block_k, offset):
+def _causal_mask(s, qi, kj, block_q, block_k, offset, causal_block=1):
     """Bottom-right-aligned causal mask (query i attends keys <= i + offset,
-    offset = sk - sq)."""
+    offset = sk - sq). With ``causal_block = B`` (a power of two that divides
+    the tiles) the mask is block-causal: keys ``<= (i + offset) | (B - 1)``,
+    the query's own block of ``B`` positions whole. A tile's last query ends
+    a block, so which tiles lie in the band does not change: only the tiles
+    on the diagonal differ from the causal ones."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, dimension=0)
+        jnp.int32, s.shape, dimension=0) + offset
+    if causal_block > 1:
+        q_pos = q_pos | (causal_block - 1)
     k_pos = kj * block_k + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, dimension=1)
-    return jnp.where(q_pos + offset >= k_pos, s, NEG_INF)
+    return jnp.where(q_pos >= k_pos, s, NEG_INF)
 
 
 def _dot(a, b, dims):
@@ -227,7 +233,7 @@ def _paired_qi_kj(p, t, nq):
 def _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, seed_ref,
                 bias_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale, causal, segmented, block_q, block_k, seq_q, seq_k,
-                dropout=0.0, biased=False, paired_nq=None):
+                dropout=0.0, biased=False, paired_nq=None, causal_block=1):
     bh_id = pl.program_id(0)  # hoisted: program_id inside pl.when bodies
     # has no interpret-mode lowering
     if paired_nq is None:
@@ -265,7 +271,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, seed_ref,
         vb = v_ref[0]
         s = _dot(q, kb, ((1,), (1,))) * scale  # [bq, bk] fp32
         if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, offset)
+            s = _causal_mask(s, qi, kj, block_q, block_k, offset,
+                             causal_block)
         if segmented:
             s = _seg_mask(s, segq_ref, segk_ref)
         if biased:
@@ -331,7 +338,8 @@ def _bias_or_dummy(bias, b, sk):
 
 
 def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
-         seg_q=None, seg_k=None, dropout=0.0, seed=None, bias=None):
+         seg_q=None, seg_k=None, dropout=0.0, seed=None, bias=None,
+         causal_block=1):
     """q: [BH, S, D]; k: [B*HK, S, D]; v: [B*HK, S, DV] (+ optional
     [BH, 1, S] int32 segment ids) -> (o [BH, Sq, DV], lse [BH, 1, Sq]
     fp32). ``DV`` is ``D`` wherever a backward follows; the forward alone
@@ -357,7 +365,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, num_heads,
                              segmented=segmented, block_q=block_q,
                              block_k=block_k, seq_q=sq, seq_k=sk,
                              dropout=dropout, biased=biased,
-                             paired_nq=nq if paired else None)
+                             paired_nq=nq if paired else None,
+                             causal_block=causal_block)
     kv_index = _kv_index(h, hk)
     if paired:
         grid = (bh, nq // 2, nq + 1)
@@ -979,8 +988,11 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
                            block_k: Optional[int] = None,
                            segment_ids=None, segment_ids_k=None,
                            dropout: float = 0.0, dropout_seed=None,
-                           key_bias=None):
-    """[B, S, H, D] flash attention via Pallas. Differentiable.
+                           key_bias=None, causal_block: int = 1):
+    """[B, S, H, D] flash attention via Pallas. Differentiable (but for
+    ``causal_block > 1``, the block-causal mask ``key <= query |
+    (causal_block - 1)`` of generation by diffusion over blocks, which runs
+    the forward alone).
 
     Block sizes default to the autotuned table in ``_pick_blocks``; pass
     explicit ``block_q``/``block_k`` to override. Grouped-query attention
@@ -993,7 +1005,7 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
     (self-attention packing). Under a multi-device hybrid mesh the call
     runs per shard (``_flash_on_mesh``)."""
     mesh, manual, free = _free_mesh_axes()
-    if free:
+    if free and causal_block == 1:
         return _flash_on_mesh(mesh, manual, free, query, key, value, causal,
                               scale, block_q, block_k, segment_ids,
                               segment_ids_k, dropout, dropout_seed, key_bias)
@@ -1006,7 +1018,7 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
     # lane DMAs. Skipped when the caller pins blocks (kernel sweeps/tests
     # target a specific grid of the unpacked kernel).
     if (block_q is None and block_k is None and d == 64 and dv == d
-            and hk == h
+            and hk == h and causal_block == 1
             and sq % 128 == 0 and sk % 128 == 0
             and int(_flags.flag("flash_head_pack"))):
         from .flash_attention_packed import (flash_attention_packed,
@@ -1071,15 +1083,22 @@ def flash_attention_pallas(query, key, value, causal: bool = False,
     bias = None
     if key_bias is not None:
         bias = jnp.asarray(key_bias, jnp.float32).reshape(b, 1, sk)
-    if dv != d:
-        # values of another head size than the keys: the forward alone (the
-        # backward kernels take one size), so no gradient and no dropout
+    if dv != d or causal_block > 1:
+        # values of another head size than the keys, or a block-causal mask:
+        # the forward alone (the backward kernels take one size and the
+        # causal mask), so no gradient and no dropout
         if dropout > 0.0:
             raise ValueError(
                 f"flash_attention_pallas: values of head size {dv} beside "
-                f"keys of {d} run forward only, without dropout")
+                f"keys of {d}, or causal_block {causal_block}, run forward "
+                "only, without dropout")
+        if causal_block > 1 and (not causal or min(block_q, sq) % causal_block
+                                 or (sk - sq) % causal_block):
+            raise ValueError(
+                f"causal_block {causal_block} needs causal=True, tiles of "
+                f"whole blocks (block_q {block_q}) and sk - sq a multiple")
         o, _ = _fwd(q, k, v, float(scale), bool(causal), block_q, block_k, h,
-                    seg_q, seg_k, 0.0, seed, bias)
+                    seg_q, seg_k, 0.0, seed, bias, causal_block)
     else:
         o = _flash_bhsd(q, k, v, seg_q, seg_k, seed, bias, float(scale),
                         bool(causal), block_q, block_k, h, float(dropout))

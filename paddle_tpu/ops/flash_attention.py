@@ -22,11 +22,13 @@ import jax
 import jax.numpy as jnp
 
 from ..core import flags
+from .paged_layout import gather_pages, heads_first
 
 __all__ = ["flash_attention", "flash_attn_unpadded", "reference_attention",
            "single_query_attention", "paged_single_query_attention",
            "takes_paged_kernel", "multi_query_attention",
-           "latent_attention", "latent_paged_attention"]
+           "block_paged_attention", "latent_attention",
+           "latent_paged_attention"]
 
 
 def _masked_softmax(scores, dtype):
@@ -39,12 +41,24 @@ def _masked_softmax(scores, dtype):
                             1e-30)).astype(dtype)
 
 
+def _causal_mask(sq: int, sk: int, causal_block: int = 1):
+    """``[sq, sk]`` bool, bottom-right aligned: query ``i`` sees key ``j``
+    iff ``j <= i + sk - sq``, or with ``causal_block = B > 1`` (block-causal,
+    blocks counted from position 0) iff ``j // B <= (i + sk - sq) // B``."""
+    qi = jnp.arange(sq)[:, None] + (sk - sq)
+    if causal_block > 1:
+        qi = qi // causal_block * causal_block + causal_block - 1
+    return jnp.arange(sk)[None, :] <= qi
+
+
 def reference_attention(q, k, v, causal: bool = False,
                         scale: Optional[float] = None,
-                        bias: Optional[jax.Array] = None):
+                        bias: Optional[jax.Array] = None,
+                        causal_block: int = 1):
     """jnp reference, [B,S,H,D] layout, fp32 softmax. Handles grouped-query
     kv (fewer kv heads) and rows with no valid keys (output 0, matching the
-    Pallas kernel)."""
+    Pallas kernel). ``causal_block`` makes the causal mask block-causal (a
+    query sees every key of its own block of that many positions)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if k.shape[2] != h:
@@ -57,7 +71,7 @@ def reference_attention(q, k, v, causal: bool = False,
     if bias is not None:
         scores = scores + bias
     if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), sk - sq)
+        mask = _causal_mask(sq, sk, causal_block)
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     # Masked-row-safe softmax: fully-masked rows (all -inf) produce 0, not
     # NaN — matching the Pallas kernels' handling.
@@ -110,14 +124,18 @@ def _platform_of(x) -> str:
     return jax.default_backend()
 
 
-def takes_paged_kernel(q_dtype, k_pool, latent_value_dim=None) -> bool:
+def takes_paged_kernel(q_dtype, k_pool, latent_value_dim=None,
+                       block_size: Optional[int] = None) -> bool:
     """Does decode attention over ``k_pool`` with queries of ``q_dtype`` take
-    its paged Pallas kernel? ``k_pool`` is a K (or V) pool ``[..., NB, bs,
-    KH, D]`` or, with ``latent_value_dim`` (the part of a row that is its
-    value), a latent pool ``[..., NB, bs, W]``. As ``_use_pallas``: on a TPU
-    with the flag on and a shape the kernel takes; an unsupported shape ON a
-    TPU is announced once (P005). The serving engine asks too, to count what
-    its decode program reads."""
+    its paged Pallas kernel? ``k_pool`` is one of the three pool shapes
+    (``ops/paged_layout.py``): a K (or V) pool with its pages tokens first,
+    ``[..., NB, bs, KH, D]`` (the single-query kernel); the same heads first,
+    ``[..., NB, KH, bs, D]``, which is told apart by ``block_size`` (the
+    block kernel, several queries a row); or, with ``latent_value_dim`` (the
+    part of a row that is its value), a latent pool ``[..., NB, bs, W]``. As
+    ``_use_pallas``: on a TPU with the flag on and a shape the kernel takes;
+    an unsupported shape ON a TPU is announced once (P005). The serving
+    engine asks too, to count what its decode program reads."""
     if not flags.flag("use_pallas_kernels") or _platform_of(k_pool) != "tpu":
         return False
     from ..analysis.pallas_check import report_fallback
@@ -132,6 +150,15 @@ def takes_paged_kernel(q_dtype, k_pool, latent_value_dim=None) -> bool:
             f"{shape} value_dim {latent_value_dim}",
             "needs bf16 queries and pool, the row and value_dim multiples "
             "of 128 and block_size a multiple of 16")
+        return False
+    if block_size is not None and heads_first(k_pool, block_size):
+        from ._pallas.block_paged_attention import supported_shapes
+        if supported_shapes(q_dtype, k_pool):
+            return True
+        report_fallback(
+            "block_paged_attention", shape,
+            "needs bf16 queries and pool, head_dim 128 and block_size a "
+            "multiple of 16")
         return False
     from ._pallas.paged_attention import supported_shapes
     if supported_shapes(q_dtype, k_pool):
@@ -160,10 +187,11 @@ def paged_single_query_attention(q, k_pool, v_pool, tables, lengths, *,
     up to its own length and no gathered copy exists. Everywhere else it is
     the dense path the kernel is checked against: gather every table's
     pages, then :func:`single_query_attention` behind a length mask."""
-    if k_pool.shape[-3] != block_size:
-        raise ValueError(f"pool pages hold {k_pool.shape[-3]} tokens, "
-                         f"block_size says {block_size}")
-    if takes_paged_kernel(q.dtype, k_pool):
+    if block_size not in k_pool.shape[-3:-1]:
+        raise ValueError(f"pool pages {tuple(k_pool.shape[-3:])} hold no "
+                         f"axis of block_size {block_size}")
+    if not heads_first(k_pool, block_size) \
+            and takes_paged_kernel(q.dtype, k_pool):
         from ._pallas.paged_attention import paged_attention_pallas
         from ..analysis import pallas_check as _pc
         _pc.enforce(_pc.spec_for_paged_decode(
@@ -174,12 +202,42 @@ def paged_single_query_attention(q, k_pool, v_pool, tables, lengths, *,
                                       layer=layer, scale=scale)
     if k_pool.ndim == 5:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
-    b = q.shape[0]
-    mx = tables.shape[1] * block_size
-    keys = k_pool[tables].reshape(b, mx, *k_pool.shape[2:])
-    vals = v_pool[tables].reshape(b, mx, *v_pool.shape[2:])
+    keys = gather_pages(k_pool, tables, block_size)
+    vals = gather_pages(v_pool, tables, block_size)
     return single_query_attention(q, keys, vals, lengths=lengths,
                                   scale=scale)
+
+
+def block_paged_attention(q, k_pool, v_pool, tables, lengths, *,
+                          block_size: int, layer=0,
+                          scale: Optional[float] = None):
+    """A block's attention read through block tables: ``q [B, Lq, H, D]``
+    (the ``Lq`` positions of each row's block in flight) against the pages
+    ``tables [B, M]`` names in the pool, every query of row ``b`` over the
+    row's first ``lengths[b]`` keys (its context and the block itself, which
+    the pass has written: within the block nothing is masked; 0: the row
+    returns 0). Returns ``[B, Lq, H, D]``.
+
+    The pool is the engine's, with ``layer`` the layer to read (a Python int
+    or a traced scalar), or one layer's; its pages are heads first
+    (``[.., NB, KH, block_size, D]``) or tokens first. On a TPU, for the
+    heads-first shapes ``_pallas.block_paged_attention.supported_shapes``
+    takes, this is the Pallas kernel: each row's pages are fetched from HBM
+    up to its own length, a kv head at a time against its ``Lq * H / KH``
+    query rows, and no gathered copy exists. Everywhere else it is the dense
+    path the kernel is checked against: gather every table's pages, then
+    :func:`multi_query_attention` behind the length mask."""
+    if takes_paged_kernel(q.dtype, k_pool, None, block_size):
+        from ._pallas.block_paged_attention import \
+            block_paged_attention_pallas
+        return block_paged_attention_pallas(
+            q, k_pool, v_pool, tables, lengths, layer=layer, scale=scale)
+    if k_pool.ndim == 5:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
+    keys = gather_pages(k_pool, tables, block_size)
+    vals = gather_pages(v_pool, tables, block_size)
+    pos = jnp.broadcast_to((jnp.asarray(lengths) - 1)[:, None], q.shape[:2])
+    return multi_query_attention(q, keys, vals, pos, scale=scale)
 
 
 def multi_query_attention(q, k, v, pos, scale: Optional[float] = None):
@@ -312,15 +370,35 @@ def _dense_prob_dropout_attention(q, k, v, causal, scale, seed,
 def flash_attention(query, key, value, dropout: float = 0.0,
                     causal: bool = False, return_softmax: bool = False,
                     *, scale: Optional[float] = None, training: bool = True,
-                    fixed_seed_offset=None):
+                    fixed_seed_offset=None, causal_block: int = 1):
     """paddle.nn.functional.flash_attention parity ([B,S,H,D]).
 
     ``dropout`` is attention-PROB dropout inside the kernel (ref
     flash_attn_kernel.cu:44): the mask is regenerated in backward from
     (position, seed) — the TPU-native form of the reference's saved-RNG-
-    state recompute (:76). ``fixed_seed_offset`` pins the seed."""
+    state recompute (:76). ``fixed_seed_offset`` pins the seed.
+
+    ``causal_block = B > 1`` with ``causal`` is the block-causal mask of
+    generation by diffusion over blocks: a query sees every key of its own
+    block of ``B`` positions (counted from position 0) and all earlier ones,
+    ``key <= query | (B - 1)`` for ``B`` a power of two. Forward only (no
+    dropout, no gradient)."""
     if return_softmax:
         raise NotImplementedError("return_softmax is a debug-only GPU feature")
+    if causal_block > 1:
+        if not causal or (dropout > 0.0 and training):
+            raise ValueError("causal_block needs causal=True and runs "
+                             "forward only, without dropout")
+        if causal_block & (causal_block - 1):
+            raise ValueError(f"causal_block {causal_block} is not a power "
+                             "of two")
+        if _use_pallas(query, key):
+            from ._pallas.flash_attention import flash_attention_pallas
+            return flash_attention_pallas(query, key, value, causal=True,
+                                          scale=scale,
+                                          causal_block=causal_block)
+        return reference_attention(query, key, value, True, scale,
+                                   causal_block=causal_block)
     if dropout > 0.0 and training:
         if fixed_seed_offset is not None:
             seed = jnp.asarray(fixed_seed_offset, jnp.int32).reshape(1)

@@ -82,15 +82,24 @@ the rows by where their token came from, and the dropped tokens. A request
 joins the batch at the launch after its prefill, so its second token is seen
 one step after its first.
 
-**The model seam.** The three programs know no model: they ask the model
-for its layer step and for what it caches a token. A model serves by giving
-(``text/models/gpt.py`` and ``text/models/deepseek_v2.py`` both do):
+**The model seam.** The programs know no model: they ask the model for its
+layer step, for what it caches a token and for how it generates. A model
+serves by giving (``text/models/gpt.py``, ``text/models/deepseek_v2.py`` and
+``text/models/sdar_moe.py`` do):
 
 - ``serve_cache_rows()``: the shapes of a token's page rows, one a pool:
   keys and values a head ``((KH, D), (KH, D))``, or one latent row
-  ``((W,),)`` (:mod:`.paged_cache` builds one pool a row);
+  ``((W,),)`` (:mod:`.paged_cache` builds one pool a row, its pages laid
+  out by the row's shape so that no axis is padded on the chip);
   ``serve_dtype()``; ``serve_latent_value_dim`` (None, or where the pool is
   latent the part of a row that is its value);
+- ``serve_generation``: how it generates. ``None``: a token a row a step,
+  and the decode program is the single-query one. A block spec (block
+  length, denoising steps, confidence threshold, mask id): generation by
+  diffusion over blocks, and the decode program is the block-decode program
+  (below); the layer then gives ``serve_attend_block(q, pools, tables,
+  lengths, block_size, layer)`` (the block's queries over each row's pages
+  up to ``lengths``) where the others give ``serve_attend_paged``;
 - ``serve_embed(ids, pos)``, ``serve_layers()``, ``serve_final_norm(x)``,
   ``logits(hidden)``;
 - a layer: ``serve_project(x, pos) -> (q, rows)`` (the queries and the
@@ -109,8 +118,34 @@ for its layer step and for what it caches a token. A model serves by giving
 
 The engine writes the rows into the pools, keeps the block tables, and
 calls no model by name; scheduler, allocator, spill, spans and counters are
-the same for every model. Decoding is greedy (argmax), matching
-``model.generate``'s default.
+the same for every model. Decoding is greedy: the argmax of a row's logits,
+matching ``model.generate``'s default, or for a block model the argmax at
+the positions the unmask rule chooses.
+
+**Generation by diffusion over blocks.** A row carries a block of ``B``
+positions, some still masked, through several passes of one program over
+``[rows, B]`` tokens and a ``[rows, B]`` masked flag. A *denoise pass* embeds
+the block (the mask id at masked positions), writes its keys and values to
+its page (every later pass of the block overwrites all ``B``), attends
+through the block table up to ``pos0 + B`` and, on the device, unmasks by
+the rule (:func:`_unmask`: float32 confidences against the threshold, else
+the most confident); when none is masked a *commit pass* runs the clean block
+once more, writes last, and the block's tokens become output: ``ctx_len``,
+``out_tokens``, ``token_t_ns`` and ``t_first_token`` advance only then, by up
+to ``B`` (fewer in an answer's first and last block; positions past
+``max_new_tokens`` hold the mask id, are never chosen, and a request returns
+exactly what it asked for). The prefill runs the prompt's whole blocks under
+the model's block-causal mask, stores their keys and values and yields no
+token; the prompt's tail opens the first block. PR 33's order holds: the next
+pass is launched before the last one's result is taken, and a row of the
+last launch takes its state from that result on the device: still masked,
+the next denoise pass; whole, its commit pass; committed, a fresh masked
+block up to where its answer ends; so the host decides a launch's rows
+knowing only which rows' commit in flight ends them. A row preempted,
+cancelled or restored mid-block loses only its passes: nothing of an
+unfinished block was committed, and it starts that block again from masks.
+Prefix sharing, chunked prefill and speculation are refused for such a model
+(a block may straddle a shared prefix or a chunk: ROADMAP).
 """
 
 from __future__ import annotations
@@ -134,12 +169,14 @@ from ..observability import metrics, request_timeline, trace
 from ..observability.request_timeline import percentile
 from ..observability.step_monitor import RecompileSentinel
 from ..ops.flash_attention import takes_paged_kernel
+from ..ops.paged_layout import write_blocks, write_tokens
 from .buckets import BucketSet, pow2_buckets, pad_axis
 from .paged_cache import (NULL_BLOCK, OutOfBlocksError, PagedKVCache,
                           SpillError)
 from .prefix_tree import PrefixCache
 from .resilience import Rejected, RequestJournal, ShedPolicy
-from .scheduler import FCFSScheduler, Request, Sequence, Status
+from .scheduler import (BlockInFlight, FCFSScheduler, Request, Sequence,
+                        Status)
 from .speculative import (DEFAULT_GAMMA, ModelDrafter, NGramDrafter,
                           pick_gamma)
 
@@ -175,7 +212,28 @@ def _meters() -> types.SimpleNamespace:
         "rows of the launched decode iterations by where their token came "
         "from (fed=device: the launch in flight, on the device; fed=host), "
         "and tokens in flight that were thrown away (fed=dropped)")
+    passes = metrics.counter(
+        "serving.diffusion_passes",
+        "passes rows of a block-diffusion model took, a row a pass "
+        "(kind=denoise: positions may be unmasked; kind=commit: the whole "
+        "block's keys and values are stored and its tokens become output)")
+    unmasked = metrics.counter(
+        "serving.diffusion_unmasked",
+        "positions unmasked, by the branch of the rule that chose them "
+        "(rule=threshold: every masked position over the confidence "
+        "threshold; rule=schedule: the most confident)")
     return types.SimpleNamespace(
+        passes_denoise=passes.labels(kind="denoise"),
+        passes_commit=passes.labels(kind="commit"),
+        unmasked_threshold=unmasked.labels(rule="threshold"),
+        unmasked_schedule=unmasked.labels(rule="schedule"),
+        blocks=metrics.counter(
+            "serving.diffusion_blocks",
+            "blocks committed by a block-diffusion model").labels(),
+        pass_rows=metrics.counter(
+            "serving.diffusion_pass_rows",
+            "rows of the launched diffusion passes, summed over the "
+            "launches").labels(),
         kv_needed=kv.labels(kind="needed"),
         kv_gathered=kv.labels(kind="gathered"),
         fed_device=fed.labels(fed="device"),
@@ -219,6 +277,29 @@ def _still_rows(seq: Sequence, epoch: int) -> bool:
     """Whether a row launched at preemption count ``epoch`` still is its
     sequence's: not cancelled, finished or preempted since."""
     return seq.status is Status.RUNNING and seq.preemptions == epoch
+
+
+def _unmask(logits, masked, k_min: int, threshold: float):
+    """The unmask rule of generation by diffusion over blocks, on the device:
+    ``logits [W, B, V]`` float32 and ``masked [W, B]`` -> ``(x0, chosen,
+    by_threshold)``. At each masked position ``x0 = argmax(logits)`` and
+    ``conf = softmax_float32(logits)[x0]``; the masked positions with ``conf
+    > threshold`` are chosen if they are at least ``k_min``
+    (``by_threshold``, a row), else the ``k_min`` most confident (ties to
+    the earlier position)."""
+    at = jnp.arange(masked.shape[1])[None, :]
+    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    top = jnp.max(logits, axis=-1)
+    conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))
+    high = jnp.logical_and(masked, conf > threshold)
+    by_threshold = (jnp.sum(high, axis=-1) >= k_min)[:, None]
+    c = jnp.where(masked, conf, -1.0)
+    ahead = jnp.logical_or(
+        c[:, None, :] > c[:, :, None],
+        jnp.logical_and(c[:, None, :] == c[:, :, None],
+                        at[:, None, :] < at[:, :, None]))
+    best = jnp.logical_and(masked, jnp.sum(ahead, axis=-1) < k_min)
+    return x0, jnp.where(by_threshold, high, best), by_threshold
 
 
 class _Launch(NamedTuple):
@@ -362,6 +443,23 @@ class ServingEngine:
         self._accept_lens: List[int] = []
         self.spec_stats = {"iterations": 0, "proposed": 0, "accepted": 0}
 
+        #: how the model generates: None (a token a row a step) or its
+        #: block spec (block length, steps, threshold, mask id), by which
+        #: the engine takes its decode program
+        self._gen = getattr(model, "serve_generation", None)
+        if self._gen is not None:
+            if self.prefix_on or self.chunk_tokens or spec:
+                raise ValueError(
+                    "a model that generates by diffusion over blocks is "
+                    "served without prefix sharing, chunked prefill and "
+                    "speculation (a block may straddle a shared prefix or "
+                    "a chunk)")
+            if self.block_size % self._gen.block_length:
+                raise ValueError(
+                    f"block_size {self.block_size} is not a multiple of the "
+                    f"model's block length {self._gen.block_length}: a "
+                    "block in flight has to lie inside one page")
+
         # -- device state ----------------------------------------------------
         self.cache = self._pool_for(model, num_blocks)
         self._n_pools = len(self.cache.pools)
@@ -380,7 +478,7 @@ class ServingEngine:
         #: ``serving.kv_tokens{kind=gathered}`` then counts)
         self._decode_paged = takes_paged_kernel(
             self.cache.dtype, self.cache.pools[0],
-            model.serve_latent_value_dim)
+            model.serve_latent_value_dim, self.block_size)
         self.prefix = PrefixCache(self.cache, mirror=self._draft_cache) \
             if self.prefix_on else None
         self.sched = FCFSScheduler(max_batch, max_waiting=max_waiting)
@@ -423,7 +521,8 @@ class ServingEngine:
 
         # -- compiled steps + their sentinels --------------------------------
         self._prefill_raw = self._make_prefill()
-        self._decode_raw = self._make_decode()
+        self._decode_raw = self._make_decode() if self._gen is None \
+            else self._make_block_decode()
         self._prefill_fn = _ParamJit(self._prefill_raw, model)
         self._decode_fn = _ParamJit(self._decode_raw, model)
         self._sent_prefill = RecompileSentinel(
@@ -527,9 +626,8 @@ class ServingEngine:
                 q, rows = layer.serve_project(x, pos)
                 o = layer.serve_attend_prefill(q, rows)
                 for pi, row in enumerate(rows):
-                    shape = (s // bs, bs) + row.shape[2:]
-                    pools[pi] = pools[pi].at[li, block_ids].set(
-                        row[0].reshape(shape).astype(pools[pi].dtype))
+                    pools[pi] = write_blocks(pools[pi], li, block_ids,
+                                             row[0], bs)
                 x, c = layer.serve_finish(x, o, real)
                 if c is not None:
                     counts.append(c)
@@ -578,8 +676,8 @@ class ServingEngine:
             for li, layer in enumerate(m.serve_layers()):
                 q, rows = layer.serve_project(x, pos_col)
                 for pi, row in enumerate(rows):
-                    pools[pi] = pools[pi].at[li, bi, si].set(
-                        row[:, 0].astype(pools[pi].dtype))
+                    pools[pi] = write_tokens(pools[pi], li, bi, si,
+                                             row[:, 0], bs)
                 o = layer.serve_attend_paged(q, pools, tables, pos + 1, bs,
                                              li)
                 x, c = layer.serve_finish(x, o, real)
@@ -591,6 +689,117 @@ class ServingEngine:
             return (self._with_counts(tok, counts), *pools)
 
         return decode
+
+    def _state_len(self, width: int) -> int:
+        """Ints of a decode result at bucket ``width`` before the model's
+        counts: a token a row, or for a block-decode program the rows' state:
+        each row's block (its tokens, its masked flags), its stage and its
+        block's first position (:meth:`_block_state`), then the positions the
+        pass unmasked by either branch of the rule."""
+        if self._gen is None:
+            return width
+        return width * (2 * self._gen.block_length + 2) + 2
+
+    def _block_state(self, vec, width: int):
+        """``(tokens [W, B], masked [W, B], stage [W], pos0 [W])`` of a
+        block-decode result, on the device or on the host."""
+        n = width * self._gen.block_length
+        return (vec[:n].reshape(width, -1), vec[n:2 * n].reshape(width, -1),
+                vec[2 * n:2 * n + width], vec[2 * n + width:2 * n + 2 * width])
+
+    def _make_block_decode(self):
+        """The decode program of a model that generates by diffusion over
+        blocks: one pass over ``[rows, B]`` positions, ``B`` the model's
+        block length."""
+        m = self.model
+        gen = self._gen
+        bs = self.block_size
+        B, mask_id, thr = gen.block_length, gen.mask_id, gen.threshold
+        k_min = max(1, B // gen.steps)
+        n_pools = len(m.serve_cache_rows())
+        counted = bool(m.serve_counts)
+
+        def block_decode(tokens, *rest):
+            """tokens [W, B] (each row's block in flight, the mask id where
+            masked); then the pools; tables [W, M]; pos0 [W] the block's
+            first position; masked [W, B] (1: still to be unmasked); commit
+            [W] (1: the block is whole and this is its commit pass); end_pos
+            [W] where the request's answer ends (prompt + max_new_tokens; 0 =
+            inactive pad row, which writes the null block); ``prev``, what
+            the previous launch of this bucket returned, and ``src`` [W]: row
+            i takes its state from row ``src[i]`` of ``prev`` where ``src[i]
+            >= 0`` and from the host's arguments where it is -1.
+
+            One pass: embed the block, write its keys and values to its page
+            (a denoise pass writes them too: every later pass of the block
+            overwrites all B, and the commit pass, which runs the clean
+            block, writes last; nothing reads them but the block's own
+            passes until then), attend through the block table up to ``pos0
+            + B`` (within the block nothing is masked), finish; then, in a
+            denoise pass, the unmask rule (:func:`_unmask`) on the device: a
+            chosen position takes its ``x0``. A row from ``prev``: still
+            masked, the next denoise pass; whole, its commit pass; committed,
+            the first pass of the next block, masks up to ``end_pos``.
+
+            Returns the rows' new state (``_state_len``) and the
+            expert counts behind it, then the pools."""
+            pools = list(rest[:n_pools])
+            tables, pos0, masked, commit, end_pos, prev, src = rest[n_pools:]
+            w = tokens.shape[0]
+            at = jnp.arange(B)[None, :]
+            # -- a resident row's state, from the launch in flight ----------
+            row = jnp.maximum(src, 0)
+            p_tok, p_masked, p_stage, p_pos0 = (
+                part[row] for part in self._block_state(prev, w))
+            nxt = (p_stage == 2)[:, None]       # its block was committed
+            d_pos0 = p_pos0 + jnp.where(p_stage == 2, B, 0)
+            d_tok = jnp.where(nxt, mask_id, p_tok)
+            d_masked = jnp.where(nxt, d_pos0[:, None] + at < end_pos[:, None],
+                                 p_masked > 0)
+            fed = src >= 0
+            tokens = jnp.where(fed[:, None], d_tok, tokens)
+            masked = jnp.where(fed[:, None], d_masked, masked > 0)
+            pos0 = jnp.where(fed, d_pos0, pos0)
+            commit = jnp.where(fed, p_stage == 1, commit > 0)
+            live = end_pos > 0
+            masked = jnp.logical_and(masked, live[:, None])
+            # -- the pass ---------------------------------------------------
+            pos = pos0[:, None] + at
+            real = jnp.broadcast_to(live[:, None], (w, B)) if counted \
+                else None
+            counts = []
+            x = m.serve_embed(tokens, pos)
+            bi = jnp.take_along_axis(
+                tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1), axis=1)
+            bi = jnp.where(live[:, None], bi, NULL_BLOCK)
+            si = pos % bs
+            lengths = jnp.where(live, pos0 + B, 0)
+            for li, layer in enumerate(m.serve_layers()):
+                q, rows = layer.serve_project(x, pos)
+                for pi, r in enumerate(rows):
+                    pools[pi] = write_tokens(pools[pi], li, bi, si, r, bs)
+                o = layer.serve_attend_block(q, pools, tables, lengths, bs,
+                                             li)
+                x, c = layer.serve_finish(x, o, real)
+                if c is not None:
+                    counts.append(c)
+            logits = m.logits(m.serve_final_norm(x)).astype(jnp.float32)
+            x0, chosen, by_threshold = _unmask(logits, masked, k_min, thr)
+            chosen = jnp.logical_and(chosen,
+                                     jnp.logical_not(commit)[:, None])
+            tokens = jnp.where(chosen, x0, tokens)
+            masked = jnp.logical_and(masked, jnp.logical_not(chosen))
+            stage = jnp.where(commit, 2,
+                              jnp.where(jnp.any(masked, axis=-1), 0, 1))
+            n_thr = jnp.sum(jnp.logical_and(chosen, by_threshold))
+            state = jnp.concatenate([
+                tokens.reshape(-1), masked.astype(jnp.int32).reshape(-1),
+                stage.astype(jnp.int32), pos0,
+                jnp.stack([n_thr, jnp.sum(chosen) - n_thr]).astype(
+                    jnp.int32)])
+            return (self._with_counts(state, counts), *pools)
+
+        return block_decode
 
     def _make_extend(self, model, last_only: bool = False):
         """The multi-token paged step: chunk prefill, prefix-hit suffix
@@ -626,8 +835,7 @@ class ServingEngine:
             for li, layer in enumerate(m.serve_layers()):
                 q, rows = layer.serve_project(x, pos_q)
                 for pi, row in enumerate(rows):
-                    pools[pi] = pools[pi].at[li, bi, si].set(
-                        row.astype(pools[pi].dtype))
+                    pools[pi] = write_tokens(pools[pi], li, bi, si, row, bs)
                 o = layer.serve_attend_extend(q, pools, tables, pos_q, bs,
                                               li)
                 x, _ = layer.serve_finish(x, o, None)
@@ -729,8 +937,7 @@ class ServingEngine:
             jax.ShapeDtypeStruct((s0 // self.block_size,), i32),
             jax.ShapeDtypeStruct((), i32))
         dec = jax.make_jaxpr(self._decode_raw)(
-            jax.ShapeDtypeStruct((b0,), i32), *pages,
-            *self._decode_tail_spec(b0))
+            self._decode_head_spec(b0), *pages, *self._decode_tail_spec(b0))
         out = {"prefill": (pre, donated), "decode": (dec, donated)}
         if self._chunk_raw is not None:
             out["extend"] = (jax.make_jaxpr(self._chunk_raw)(
@@ -756,15 +963,28 @@ class ServingEngine:
                 tuple(range(1, 1 + len(dpages))))
         return out
 
+    def _decode_head_spec(self, width: int):
+        """The decode program's first argument at bucket ``width``: a token
+        a row, or a block of them."""
+        shape = (width,) if self._gen is None \
+            else (width, self._gen.block_length)
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
     def _decode_tail_spec(self, width: int):
         """The decode program's arguments behind the pools at bucket
-        ``width``: tables, contexts, the previous launch's result and the
-        row map."""
+        ``width``: tables, contexts (a block-decode program: each block's
+        first position, its masked flags, whether the pass commits, where
+        the answer ends), the previous launch's result and the row map."""
         i32 = jnp.int32
-        return (jax.ShapeDtypeStruct((width, self.max_blocks_per_seq), i32),
-                jax.ShapeDtypeStruct((width,), i32),
-                jax.ShapeDtypeStruct((width + self._n_counts,), i32),
-                jax.ShapeDtypeStruct((width,), i32))
+        row = jax.ShapeDtypeStruct((width,), i32)
+        tables = jax.ShapeDtypeStruct((width, self.max_blocks_per_seq), i32)
+        if self._gen is None:
+            return (tables, row, jax.ShapeDtypeStruct(
+                (self._state_len(width) + self._n_counts,), i32), row)
+        return (tables, row, self._decode_head_spec(width), row, row,
+                jax.ShapeDtypeStruct(
+                    (self._state_len(width) + self._n_counts,), i32),
+                row)
 
     def compile_decode(self):
         """AOT lower+compile the decode executable at its smallest
@@ -779,7 +999,7 @@ class ServingEngine:
         pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.pools]
         i32 = jnp.int32
         compiled = self._decode_fn.lower(
-            jax.ShapeDtypeStruct((b0,), i32), *pages,
+            self._decode_head_spec(b0), *pages,
             *self._decode_tail_spec(b0)).compile()
         return compiled, len(pages)
 
@@ -1233,18 +1453,49 @@ class ServingEngine:
         return True
 
     def _prefill(self, seq: Sequence, block_ids: List[int]) -> None:
+        if self._gen is not None:
+            return self._prefill_blocks(seq, block_ids)
+        self._prefill_run(seq, block_ids, seq.prompt_len)
+
+    def _prefill_blocks(self, seq: Sequence, block_ids: List[int]) -> None:
+        """Admission of a request of a model that generates by diffusion
+        over blocks: the prompt's whole blocks are prefilled clean in one
+        block-causal pass that only stores their keys and values (the bucket's
+        padding lies in later blocks, which no prompt position sees) and
+        yields no token; the prompt's tail opens the first block in flight. A
+        prompt shorter than a block has no such pass."""
+        B = self._gen.block_length
+        n_clean = seq.prompt_len // B * B
+        if n_clean:
+            self._prefill_run(seq, block_ids, n_clean)
+        else:
+            seq.add_phase("queue", time.perf_counter() - seq.t_enqueue)
+            seq.block_ids = list(block_ids)
+            seq.block_log.extend(block_ids)
+            seq.prefill_pos = seq.prompt_len
+        seq.block = self._fresh_block(seq, n_clean)
+
+    def _prefill_run(self, seq: Sequence, block_ids: List[int],
+                     n_tokens: int) -> None:
+        """The prefill program over the prompt's first ``n_tokens`` tokens:
+        all of it, whose last position gives the first token, or (a model
+        that generates by diffusion over blocks) its whole blocks, which
+        give none."""
         seq.add_phase("queue", time.perf_counter() - seq.t_enqueue)
-        bucket = self.prefill_buckets.fit(seq.prompt_len)
+        bucket = self.prefill_buckets.fit(n_tokens)
         with trace.span("serve/prefill", rid=seq.rid,
-                        prompt_len=seq.prompt_len, bucket=bucket) as sp:
+                        prompt_len=n_tokens, bucket=bucket) as sp:
             with trace.span("serve/prefill/build"):
                 nb_bucket = bucket // self.block_size
-                ids = pad_axis(seq.request.prompt_ids[None, :], 1, bucket)
+                ids = pad_axis(seq.request.prompt_ids[None, :n_tokens], 1,
+                               bucket)
                 btab = np.full((nb_bucket,), NULL_BLOCK, np.int32)
-                btab[:len(block_ids)] = block_ids
+                # (a prompt's tail may have a block past the bucket's)
+                held = block_ids[:nb_bucket]
+                btab[:len(held)] = held
                 args = (jnp.asarray(ids, jnp.int32), *self.cache.pools,
                         jnp.asarray(btab),
-                        jnp.asarray(seq.prompt_len, jnp.int32))
+                        jnp.asarray(n_tokens, jnp.int32))
                 self._maybe_lint()
                 self._assert_cow(block_ids)
                 self._sent_prefill.observe_tree(
@@ -1255,18 +1506,19 @@ class ServingEngine:
             with trace.span("serve/prefill/wait") as wait:
                 # host sync: the first token exists now
                 tok = int(self._take_counts(
-                    np.asarray(tok), 1, seq.prompt_len,
-                    bucket).reshape(-1)[0])
+                    np.asarray(tok), 1, n_tokens, bucket).reshape(-1)[0])
             _account(sp.t0_ns, wait.end_ns, "prefill", (seq,))
             with trace.span("serve/prefill/commit"):
                 self.cache.swap(*pools)
                 seq.block_ids = list(block_ids)
                 seq.block_log.extend(block_ids)
-                seq.ctx_len = seq.prompt_len
+                seq.ctx_len = n_tokens
                 seq.prefill_pos = seq.prompt_len
-                self._commit_first_token(seq, tok)
-                self._m.prefill_real.inc(seq.prompt_len)
+                self._m.prefill_real.inc(n_tokens)
                 self._m.prefill_bucket.inc(bucket)
+                if self._gen is not None:
+                    return      # no first token: the blocks give the tokens
+                self._commit_first_token(seq, tok)
                 self._mirror_draft_prefill(seq)
                 if self.prefix is not None:
                     new_nodes = self.prefix.insert(
@@ -1422,6 +1674,10 @@ class ServingEngine:
             seq.block_ids = seq.block_ids[:seq.n_shared_blocks] + list(ids)
             seq.block_log.append(-1)  # spill/restore boundary
             seq.block_log.extend(ids)
+            if seq.block is not None:
+                # nothing of an unfinished block was committed: its passes
+                # are lost, and it starts again from masks
+                seq.block = self._fresh_block(seq, seq.block.pos0)
         # KV re-materialization substitutes for prefill on resume
         _account(sp.t0_ns, sp.end_ns, "prefill", (seq,))
 
@@ -1458,6 +1714,9 @@ class ServingEngine:
         """Resident sequences with a committed frontier token (a
         mid-prefill chunked sequence is resident but not yet
         decodable)."""
+        if self._gen is not None:
+            return [s for s in self.sched.iteration_batch()
+                    if s.block is not None]
         return [s for s in self.sched.iteration_batch() if s.out_tokens]
 
     def _flight_row(self, seq: Sequence) -> int:
@@ -1480,6 +1739,18 @@ class ServingEngine:
         most-private-blocks, youngest) to make room. Pool exhaustion with
         nothing left to preempt fails *that* sequence (F003) —
         :class:`OutOfBlocksError` never crosses the engine loop."""
+        if self._gen is not None:
+            # a block-diffusion row: the block the host knows it in and the
+            # next (the launch in flight may commit the one, and the launch
+            # being built then runs the other), up to the answer's last block
+            B = self._gen.block_length
+            for seq in list(self.sched.running):
+                if seq.status is not Status.RUNNING or seq.block is None:
+                    continue
+                reach = min(seq.block.pos0 + 2 * B,
+                            _ceil_div(seq.end_pos, B) * B)
+                self._grow_blocks(seq, _ceil_div(reach, self.block_size))
+            return
         lookahead = self.spec_gamma if self.spec_gamma else 0
         for seq in list(self.sched.running):
             if seq.status is not Status.RUNNING or not seq.out_tokens:
@@ -1490,29 +1761,32 @@ class ServingEngine:
             ahead = int(self._flight_row(seq) >= 0)
             if ahead and seq.length_reached(1):
                 continue
-            needed = (reach + ahead) // self.block_size + 1
-            while len(seq.block_ids) < needed:
-                got = self._alloc(1)
-                if got is not None:
-                    seq.block_ids.extend(got)
-                    seq.block_log.extend(got)
-                    continue
-                victim = self.sched.preempt_victim(exclude=seq,
-                                                   cost=self._cost_fn())
-                if victim is None:
-                    err = OutOfBlocksError(
-                        f"sequence {seq.rid!r} needs block "
-                        f"{len(seq.block_ids) + 1} of {needed} and there "
-                        "is nothing left to preempt — the request "
-                        "outgrew the pool")
-                    self._cancel(seq, Status.FAILED, str(err),
-                                 diagnose=True)
-                    break
-                try:
-                    self._preempt(victim)
-                except SpillError as e:
-                    self._cancel(victim, Status.FAILED,
-                                 f"KV spill failed: {e}", diagnose=True)
+            self._grow_blocks(seq, (reach + ahead) // self.block_size + 1)
+
+    def _grow_blocks(self, seq: Sequence, needed: int) -> None:
+        """Top ``seq`` up to ``needed`` blocks, preempting for room; with
+        nothing left to preempt the sequence fails."""
+        while len(seq.block_ids) < needed:
+            got = self._alloc(1)
+            if got is not None:
+                seq.block_ids.extend(got)
+                seq.block_log.extend(got)
+                continue
+            victim = self.sched.preempt_victim(exclude=seq,
+                                               cost=self._cost_fn())
+            if victim is None:
+                err = OutOfBlocksError(
+                    f"sequence {seq.rid!r} needs block "
+                    f"{len(seq.block_ids) + 1} of {needed} and there "
+                    "is nothing left to preempt — the request "
+                    "outgrew the pool")
+                self._cancel(seq, Status.FAILED, str(err), diagnose=True)
+                break
+            try:
+                self._preempt(victim)
+            except SpillError as e:
+                self._cancel(victim, Status.FAILED,
+                             f"KV spill failed: {e}", diagnose=True)
 
     def _decode_rows(self) -> List[Tuple[Sequence, int]]:
         """Who decodes next, each with the row of the launch in flight that
@@ -1537,18 +1811,24 @@ class ServingEngine:
         into another bucket than the one in flight (or there is nothing to
         launch) the tokens are taken first and every row is fed by the host:
         one compiled program a bucket, whose ``prev`` has that bucket's
-        shape. (Speculation keeps its own iteration, which waits.)"""
+        shape. (Speculation keeps its own iteration, which waits.) A model
+        that generates by diffusion over blocks runs a pass where the others
+        decode a token, in the same order through the same launch and
+        collect; its own are who runs (``_block_rows``), what the host sends
+        up (``_block_args``) and what a row's result commits
+        (``_block_commit``)."""
         if self.spec_gamma:
             batch = self._decodable()
             if batch:
                 self._spec_iteration(batch)
             return
-        rows, prev = self._decode_rows(), self._ahead
+        pick = self._decode_rows if self._gen is None else self._block_rows
+        rows, prev = pick(), self._ahead
         if prev is not None and not (
                 rows and self.decode_buckets.fit(len(rows)) == prev.width):
             self._ahead = None
             self._decode_collect(prev)
-            rows, prev = self._decode_rows(), None
+            rows, prev = pick(), None
         self._ahead = self._decode_launch(rows) if rows else None
         if prev is not None:
             self._decode_collect(prev)
@@ -1562,7 +1842,8 @@ class ServingEngine:
         if blank is None:
             like = self.cache.pools[0]
             blank = jax.device_put(
-                np.zeros((width + self._n_counts,), np.int32),
+                np.zeros((self._state_len(width) + self._n_counts,),
+                         np.int32),
                 like.sharding if like.committed else None)
             self._no_prev[width] = blank
         return blank
@@ -1570,30 +1851,30 @@ class ServingEngine:
     def _decode_launch(self, rows: List[Tuple[Sequence, int]]) -> _Launch:
         """Build and launch one decode iteration over ``rows`` and return
         without waiting. A row the launch in flight holds reads its token
-        from that launch's result on the device and stands one token further
-        than the host has committed."""
+        (a block model's row: its block, or after its commit pass the next
+        one) from that launch's result on the device and stands that far
+        ahead of what the host has committed."""
         batch = [seq for seq, _ in rows]
         n = len(batch)
         width = self.decode_buckets.fit(n)
         m_blocks = self.max_blocks_per_seq
+        host_args = self._token_args if self._gen is None \
+            else self._block_args
         with self._acted_span("serve/decode", rows=n, width=width) as sp:
             with trace.span("serve/decode/build"):
-                tokens = np.zeros((width,), np.int32)
                 src = np.full((width,), -1, np.int32)
                 tables = np.full((width, m_blocks), NULL_BLOCK, np.int32)
-                lens = np.zeros((width,), np.int32)
                 src[:n] = [row for _, row in rows]
-                # (the program does not read the host's token where src >= 0)
-                tokens[:n] = [seq.out_tokens[-1] for seq in batch]
-                lens[:n] = [seq.ctx_len for seq in batch]
-                lens[:n] += src[:n] >= 0
                 for i, seq in enumerate(batch):
                     tables[i] = seq.table_row(m_blocks, NULL_BLOCK)
+                # (the program does not read the host's token or block
+                # where src >= 0)
+                first, after = host_args(batch, src, width)
                 prev = self._ahead.out if self._ahead is not None \
                     else self._no_prev_for(width)
-                tokens_d, tables_d, lens_d, src_d = jax.device_put(
-                    (tokens, tables, lens, src))
-                args = (tokens_d, *self.cache.pools, tables_d, lens_d, prev,
+                first_d, tables_d, *after_d, src_d = jax.device_put(
+                    (first, tables, *after, src))
+                args = (first_d, *self.cache.pools, tables_d, *after_d, prev,
                         src_d)
                 n_device = int((src >= 0).sum())
                 self._m.fed_device.inc(n_device)
@@ -1601,7 +1882,7 @@ class ServingEngine:
             with trace.span("serve/decode/checks"):
                 self._maybe_lint()
                 if self.prefix is not None:
-                    for seq, pos in zip(batch, lens.tolist()):
+                    for seq, pos in zip(batch, after[0].tolist()):
                         self._assert_cow(self._write_span_ids(seq, pos, 1))
                 self._sent_decode.observe_tree(
                     "serving.decode", self._undonated(args),
@@ -1613,21 +1894,39 @@ class ServingEngine:
                 # prefill, a spill) runs behind it on them
                 self.cache.swap(*pools)
         return _Launch(batch, [seq.preemptions for seq in batch], width,
-                       lens, out, sp.t0_ns,
+                       after[0], out, sp.t0_ns,
                        {id(seq): i for i, seq in enumerate(batch)})
+
+    def _token_args(self, batch: List[Sequence], src: np.ndarray,
+                    width: int):
+        """What the host sends a decode program beside tables and ``src``:
+        ``(tokens [W], (lens [W],))``, the first before the pools, the rest
+        behind the tables; ``lens`` is where each row writes."""
+        n = len(batch)
+        tokens = np.zeros((width,), np.int32)
+        lens = np.zeros((width,), np.int32)
+        tokens[:n] = [seq.out_tokens[-1] for seq in batch]
+        lens[:n] = [seq.ctx_len for seq in batch]
+        lens[:n] += src[:n] >= 0
+        return tokens, (lens,)
 
     def _decode_collect(self, launch: _Launch) -> None:
         """Wait for a launched decode iteration and commit its tokens. A row
         that was cancelled or preempted while the program ran, or that the
         previous launch's token finished (an end-of-sequence token: the one
-        ending the host cannot see ahead), is skipped: its token is dropped,
-        and computed again if the row comes back."""
-        batch, epochs, width, lens, out, t0_ns, _ = launch
+        ending the host cannot see ahead), is skipped: its token (a block
+        model's row: its pass) is dropped, and computed again if the row
+        comes back."""
+        batch, epochs, width, _, out, t0_ns, _ = launch
         rows = len(batch)
+        gen = self._gen
+        per_row = 1 if gen is None else gen.block_length
         with trace.span("serve/decode", rows=rows, width=width):
             with self._acted_span("serve/decode/wait") as wait:
                 # host sync per iteration
-                out = self._take_counts(np.asarray(out), width, rows, width)
+                out = self._take_counts(
+                    np.asarray(out), self._state_len(width), rows * per_row,
+                    width * per_row)
             if self._ahead is not None and wait.end_ns > self._ahead.t0_ns:
                 # the launch queued behind this one began on the device now
                 self._ahead = self._ahead._replace(t0_ns=wait.end_ns)
@@ -1637,28 +1936,122 @@ class ServingEngine:
                 # the relaunch must replay every in-flight request from
                 # scratch, exactly once.
                 _fault_fire("serve.mid_decode")
-                live = [(seq, tok) for seq, epoch, tok
-                        in zip(batch, epochs, out[:rows].tolist())
+                live = [i for i, (seq, epoch) in enumerate(zip(batch, epochs))
                         if _still_rows(seq, epoch)]
                 if len(live) < rows:
                     self._m.fed_dropped.inc(rows - len(live))
                 _account(t0_ns, wait.end_ns, "decode",
-                         [seq for seq, _ in live])
+                         [batch[i] for i in live])
                 self._decode_done(t0_ns, wait)
-                self._kv_count(lens, self._decode_paged)
                 # one commit stamp a step, shared by its rows; none
                 # under FLAGS_telemetry=off
                 now_ns = time.perf_counter_ns() if trace.enabled() else 0
-                finished: List[Sequence] = []
-                for seq, tok in live:
-                    seq.ctx_len += 1
-                    seq.out_tokens.append(tok)
-                    if now_ns:
-                        seq.token_t_ns.append(now_ns)
-                    if seq.is_finished_by(tok):
-                        finished.append(seq)
-                for seq in finished:
+                commit = self._token_commit if gen is None \
+                    else self._block_commit
+                for seq in commit(launch, out, live, now_ns):
                     self._finish(seq)
+
+    def _token_commit(self, launch: _Launch, out: np.ndarray,
+                      live: List[int], now_ns: int) -> List[Sequence]:
+        """Commit a decode program's token to each live row, and return the
+        rows that token finished."""
+        self._kv_count(launch.lens, self._decode_paged)
+        toks = out.tolist()
+        finished: List[Sequence] = []
+        for i in live:
+            seq, tok = launch.batch[i], toks[i]
+            seq.ctx_len += 1
+            seq.out_tokens.append(tok)
+            if now_ns:
+                seq.token_t_ns.append(now_ns)
+            if seq.is_finished_by(tok):
+                finished.append(seq)
+        return finished
+
+    # -- the decode iteration of a model that generates by diffusion --------
+
+    def _fresh_block(self, seq: Sequence, pos0: int) -> BlockInFlight:
+        return BlockInFlight.fresh(seq.request, pos0,
+                                   self._gen.block_length, self._gen.mask_id)
+
+    def _block_rows(self) -> List[Tuple[Sequence, int]]:
+        """:meth:`_decode_rows` for blocks: who runs a pass next, each with
+        the row of the launch in flight that holds its state (-1: the host
+        has it). A row whose launch in flight is the commit pass of its
+        answer's last block is left out (the host knows a whole block's next
+        pass commits it); a block that ends its request some other way (an
+        end-of-sequence token) runs once more and that result is dropped."""
+        B = self._gen.block_length
+        rows = []
+        for seq in self._decodable():
+            src = self._flight_row(seq)
+            blk = seq.block
+            if src >= 0 and blk.stage == 1 and blk.pos0 + B >= seq.end_pos:
+                continue
+            rows.append((seq, src))
+        return rows
+
+    def _block_args(self, batch: List[Sequence], src: np.ndarray,
+                    width: int):
+        """:meth:`_token_args` for a pass: ``(tokens [W, B], (pos0 [W],
+        masked [W, B], commit [W], end_pos [W]))``, each row's block as the
+        host knows it."""
+        B = self._gen.block_length
+        tokens = np.zeros((width, B), np.int32)
+        masked = np.zeros((width, B), np.int32)
+        pos0 = np.zeros((width,), np.int32)
+        commit = np.zeros((width,), np.int32)
+        end_pos = np.zeros((width,), np.int32)
+        for i, seq in enumerate(batch):
+            blk = seq.block
+            tokens[i], masked[i] = blk.tokens, blk.masked
+            pos0[i], commit[i] = blk.pos0, blk.stage == 1
+            end_pos[i] = seq.end_pos
+        self._m.pass_rows.inc(len(batch))
+        return tokens, (pos0, masked, commit, end_pos)
+
+    def _block_commit(self, launch: _Launch, out: np.ndarray,
+                      live: List[int], now_ns: int) -> List[Sequence]:
+        """:meth:`_token_commit` for a pass: take each live row's new state.
+        Only a commit pass gives tokens: the block's positions from the
+        prompt's end to the answer's become output together, and ``ctx_len``
+        moves past the block. (A row that was not live starts its block
+        again from masks if it comes back.)"""
+        batch, width = launch.batch, launch.width
+        rows = len(batch)
+        B = self._gen.block_length
+        toks, masked, stage, pos0 = self._block_state(out, width)
+        self._m.unmasked_threshold.inc(int(out[-2]))
+        self._m.unmasked_schedule.inc(int(out[-1]))
+        # the last position each row attends (a pad row: none)
+        reach = np.zeros((width,), np.int64)
+        reach[:rows] = pos0[:rows] + (B - 1)
+        self._kv_count(reach, self._decode_paged)
+        n_commit = int((stage[live] == 2).sum())
+        self._m.passes_commit.inc(n_commit)
+        self._m.passes_denoise.inc(len(live) - n_commit)
+        finished: List[Sequence] = []
+        for i in live:
+            seq, p0 = batch[i], int(pos0[i])
+            if stage[i] != 2:
+                seq.block = BlockInFlight(p0, toks[i], masked[i] > 0,
+                                          int(stage[i]))
+                continue
+            self._m.blocks.inc()
+            first = max(seq.prompt_len - p0, 0)
+            seq.ctx_len = min(p0 + B, seq.end_pos)
+            if not seq.out_tokens:
+                seq.t_first_token = time.perf_counter()
+            for tok in toks[i, first:seq.ctx_len - p0].tolist():
+                seq.out_tokens.append(tok)
+                if now_ns:
+                    seq.token_t_ns.append(now_ns)
+                if seq.is_finished_by(tok):
+                    finished.append(seq)
+                    break
+            else:
+                seq.block = self._fresh_block(seq, p0 + B)
+        return finished
 
     def _decode_done(self, t0_ns: int, wait) -> None:
         """The decode iteration's wall time, the start of its build to the
@@ -1669,7 +2062,8 @@ class ServingEngine:
             self._m.decode_step_ms.observe(ms)
 
     def _kv_count(self, lens: np.ndarray, paged: bool = False) -> None:
-        """Useful over attempted where the padding happens. The dense
+        """Useful over attempted where the padding happens, from the last
+        position each row of the bucket attends (``lens``). The dense
         decode program and verify are handed the whole table of every row
         of the bucket, whatever the rows' contexts; the paged decode kernel
         reads each row's pages up to its context and the token it wrote

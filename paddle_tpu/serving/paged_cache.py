@@ -4,7 +4,8 @@ The PagedAttention idea (vLLM, SOSP'23) applied to our stack: instead of
 one contiguous ``[B, max_len, KH, D]`` cache per sequence (whose max_len
 reservation wastes ~60-80% of KV memory on real traffic), the KV store
 is a pool of fixed-size *blocks* — ``[L, num_blocks, block_size, *row]``
-for each kind of row the model caches a token (keys and values a head:
+(heads first, ``[L, num_blocks, KH, block_size, D]``, for a row of few heads
+that the chip would pad: ``ops/paged_layout.py``) for each kind of row the model caches a token (keys and values a head:
 two pools of ``[KH, D]`` rows; a latent-attention model: one pool of its
 latent row, no value pool; the model's ``serve_cache_rows()`` says which) —
 and each sequence owns an ordered block list. Allocation
@@ -42,6 +43,7 @@ import numpy as np
 from ..fault.injection import fire as _fault_fire
 from ..framework.offload import host_memory_kind
 from ..observability import metrics
+from ..ops.paged_layout import page_shape
 
 __all__ = ["BlockAllocator", "PagedKVCache", "NULL_BLOCK",
            "OutOfBlocksError", "SpillError"]
@@ -167,7 +169,9 @@ def _gather_blocks(pages, ids):
 
 class PagedKVCache:
     """The device page pool for one model: one array a kind of row the model
-    caches for a token, each ``[n_layers, num_blocks, block_size, *row]``.
+    caches for a token, each ``[n_layers, num_blocks, *page]`` with the page
+    ``[block_size, *row]`` or, for a keys-or-values row of few heads, heads
+    first ``[kv_heads, block_size, head_dim]``.
 
     ``rows`` is the model's row spec (``model.serve_cache_rows()``): a tuple
     of per-token row shapes, one a pool. A model that caches keys and values
@@ -176,7 +180,18 @@ class PagedKVCache:
     ``.v``); a latent-attention model gives one row of its latent width,
     ``((576,),)``, and has no second pool. Block tables, the allocator, spill
     and restore are the same for every spec: they move whole blocks of every
-    pool together.
+    pool together, and so does the prefix tree; none of them looks inside a
+    page.
+
+    **The stored size of a row.** The chip stores an array's last two axes in
+    tiles (16 x 128 in bfloat16) and pads each up to whole tiles, so a page
+    ``[block_size, 4, 128]`` would hold its 4 heads as 16, four times the
+    bytes (the latent row of 576 values is widened to 640 by its model for
+    the same reason). ``ops/paged_layout.page_shape`` therefore picks the
+    page's layout from the row's shape such that no axis is padded: rows of
+    ``(4, 128)`` are stored heads first (the token axis fills the tile); rows
+    of ``(16, 128)`` and the latent row keep tokens first, as ever.
+    ``bytes_per_block`` is then what a block takes on the device too.
 
     The pool arrays are owned here but *written* by the serving engine's
     prefill/decode executables, which take them as donated arguments and
@@ -197,7 +212,8 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.dtype = jnp.dtype(dtype)
         self.pools = tuple(
-            jnp.zeros((n_layers, num_blocks, block_size) + r, self.dtype)
+            jnp.zeros((n_layers, num_blocks)
+                      + page_shape(r, block_size, self.dtype), self.dtype)
             for r in self.rows)
         self.allocator = BlockAllocator(num_blocks)
         self.host_kind = host_memory_kind()

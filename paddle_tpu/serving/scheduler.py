@@ -42,7 +42,7 @@ import numpy as np
 from collections import deque
 
 __all__ = ["Request", "Sequence", "Status", "FCFSScheduler",
-           "TERMINAL_STATUSES"]
+           "TERMINAL_STATUSES", "BlockInFlight"]
 
 
 class Status(enum.Enum):
@@ -86,6 +86,35 @@ class Request:
 
 
 @dataclass
+class BlockInFlight:
+    """The block a sequence of a model that generates by diffusion over
+    blocks is denoising: nothing of it is output or counted in ``ctx_len``
+    until its commit pass has run. ``stage`` 0: positions are still masked;
+    1: the block is whole and its next pass is the commit pass."""
+
+    pos0: int                       # the block's first position
+    tokens: np.ndarray              # [B] int32; the mask id where masked
+    masked: np.ndarray              # [B] bool: still to be unmasked
+    stage: int = 0
+
+    @classmethod
+    def fresh(cls, request: "Request", pos0: int, length: int,
+              mask_id: int) -> "BlockInFlight":
+        """The block at ``pos0`` before its first pass: the prompt's tail
+        where the block begins inside the prompt, masks elsewhere. Positions
+        past ``max_new_tokens`` hold the mask id too but are not ``masked``:
+        they stay as they are through every pass and are never chosen."""
+        pos = pos0 + np.arange(length)
+        prompt = request.prompt_ids
+        tokens = np.full((length,), mask_id, np.int32)
+        n_prompt = max(0, min(length, prompt.size - pos0))
+        tokens[:n_prompt] = prompt[pos0:pos0 + n_prompt]
+        masked = (pos >= prompt.size) \
+            & (pos < prompt.size + request.max_new_tokens)
+        return cls(int(pos0), tokens, masked)
+
+
+@dataclass
 class Sequence:
     """Runtime state of one request inside the engine."""
 
@@ -110,6 +139,10 @@ class Sequence:
     # -- speculative decoding (FLAGS_serve_speculative) -------------------
     host_draft_kv: Any = None            # drafter-pool mirror of host_kv
     draft_ctx: int = 0                   # tokens with drafter KV written
+    # -- generation by diffusion over blocks (model.serve_generation) ------
+    # the block being denoised; ctx_len, out_tokens, token_t_ns and
+    # t_first_token advance only when its commit pass has run
+    block: Optional[BlockInFlight] = None
     error: Optional[str] = None          # reason for a non-FINISHED ending
     # every block id ever assigned, in grant order (spill boundaries as
     # -1): the determinism regression's witness
@@ -148,6 +181,11 @@ class Sequence:
     @property
     def n_generated(self) -> int:
         return len(self.out_tokens)
+
+    @property
+    def end_pos(self) -> int:
+        """The position at which the answer ends by its length."""
+        return self.prompt_len + self.request.max_new_tokens
 
     def add_phase(self, name: str, dur_s: float) -> None:
         self.phase_s[name] = self.phase_s.get(name, 0.0) + dur_s

@@ -43,8 +43,7 @@ from ... import nn
 from ...nn import initializer as I
 from ...nn.layer import ParamAttr
 from ...incubate.distributed.models.moe.dropless import (
-    dropless_glu_experts, expert_form, group_limited_topk)
-from ...observability import metrics
+    dropless_glu_experts, group_limited_topk, record_held_pairs)
 from ...ops.flash_attention import (flash_attention, latent_attention,
                                     latent_paged_attention)
 
@@ -478,6 +477,8 @@ class DeepseekV2ForCausalLM(nn.Layer):
 
     # -- the serving engine's seam (serving/engine.py) ---------------------
 
+    serve_generation = None             # a token a row a step
+
     #: the decode and prefill programs return, beside the token, the pairs
     #: each held expert got (summed over the expert layers)
     @property
@@ -513,23 +514,7 @@ class DeepseekV2ForCausalLM(nn.Layer):
         held expert ``e``; the program was traced for ``n_slots`` tokens,
         which is what chose its expert layers' form."""
         cfg = self.cfg
-        n_moe = sum(1 for l in self.model.layers if l.is_moe)
-        metrics.counter(
-            "serving.moe_expert_calls",
-            "expert layers the launched prefill and decode programs ran, "
-            "by the form their token count selects (form=dense: every held "
-            "expert over the whole batch; form=grouped: sorted pairs)"
-        ).labels(form=expert_form(n_slots)).inc(n_moe)
-        pairs = metrics.counter(
-            "serving.moe_assignments",
-            "(token, expert) pairs the router made (kind=routed: tokens x "
-            "top-k x expert layers) and those that fell to experts held "
-            "here (kind=held)")
-        pairs.labels(kind="routed").inc(
-            int(n_tokens) * cfg.num_experts_per_tok * n_moe)
-        pairs.labels(kind="held").inc(int(load.sum()))
-        by_expert = metrics.counter(
-            "serving.moe_expert_load",
-            "(token, expert) pairs that fell to each held expert")
-        for i, n in enumerate(load):
-            by_expert.labels(expert=cfg.held[0] + i).inc(int(n))
+        record_held_pairs(
+            load, n_tokens, n_slots, top_k=cfg.num_experts_per_tok,
+            n_layers=sum(1 for l in self.model.layers if l.is_moe),
+            first=cfg.held[0])

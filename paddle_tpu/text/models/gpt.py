@@ -28,6 +28,7 @@ from ...distributed.fleet.layers.mpu.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
     ParallelCrossEntropy, _constrain, MP_AXIS)
 from ...ops import flash_attention
+from ...ops.paged_layout import gather_pages
 from ...ops.flash_attention import (multi_query_attention,
                                     paged_single_query_attention)
 
@@ -302,8 +303,7 @@ class GPTBlock(nn.Layer):
             q, *pools, tables, lengths, block_size=block_size, layer=layer)
 
     def serve_attend_extend(self, q, pools, tables, pos, block_size, layer):
-        b, mx = tables.shape[0], tables.shape[1] * block_size
-        keys, vals = (p[layer][tables].reshape(b, mx, *p.shape[3:])
+        keys, vals = (gather_pages(p[layer], tables, block_size)
                       for p in pools)
         return multi_query_attention(q, keys, vals, pos)
 
@@ -384,6 +384,7 @@ class GPTForCausalLM(nn.Layer):
 
     serve_counts = 0        # the programs return nothing beside the token
     serve_latent_value_dim = None       # keys and values, not a latent row
+    serve_generation = None             # a token a row a step
 
     def serve_cache_rows(self):
         """A token's page rows: keys and values a kv head."""

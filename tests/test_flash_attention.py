@@ -94,6 +94,43 @@ def test_pallas_forward_takes_values_of_another_head_size(causal, blocks):
             fa.flash_attention_pallas(q, k, v, dropout=0.1)
 
 
+@pytest.mark.parametrize("s,blocks,kv_heads", [(256, (128, 128), 2),
+                                                (256, (64, 64), 1),
+                                                (512, (128, 128), 2)])
+def test_pallas_forward_block_causal_mask(s, blocks, kv_heads):
+    """The block-causal mask of generation by diffusion over blocks (a query
+    sees every key of its own block of 4 and all earlier ones): the kernel,
+    plain and triangular enumeration, grouped-query, against the dense path;
+    only the diagonal differs from the causal mask."""
+    with interpreted_pallas() as fa:
+        rng = np.random.default_rng(3)
+        q = jnp.asarray(rng.standard_normal((1, s, 2, 128)), jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal((1, s, kv_heads, 128)),
+                            jnp.float32) for _ in range(2))
+        out = fa.flash_attention_pallas(q, k, v, causal=True, causal_block=4,
+                                        block_q=blocks[0], block_k=blocks[1])
+        want = reference_attention(q, k, v, causal=True, causal_block=4)
+        np.testing.assert_allclose(out, want, atol=2e-5)
+        plain = reference_attention(q, k, v, causal=True)
+        # the last position of a block is causal already, the first is not
+        np.testing.assert_allclose(want[:, 3::4], plain[:, 3::4], atol=1e-6)
+        assert np.abs(np.asarray(want[:, 0::4] - plain[:, 0::4])).max() > 1e-3
+        # the mask by its definition: j // 4 <= i // 4
+        i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+        sc = np.einsum("qhd,khd->hqk", np.asarray(q[0]),
+                       np.repeat(np.asarray(k[0]), 2 // kv_heads, 1)) \
+            / np.sqrt(128)
+        sc = np.where(j // 4 <= i // 4, sc, -np.inf)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        pr /= pr.sum(-1, keepdims=True)
+        naive = np.einsum("hqk,khd->qhd", pr, np.repeat(np.asarray(v[0]),
+                                                        2 // kv_heads, 1))
+        np.testing.assert_allclose(want[0], naive, atol=2e-5)
+        with pytest.raises(ValueError, match="forward only"):
+            fa.flash_attention_pallas(q, k, v, causal=True, causal_block=4,
+                                      dropout=0.1)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_pallas_kernel_interpret_bf16(causal):
     """The production dtype: bf16 inputs, MXU-native dots, fp32 accumulation.

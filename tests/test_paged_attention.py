@@ -338,3 +338,125 @@ def test_roofline_reader_reads_nothing_in_a_trace_of_the_gather_program(
     ctx.win = (tr.spans[0].start, tr.spans[-1].end)
     assert len(readers.decode_programs(ctx)) == 5
     assert read(ctx) is None
+
+
+# -- the block kernel: several queries a row over a heads-first pool --------
+
+from paddle_tpu.ops._pallas import block_paged_attention as BPA  # noqa: E402
+from paddle_tpu.ops.flash_attention import (  # noqa: E402
+    block_paged_attention, multi_query_attention)
+from paddle_tpu.ops.paged_layout import (  # noqa: E402
+    gather_pages, heads_first, page_shape, write_blocks, write_tokens)
+from paddle_tpu.serving.paged_cache import PagedKVCache  # noqa: E402
+
+block_kernel = functools.partial(BPA.block_paged_attention_pallas,
+                                 interpret=True)
+
+
+def _block_case(lengths, kh, heads, lq, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    b = len(lengths)
+    q = jnp.asarray(rng.standard_normal((b, lq, heads, D)), dtype)
+    k = jnp.asarray(rng.standard_normal((L, NB, kh, BS, D)), dtype)
+    v = jnp.asarray(rng.standard_normal((L, NB, kh, BS, D)), dtype)
+    tables = np.full((b, M), NULL_BLOCK, np.int32)
+    free = rng.permutation(np.arange(1, NB))
+    at = 0
+    for i, n in enumerate(lengths):
+        pages = -(-n // BS)
+        tables[i, :pages] = free[at:at + pages]
+        at += pages
+    return q, k, v, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 5])
+@pytest.mark.parametrize("kh,heads,lq", [(4, 32, 4), (2, 4, 4), (1, 8, 2)])
+def test_block_kernel_equals_dense_attention_over_ragged_contexts(
+        pages, kh, heads, lq):
+    """Rows of unequal contexts (a pad row, one block, page edges, the whole
+    table): every query of a row over the row's first ``lengths[b]`` keys,
+    against gather-and-dense behind the length mask, in either layer."""
+    q, k, v, tables, lengths = _block_case(LENGTHS[:1] + [4] + LENGTHS[2:],
+                                           kh, heads, lq, jnp.bfloat16)
+    for layer in (0, 1):
+        got = block_kernel(q, k, v, tables, lengths, layer=layer,
+                           pages_per_step=pages)
+        keys = gather_pages(k[layer], tables, BS)
+        vals = gather_pages(v[layer], tables, BS)
+        pos = jnp.broadcast_to((lengths - 1)[:, None], q.shape[:2])
+        want = multi_query_attention(q, keys, vals, pos)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=2e-2, rtol=2e-2)
+        assert not np.asarray(got, np.float32)[0].any()     # the pad row
+        # and off the chip the entry point is that dense path
+        np.testing.assert_array_equal(
+            np.asarray(block_paged_attention(q, k, v, tables, lengths,
+                                             block_size=BS, layer=layer),
+                       np.float32), np.asarray(want, np.float32))
+
+
+def test_block_kernel_takes_the_cells_shapes_and_refuses_others():
+    pool = jnp.zeros((1, 2, 4, 16, 128), jnp.bfloat16)
+    assert BPA.supported_shapes(jnp.bfloat16, pool)
+    assert not BPA.supported_shapes(jnp.float32, pool)
+    assert not BPA.supported_shapes(jnp.bfloat16,
+                                    jnp.zeros((1, 2, 4, 8, 128),
+                                              jnp.bfloat16))
+    assert not BPA.supported_shapes(jnp.bfloat16,
+                                    jnp.zeros((1, 2, 4, 16, 64),
+                                              jnp.bfloat16))
+    assert not FA.takes_paged_kernel(jnp.bfloat16, pool, None, 16)  # the CPU
+
+
+def test_the_pools_layout_follows_the_rows_shape():
+    """Rows of (4, 128) in bfloat16 are stored heads first (tokens first the
+    head axis of 4 would be padded to the tile's 16 on the chip); rows of
+    (16, 128) and the latent row keep tokens first, their bytes unchanged;
+    shapes that fill no tile either way (the tests' small heads) keep it
+    too. Spill and restore move whole pages and follow."""
+    bf = jnp.bfloat16
+    assert page_shape((4, 128), 16, bf) == (4, 16, 128)
+    assert page_shape((16, 128), 16, bf) == (16, 16, 128)
+    assert page_shape((640,), 16, bf) == (16, 640)
+    assert page_shape((2, 128), 8, jnp.float32) == (2, 8, 128)
+    assert page_shape((4, 32), 8, jnp.float32) == (8, 4, 32)
+    assert page_shape((2, 8), 4, jnp.float32) == (4, 2, 8)
+    few = PagedKVCache(2, 6, 16, dtype=bf, rows=((4, 128), (4, 128)))
+    gpt = PagedKVCache(2, 6, 16, dtype=bf, rows=((16, 128), (16, 128)))
+    mla = PagedKVCache(2, 6, 16, dtype=bf, rows=((640,),))
+    assert few.k.shape == (2, 6, 4, 16, 128) and heads_first(few.k, 16)
+    assert gpt.k.shape == (2, 6, 16, 16, 128) and not heads_first(gpt.k, 16)
+    assert mla.pools[0].shape == (2, 6, 16, 640)
+    for cache, per_token in ((few, 2 * 4 * 128), (gpt, 2 * 16 * 128),
+                             (mla, 640)):
+        assert cache.bytes_per_block == 2 * 16 * per_token * 2
+        assert sum(p.nbytes for p in cache.pools) == 6 * cache.bytes_per_block
+    # writes and reads agree, whatever the layout
+    rng = np.random.default_rng(0)
+    rows = jnp.asarray(rng.standard_normal((32, 4, 128)), bf)
+    ids = jnp.asarray([3, 5], jnp.int32)
+    pool = write_blocks(few.k, 1, ids, rows, 16)
+    got = gather_pages(pool[1], ids[None], 16)[0]
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(rows, np.float32))
+    one = jnp.asarray(rng.standard_normal((2, 3, 4, 128)), bf)
+    bi = jnp.asarray([[3, 3, 3], [5, 5, 5]], jnp.int32)
+    si = jnp.asarray([[4, 5, 6], [0, 1, 15]], jnp.int32)
+    pool = write_tokens(pool, 1, bi, si, one, 16)
+    got = np.asarray(gather_pages(pool[1], ids[None], 16)[0], np.float32)
+    np.testing.assert_array_equal(got[[4, 5, 6]],
+                                  np.asarray(one[0], np.float32))
+    np.testing.assert_array_equal(got[[16, 17, 31]],
+                                  np.asarray(one[1], np.float32))
+    # a spill and a restore into other pages keep every byte
+    few.pools = (pool, few.v)
+    blocks = few.allocator.alloc(2)
+    assert blocks == [1, 2]
+    few.allocator.free(blocks)
+    held = few.allocator.alloc(5)
+    page3 = np.asarray(pool[:, 3], np.float32)
+    host = few.snapshot([3])
+    few.restore(host, [4])
+    np.testing.assert_array_equal(np.asarray(few.k[:, 4], np.float32), page3)
+    few.allocator.free(held)

@@ -1401,3 +1401,226 @@ class TestJournalPromptHash:
             worker.run(str(tmp_path), dict(
                 model_seed=7, vocab=128, hidden=32, layers=1, heads=2,
                 max_pos=32, block_size=4, num_blocks=8, max_batch=2))
+
+
+# ---------------------------------------------------------------------------
+# Generation by diffusion over blocks (a model whose serve_generation is a
+# block spec): a pass yields no token or a whole block
+# ---------------------------------------------------------------------------
+
+def block_model(**over):
+    from paddle_tpu.text.models.sdar_moe import (SdarMoeForCausalLM,
+                                                 sdar_moe_tiny)
+    paddle.seed(11)
+    m = SdarMoeForCausalLM(sdar_moe_tiny(initializer_range=0.2, **over))
+    m.eval()
+    return m
+
+
+def block_free_run(model, req):
+    """The model's own greedy generation by diffusion over blocks, no cache:
+    every denoise pass one block-causal forward of the clean sequence so far
+    followed by the block as it stands; the rule of the engine's
+    ``_unmask`` in numpy."""
+    gen = model.serve_generation
+    B, k_min = gen.block_length, max(1, gen.block_length // gen.steps)
+    seq = [int(t) for t in req.prompt_ids]
+    n_prompt, end = len(seq), len(seq) + req.max_new_tokens
+    for pos0 in range(n_prompt // B * B, end, B):
+        pos = pos0 + np.arange(B)
+        blk = np.full((B,), gen.mask_id, np.int64)
+        blk[:max(0, n_prompt - pos0)] = seq[pos0:]
+        masked = (pos >= n_prompt) & (pos < end)
+        while masked.any():
+            ids = np.concatenate([np.asarray(seq[:pos0], np.int64), blk])
+            logits = np.asarray(model(jnp.asarray(ids[None], jnp.int32)),
+                                np.float32)[0, pos0:]
+            prob = np.exp(logits - logits.max(-1, keepdims=True))
+            conf = 1.0 / prob.sum(-1)
+            high = masked & (conf > gen.threshold)
+            if high.sum() >= k_min:
+                take = high
+            else:
+                order = np.argsort(-np.where(masked, conf, -1.0),
+                                   kind="stable")
+                take = np.zeros_like(masked)
+                take[order[:k_min]] = True
+                take &= masked
+            blk[take] = logits.argmax(-1)[take]
+            masked &= ~take
+        seq = seq[:pos0] + [int(t) for t in blk[:min(B, end - pos0)]]
+    return np.asarray(seq, np.int32)
+
+
+def block_requests(n, lo=2, hi=14, seed=0, new=(1, 13)):
+    rng = np.random.default_rng(seed)
+    return [Request(rid=f"b{i}",
+                    prompt_ids=rng.integers(0, 500,
+                                            int(rng.integers(lo, hi + 1))),
+                    max_new_tokens=int(rng.integers(new[0], new[1] + 1)))
+            for i in range(n)]
+
+
+class TestBlockDiffusion:
+    def _engine(self, model, **kw):
+        args = dict(block_size=8, num_blocks=40, max_batch=3, max_seq_len=32,
+                    prefill_buckets=[8, 16], decode_buckets=[1, 3])
+        args.update(kw)
+        return ServingEngine(model, **args)
+
+    def test_the_engine_takes_its_decode_program_by_the_models_answer(self):
+        assert micro_model().serve_generation is None
+        gpt = ServingEngine(micro_model(), block_size=4, num_blocks=17,
+                            max_batch=2)
+        assert gpt._gen is None and gpt._decode_head_spec(2).shape == (2,)
+        eng = self._engine(block_model())
+        assert eng._gen.block_length == 4
+        assert eng._decode_head_spec(3).shape == (3, 4)
+        # the pool of few kv heads is stored heads first, GPT's as ever
+        assert eng.cache.k.shape == (2, 40, 2, 8, 128)
+        assert gpt.cache.k.shape == (2, 17, 4, 4, 12)
+
+    def test_rows_join_and_leave_mid_block_and_tokens_match_a_free_run(self):
+        """Seven requests through three rows: a row that ends frees its slot
+        while its neighbours are in the middle of a block, and the joiner's
+        first pass runs beside their later ones, under the launch-ahead
+        order; every answer has exactly the tokens it asked for, the model's
+        own."""
+        model = block_model()
+        eng = self._engine(model)
+        metrics.reset_all()
+        requests = block_requests(7, seed=3)
+        mid_block_joins = 0
+        seqs = [eng.submit(r) for r in requests]
+        running = set()
+        while eng.sched.n_pending:
+            eng.step()
+            now = {s.rid for s in eng.sched.running}
+            if now - running and any(
+                    s.block is not None and s.block.masked.any()
+                    and not s.block.masked.all()
+                    for s in eng.sched.running if s.rid in running):
+                mid_block_joins += 1
+            running = now
+        assert mid_block_joins > 0
+        for r, seq in zip(requests, seqs):
+            assert seq.status is Status.FINISHED
+            assert len(seq.out_tokens) == r.max_new_tokens
+            np.testing.assert_array_equal(seq.output,
+                                          block_free_run(model, r))
+        fed = metrics.counter("serving.decode_rows")
+        assert fed.labels(fed="device").get() > fed.labels(fed="host").get()
+        assert fed.labels(fed="dropped").get() == 0
+        assert eng._ahead is None and eng.cache.allocator.n_used == 0
+
+    def test_nothing_moves_until_a_block_commits(self):
+        """A denoise pass writes the block's keys and values to its page (the
+        commit pass writes last) and changes nothing the host counts:
+        ``ctx_len``, the output and the first-token stamp move at a commit,
+        by the block's tokens."""
+        model = block_model()
+        eng = self._engine(model)
+        req = Request("one", np.arange(10, 19), max_new_tokens=7)  # P % 4 = 1
+        seq = eng.submit(req)
+        eng.step()                  # prefill of 8, the first pass launched
+        assert seq.ctx_len == 8 and not seq.out_tokens
+        assert seq.block.pos0 == 8 and seq.t_first_token is None
+        page = seq.block_ids[1]
+        seen = []
+        while not seq.out_tokens:
+            before = np.asarray(eng.cache.k[0, page]).copy()
+            eng.step()
+            seen.append((seq.ctx_len, len(seq.out_tokens),
+                         bool((np.asarray(eng.cache.k[0, page])
+                               != before).any())))
+        # three positions to unmask: three denoise passes and a commit, all
+        # of which wrote the page; the first block gives 3 tokens, not 4
+        assert [s[:2] for s in seen[:-1]] == [(8, 0)] * (len(seen) - 1)
+        assert seen[-1][:2] == (12, 3) and all(s[2] for s in seen[:3])
+        assert seq.t_first_token is not None
+        while eng.sched.n_pending:
+            eng.step()
+        assert len(seq.out_tokens) == 7 and seq.ctx_len == 16
+        np.testing.assert_array_equal(seq.output, block_free_run(model, req))
+
+    def test_a_row_preempted_mid_block_loses_only_its_passes(self):
+        model = block_model()
+        eng = self._engine(model)
+        requests = block_requests(3, lo=6, hi=9, seed=5, new=(9, 12))
+        seqs = [eng.submit(r) for r in requests]
+        victim = seqs[1]
+        eng.step()
+        while not (victim.block is not None and victim.block.stage == 0
+                   and 0 < victim.block.masked.sum() < 4
+                   and victim.out_tokens):
+            eng.step()
+        assert victim in eng._ahead[0]
+        n_out, ctx, pos0 = len(victim.out_tokens), victim.ctx_len, \
+            victim.block.pos0
+        eng._preempt(victim)            # a pass of its block is in flight
+        eng.step()                      # restored at once: room is left
+        assert victim.preemptions == 1
+        assert (len(victim.out_tokens), victim.ctx_len) == (n_out, ctx)
+        # the block starts again from masks
+        assert victim.block.pos0 == pos0 and victim.block.stage == 0
+        assert victim.block.masked.sum() >= 3
+        while eng.sched.n_pending:
+            eng.step()
+        for r, seq in zip(requests, seqs):
+            np.testing.assert_array_equal(seq.output,
+                                          block_free_run(model, r))
+        assert metrics.counter("serving.decode_rows").labels(
+            fed="dropped").get() >= 1
+        assert eng.cache.allocator.n_used == 0
+
+    def test_a_row_cancelled_mid_block_leaves_the_others_exact(self):
+        model = block_model()
+        eng = self._engine(model)
+        requests = block_requests(3, lo=6, hi=9, seed=6, new=(9, 12))
+        seqs = [eng.submit(r) for r in requests]
+        for _ in range(3):
+            eng.step()
+        gone = seqs[0]
+        assert gone in eng._ahead[0] and gone.block.masked.any()
+        n_out = len(gone.out_tokens)
+        eng._cancel(gone, Status.EXPIRED, "test: cancelled mid-block")
+        while eng.sched.n_pending:
+            eng.step()
+        assert gone.status is Status.EXPIRED and len(gone.out_tokens) == n_out
+        for r, seq in zip(requests[1:], seqs[1:]):
+            np.testing.assert_array_equal(seq.output,
+                                          block_free_run(model, r))
+        assert eng.cache.allocator.n_used == 0
+
+    def test_pool_pressure_spills_and_restores_exactly(self):
+        """A pool too small for three rows: a row is preempted (its pages go
+        to the host and come back), restarts the block it was in, and every
+        answer is still the model's own."""
+        model = block_model()
+        eng = self._engine(model, num_blocks=8)
+        metrics.reset_all()
+        requests = block_requests(4, lo=8, hi=14, seed=7, new=(10, 14))
+        results = eng.serve(requests)
+        assert metrics.counter("serving.preemptions").get() > 0
+        assert metrics.counter("serving.kv_restores").get() > 0
+        for r in requests:
+            assert results[r.rid].status is Status.FINISHED
+            np.testing.assert_array_equal(results[r.rid].output,
+                                          block_free_run(model, r))
+        assert eng.cache.allocator.n_used == 0
+
+    def test_an_end_of_sequence_token_ends_a_request_inside_a_block(self):
+        model = block_model()
+        req = Request("e", np.arange(3, 12), max_new_tokens=12)
+        free = block_free_run(model, req)[9:]
+        eos = int(free[5])
+        cut = int(np.flatnonzero(free == eos)[0]) + 1
+        eng = self._engine(model)
+        seq = eng.submit(Request("e", np.arange(3, 12), max_new_tokens=12,
+                                 eos_token_id=eos))
+        while eng.sched.n_pending:
+            eng.step()
+        assert seq.status is Status.FINISHED
+        assert seq.out_tokens == [int(t) for t in free[:cut]]
+        # (the pass launched before the token was seen is never taken)
+        assert eng.cache.allocator.n_used == 0

@@ -503,3 +503,79 @@ def test_hooks_install_once():
     assert len(gc.callbacks) == n_gc
     assert len(monitoring.get_event_duration_listeners()) == n_jit
     assert gc.callbacks.count(step_monitor._on_gc) == 1
+
+
+# -- generation by diffusion over blocks ------------------------------------
+
+def _block_engine():
+    from paddle_tpu.text.models.sdar_moe import (SdarMoeForCausalLM,
+                                                 sdar_moe_tiny)
+    paddle.seed(5)
+    m = SdarMoeForCausalLM(sdar_moe_tiny(initializer_range=0.2))
+    m.eval()
+    return ServingEngine(m, block_size=8, num_blocks=33, max_batch=4,
+                         max_seq_len=64, prefill_buckets=[16, 32],
+                         decode_buckets=[4])
+
+
+def test_a_diffusion_pass_keeps_the_decode_spans_and_counts_its_passes():
+    """A pass of a block-diffusion model is a ``serve/decode`` span with the
+    same ``build|checks|launch`` and ``wait|commit`` children as a decode
+    step (``benchmark/lib/program_spans.py`` reads it unchanged), launched
+    before the last one is taken; and on whole blocks with no confidence over
+    the threshold ``passes = 4 x blocks + blocks``."""
+    eng = _block_engine()
+    metrics.reset_all()
+    rng = np.random.default_rng(1)
+    # prompts and answers of whole blocks: every block has four positions
+    reqs = [Request(rid=f"r{i}", prompt_ids=rng.integers(0, 500, 4 * p),
+                    max_new_tokens=4 * n)
+            for i, (p, n) in enumerate([(1, 2), (2, 1), (3, 3), (2, 2),
+                                        (1, 1)])]
+    _drive(eng, reqs)
+    recs = trace.spans()
+    kids = _by_parent(recs)
+    n_take = n_launch = 0
+    for st in (r for r in recs if r["name"] == "serve/step"):
+        mine = kids[st["id"]]
+        assert {k["name"] for k in mine} <= STEP_CHILDREN
+        assert sum(k["dur_ns"] for k in mine) <= st["dur_ns"]
+        decodes = [k for k in mine if k["name"] == "serve/decode"]
+        for k in decodes:
+            parts = kids[k["id"]]
+            names = [d["name"] for d in parts if d["name"] != "serve/finish"]
+            assert names in (DECODE_TAKE, DECODE_LAUNCH)
+            n_take += names == DECODE_TAKE
+            n_launch += names == DECODE_LAUNCH
+            # the children add up inside their parent, in order
+            for a, b in zip(parts, parts[1:]):
+                assert a["t0_ns"] + a["dur_ns"] <= b["t0_ns"]
+            assert sum(d["dur_ns"] for d in parts) <= k["dur_ns"]
+            assert k["attrs"]["width"] == 4 and 1 <= k["attrs"]["rows"] <= 4
+        if len(decodes) == 2:        # launched, then the last one taken
+            assert [d["name"] for d in kids[decodes[0]["id"]]] == \
+                DECODE_LAUNCH
+    assert n_take == n_launch > 0
+    snap = metrics.snapshot()
+    passes = {s["labels"]["kind"]: s["value"]
+              for s in snap["serving.diffusion_passes"]["series"]}
+    unmasked = {s["labels"]["rule"]: s["value"]
+                for s in snap["serving.diffusion_unmasked"]["series"]}
+    blocks = snap["serving.diffusion_blocks"]["series"][0]["value"]
+    assert blocks == sum(r.max_new_tokens for r in reqs) // 4 == 9
+    assert unmasked == {"threshold": 0, "schedule": 4 * blocks}
+    assert passes == {"denoise": 4 * blocks, "commit": blocks}
+    rows = snap["serving.diffusion_pass_rows"]["series"][0]["value"]
+    assert rows == passes["denoise"] + passes["commit"]
+    assert rows == sum(
+        k["attrs"]["rows"] for r in recs if r["name"] == "serve/step"
+        for k in kids[r["id"]] if k["name"] == "serve/decode"
+        and [d["name"] for d in kids[k["id"]]][:1] == ["serve/decode/build"])
+    kv = {s["labels"]["kind"]: s["value"]
+          for s in snap["serving.kv_tokens"]["series"]}
+    # a pass attends its context and its block; off the chip the whole table
+    assert 0 < kv["needed"] < kv["gathered"]
+    assert kv["gathered"] == n_take * 4 * eng.max_blocks_per_seq * 8
+    # no first token from a prefill: the first-token stamp is a commit's
+    for r in request_timeline.current().records():
+        assert r["ttft_ms"] is not None and r["new_tokens"] % 4 == 0
