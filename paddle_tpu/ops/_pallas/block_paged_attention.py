@@ -1,25 +1,35 @@
 """Block paged attention: the attention of a diffusion pass, several queries a
-row, read straight out of a page pool whose pages are heads first.
+row, read straight out of ONE page pool of fused rows whose pages are heads
+first.
 
 One ``pallas_call`` a layer. ``tables [B, M]``, ``lengths [B]`` and the layer
-index are scalar-prefetch operands, the pool ``[L, NB, KH, bs, D]`` stays in
-HBM, and for each row the kernel walks the row's block table and fetches
-``pages_per_step`` K pages and as many V pages a step by explicit DMA into one
-of two VMEM slots, up to the row's own ``lengths[b]`` (its context and the
-block in flight, which the pass has just written) and not a page further. The
-next step's pages (of the same row or of the next row that has any) are in
-flight while this step's are attended. Online softmax in float32; rows with
-``lengths[b] == 0`` return 0.
+index are scalar-prefetch operands, the pool ``[L, NB, 2 * KH, bs, D]`` stays
+in HBM, and for each row the kernel walks the row's block table and fetches
+``pages_per_step`` pages a step by explicit DMA into one of two VMEM slots,
+up to the row's own ``lengths[b]`` (its context and the block in flight,
+which the pass has just written) and not a page further. The next step's
+pages (of the same row or of the next row that has any) are in flight while
+this step's are attended. Online softmax in float32; rows with ``lengths[b]
+== 0`` return 0.
 
-A page is ``[KH, bs, D]``: one kv head's ``bs`` keys are one contiguous
-``(bs, D)`` tile, and a step's pages land in VMEM as ``[KH, T, D]`` (``T =
-pages_per_step * bs``; the DMA puts page ``j`` at rows ``j * bs`` of every
-head). So each kv head's keys of the step are a plain matrix ``[T, D]``, and
-the head's query rows (the ``Lq`` positions of the block times the ``H / KH``
-query heads that share it, 4 x 8 = 32 at SDAR's shapes) multiply just them:
-``[32, D] x [T, D]^T``, no masked-out products and no relayout. All queries of
-a row see the same keys (within the block nothing is masked), so the only mask
-is the row's length in its last step.
+A page is ``[2 * KH, bs, D]``, the keys of kv head ``g`` at ``[g]`` and its
+values at ``[KH + g]`` (``ops/paged_layout.py``, the fused row): 32 KB in one
+stretch at SDAR's shapes, so keys AND values of a page are ONE descriptor,
+which lands in its slot as it lies in the pool (``[pages, 2 * KH, bs, D]``: a
+linear copy). What a call costs beside its products is the descriptors it
+issues, about 28 ns each on the scalar core, serial with the products (PERF.md
+section 5 has the split of a call): a full step starts and waits for its
+pages unrolled with no predicate a page, a row's last step in a loop of just
+its live pages.
+
+One kv head's ``bs`` keys of a page are one contiguous ``(bs, D)`` tile, so
+the head's keys of a step, ``slot[:, g]``, are a plain matrix ``[T, D]`` (``T
+= pages_per_step * bs``; whole tiles stacked, no relayout), and the head's
+query rows (the ``Lq`` positions of the block times the ``H / KH`` query
+heads that share it, 4 x 8 = 32 at SDAR's shapes) multiply just them: ``[32,
+D] x [T, D]^T``, no masked-out products. All queries of a row see the same
+keys (within the block nothing is masked), so the only mask is the row's
+length in its last step.
 """
 
 from __future__ import annotations
@@ -37,55 +47,67 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["block_paged_attention_pallas", "supported_shapes",
            "PAGES_PER_STEP"]
 
-# Pages of K (and of V) fetched and attended a step. At the serving cell's
-# page (4 heads x 16 tokens x 128 x bf16 = 16 KB) sixteen pages are 256
-# tokens: 2 x 2 x 256 KB of VMEM slots and four [32, 256] float32 score tiles.
-PAGES_PER_STEP = 16
+# Pages fetched and attended a step. At the serving cell's page (8 heads x 16
+# tokens x 128 x bf16 = 32 KB) thirty-two pages are 512 tokens: 2 x 1 MiB of
+# VMEM slots and four [32, 512] float32 score tiles. Measured on the chip at
+# the cell's shapes (PERF.md section 6, PR 35): 16 pages 0.66 ms a call, 32
+# 0.48, 48 0.52, 64 0.51; what a step costs beside its keys (the softmax
+# state's update, the loop) is paid half as often at 32 as at 16, and past 32
+# a row's last step attends more masked keys than that saves.
+PAGES_PER_STEP = 32
 
 _NEG = -1e30        # masked score: exp(_NEG - m) is an exact 0 for finite m
 
 
-def supported_shapes(q_dtype, k_pool) -> bool:
+def supported_shapes(q_dtype, kv_pool) -> bool:
     """Shapes the compiled kernel takes on a TPU: bf16 queries and pool
-    (``[..., NB, KH, bs, D]``), ``head_dim`` 128 (one lane tile) and
-    ``block_size`` a multiple of the bf16 sublane tile (16), so that a head's
-    keys of a page are whole tiles."""
-    bs, d = k_pool.shape[-2:]
-    return (q_dtype == jnp.bfloat16 and k_pool.dtype == jnp.bfloat16
-            and d == 128 and bs % 16 == 0)
+    (``[..., NB, 2 * KH, bs, D]``: an even number of heads a fused row),
+    ``head_dim`` 128 (one lane tile) and ``block_size`` a multiple of the
+    bf16 sublane tile (16), so that a head's keys of a page are whole
+    tiles."""
+    heads, bs, d = kv_pool.shape[-3:]
+    return (q_dtype == jnp.bfloat16 and kv_pool.dtype == jnp.bfloat16
+            and d == 128 and bs % 16 == 0 and heads % 2 == 0)
 
 
-def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, *, scale: float, pages: int, bs: int, kh: int,
-            qrows: int):
-    nrows, m_pages = tables_ref.shape
+def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, kv_hbm, o_ref, buf,
+            sems, *, scale: float, kh: int, qrows: int):
+    nrows = tables_ref.shape[0]
+    pages, _, bs, d = buf.shape[1:]
     t_step = pages * bs                 # tokens a step
     layer = layer_ref[0]
 
-    def copies(b, i, slot):
-        """The DMAs of step ``i`` of row ``b`` into ``slot``: each page of K
-        and V under the row's length, none past it."""
-        out = []
-        for j in range(pages):
-            p = i * pages + j
-            live = p * bs < lengths_ref[b]
-            page = tables_ref[b, jnp.minimum(p, m_pages - 1)]
-            dst = pl.ds(j * bs, bs)
-            out.append((live, pltpu.make_async_copy(
-                k_hbm.at[layer, page], kbuf.at[slot, :, dst, :],
-                sems.at[0, slot])))
-            out.append((live, pltpu.make_async_copy(
-                v_hbm.at[layer, page], vbuf.at[slot, :, dst, :],
-                sems.at[1, slot])))
-        return out
+    def each_page(b, i, do):
+        """``do(j)`` for every page ``j`` of step ``i`` of row ``b`` under
+        the row's length: unrolled and without a predicate a page in a full
+        step, a loop of just the live pages in a row's last."""
+        left = lengths_ref[b] - i * t_step
+        n = jnp.clip((left + bs - 1) // bs, 0, pages)
+
+        @pl.when(n == pages)
+        def _():
+            for j in range(pages):
+                do(j)
+
+        @pl.when(n < pages)
+        def _():
+            def one(j, carry):
+                do(j)
+                return carry
+            lax.fori_loop(0, n, one, 0)
 
     def start(b, i, slot):
-        for live, cp in copies(b, i, slot):
-            pl.when(live)(cp.start)
+        """Fetch step ``i`` of row ``b`` into ``slot``: one descriptor a page
+        under the row's length, none past it."""
+        each_page(b, i, lambda j: pltpu.make_async_copy(
+            kv_hbm.at[layer, tables_ref[b, i * pages + j]], buf.at[slot, j],
+            sems.at[slot]).start())
 
     def wait(b, i, slot):
-        for live, cp in copies(b, i, slot):
-            pl.when(live)(cp.wait)
+        """Wait for what :func:`start` fetched: the slot's semaphore counts
+        bytes, and each wait takes one page's off it, whichever page."""
+        each_page(b, i, lambda j: pltpu.make_async_copy(
+            kv_hbm.at[layer, 0], buf.at[slot, 0], sems.at[slot]).wait())
 
     def next_row(b):
         """The first row after ``b`` with any key (``nrows`` if none)."""
@@ -102,7 +124,10 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     tok = lax.broadcasted_iota(jnp.int32, (qrows, t_step), 1)
     tok_of_row = lax.broadcasted_iota(jnp.int32, (t_step, 1), 0)
-    d = q_ref.shape[-1]
+
+    def head(slot, h):
+        """Head ``h`` of a slot's fused rows as one matrix ``[T, D]``."""
+        return buf[slot, :, h].reshape(t_step, d)
 
     def row_body(b, slot):
         length = lengths_ref[b]
@@ -128,14 +153,15 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                 # were not fetched) hold whatever was there; 0 * NaN is NaN,
                 # so V is cleared there (scores are masked below)
                 for g in range(kh):
-                    v = vbuf[slot, g]
-                    vbuf[slot, g] = jnp.where(tok_of_row < left, v,
-                                              jnp.zeros_like(v))
+                    v = head(slot, kh + g)
+                    buf[slot, :, kh + g] = jnp.where(
+                        tok_of_row < left, v,
+                        jnp.zeros_like(v)).reshape(pages, bs, d)
 
             new = []
             for g in range(kh):
                 m, l, acc = state[g]
-                s = lax.dot_general(qs[g], kbuf[slot, g],
+                s = lax.dot_general(qs[g], head(slot, g),
                                     (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
                 s = jnp.where(tok < left, s * scale, _NEG)
@@ -144,7 +170,7 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
                 p = jnp.exp(s - m_new)
                 l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
                 acc = alpha * acc + jnp.dot(
-                    p.astype(vbuf.dtype), vbuf[slot, g],
+                    p.astype(buf.dtype), head(slot, kh + g),
                     preferred_element_type=jnp.float32)
                 new.append((m_new, l, acc))
             return tuple(new), 1 - slot
@@ -165,25 +191,24 @@ def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "pages_per_step",
-                                             "kh", "interpret"))
-def _block_call(q, k_pool, v_pool, tables, lengths, layer, *, scale,
-                pages_per_step, kh, interpret):
+                                             "interpret"))
+def _block_call(q, kv_pool, tables, lengths, layer, *, scale,
+                pages_per_step, interpret):
     b, rows, d = q.shape                # rows = KH * (Lq * H / KH)
-    bs = k_pool.shape[-2]
-    t_step = pages_per_step * bs
-    kernel = functools.partial(_kernel, scale=scale, pages=pages_per_step,
-                               bs=bs, kh=kh, qrows=rows // kh)
+    heads, bs = kv_pool.shape[-3:-1]
+    kh = heads // 2
+    kernel = functools.partial(_kernel, scale=scale, kh=kh,
+                               qrows=rows // kh)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(1,),
-            in_specs=[vmem, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[vmem, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=vmem,
-            scratch_shapes=[pltpu.VMEM((2, kh, t_step, d), k_pool.dtype),
-                            pltpu.VMEM((2, kh, t_step, d), v_pool.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2))]),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages_per_step, heads, bs, d), kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -192,23 +217,27 @@ def _block_call(q, k_pool, v_pool, tables, lengths, layer, *, scale,
             vmem_limit_bytes=48 * 2 ** 20),
         name="block_paged_attention",
         interpret=interpret,
-    )(layer, tables, lengths, q, k_pool, v_pool)
+    )(layer, tables, lengths, q, kv_pool)
 
 
-def block_paged_attention_pallas(q, k_pool, v_pool, tables, lengths, *,
-                                 layer=0, scale: Optional[float] = None,
+def block_paged_attention_pallas(q, kv_pool, tables, lengths, *, layer=0,
+                                 scale: Optional[float] = None,
                                  pages_per_step: int = PAGES_PER_STEP,
                                  interpret: bool = False):
     """``q [B, Lq, H, D]`` over the pages ``tables [B, M]`` names in
-    ``k_pool`` / ``v_pool`` (``[L, NB, KH, bs, D]``, or one layer's ``[NB,
-    KH, bs, D]``), every query of row ``b`` over the row's first
-    ``lengths[b]`` keys; returns ``[B, Lq, H, D]``. ``layer`` may be a traced
-    scalar: the unrolled layers of a program then share one traced and
-    lowered kernel."""
+    ``kv_pool`` (``[L, NB, 2 * KH, bs, D]``, or one layer's ``[NB, 2 * KH,
+    bs, D]``: keys the first ``KH`` heads of a page, values the rest), every
+    query of row ``b`` over the row's first ``lengths[b]`` keys; returns
+    ``[B, Lq, H, D]``. ``layer`` may be a traced scalar: the unrolled layers
+    of a program then share one traced and lowered kernel."""
     b, lq, h, d = q.shape
-    if k_pool.ndim == 4:
-        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
-    kh = k_pool.shape[-3]
+    if kv_pool.ndim == 4:
+        kv_pool, layer = kv_pool[None], 0
+    heads = kv_pool.shape[-3]
+    if heads % 2:
+        raise ValueError(f"a fused row holds keys and values: {heads} heads "
+                         "a page is not an even number")
+    kh = heads // 2
     if h % kh:
         raise ValueError(f"query heads ({h}) not a multiple of kv heads "
                          f"({kh})")
@@ -217,10 +246,9 @@ def block_paged_attention_pallas(q, k_pool, v_pool, tables, lengths, *,
     qr = q.reshape(b, lq, kh, g, d).transpose(0, 2, 1, 3, 4)
     pages = max(1, min(pages_per_step, tables.shape[1]))
     out = _block_call(
-        qr.reshape(b, kh * lq * g, d), k_pool, v_pool,
-        tables.astype(jnp.int32), lengths.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1),
+        qr.reshape(b, kh * lq * g, d), kv_pool, tables.astype(jnp.int32),
+        lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
         scale=float(scale if scale is not None else 1.0 / math.sqrt(d)),
-        pages_per_step=pages, kh=kh, interpret=interpret)
+        pages_per_step=pages, interpret=interpret)
     return out.reshape(b, kh, lq, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, lq, h, d)

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core import flags
-from .paged_layout import gather_pages, heads_first
+from .paged_layout import gather_pages, heads_first, split_keys_values
 
 __all__ = ["flash_attention", "flash_attn_unpadded", "reference_attention",
            "single_query_attention", "paged_single_query_attention",
@@ -131,7 +131,8 @@ def takes_paged_kernel(q_dtype, k_pool, latent_value_dim=None,
     (``ops/paged_layout.py``): a K (or V) pool with its pages tokens first,
     ``[..., NB, bs, KH, D]`` (the single-query kernel); the same heads first,
     ``[..., NB, KH, bs, D]``, which is told apart by ``block_size`` (the
-    block kernel, several queries a row); or, with ``latent_value_dim`` (the
+    block kernel, several queries a row, whose one pool holds fused rows of
+    ``2 * KH`` heads); or, with ``latent_value_dim`` (the
     part of a row that is its value), a latent pool ``[..., NB, bs, W]``. As
     ``_use_pallas``: on a TPU with the flag on and a shape the kernel takes;
     an unsupported shape ON a TPU is announced once (P005). The serving
@@ -157,8 +158,8 @@ def takes_paged_kernel(q_dtype, k_pool, latent_value_dim=None,
             return True
         report_fallback(
             "block_paged_attention", shape,
-            "needs bf16 queries and pool, head_dim 128 and block_size a "
-            "multiple of 16")
+            "needs bf16 queries and pool, an even number of heads a fused "
+            "row, head_dim 128 and block_size a multiple of 16")
         return False
     from ._pallas.paged_attention import supported_shapes
     if supported_shapes(q_dtype, k_pool):
@@ -208,7 +209,7 @@ def paged_single_query_attention(q, k_pool, v_pool, tables, lengths, *,
                                   scale=scale)
 
 
-def block_paged_attention(q, k_pool, v_pool, tables, lengths, *,
+def block_paged_attention(q, kv_pool, tables, lengths, *,
                           block_size: int, layer=0,
                           scale: Optional[float] = None):
     """A block's attention read through block tables: ``q [B, Lq, H, D]``
@@ -218,24 +219,26 @@ def block_paged_attention(q, k_pool, v_pool, tables, lengths, *,
     the pass has written: within the block nothing is masked; 0: the row
     returns 0). Returns ``[B, Lq, H, D]``.
 
-    The pool is the engine's, with ``layer`` the layer to read (a Python int
-    or a traced scalar), or one layer's; its pages are heads first
-    (``[.., NB, KH, block_size, D]``) or tokens first. On a TPU, for the
-    heads-first shapes ``_pallas.block_paged_attention.supported_shapes``
-    takes, this is the Pallas kernel: each row's pages are fetched from HBM
-    up to its own length, a kv head at a time against its ``Lq * H / KH``
-    query rows, and no gathered copy exists. Everywhere else it is the dense
-    path the kernel is checked against: gather every table's pages, then
+    The pool is the engine's ONE pool of fused rows (``ops/paged_layout.py``:
+    a token's keys and values as one row of ``2 * KH`` heads, keys first),
+    with ``layer`` the layer to read (a Python int or a traced scalar), or
+    one layer's; its pages are heads first (``[.., NB, 2 * KH, block_size,
+    D]``) or tokens first. On a TPU, for the heads-first shapes
+    ``_pallas.block_paged_attention.supported_shapes`` takes, this is the
+    Pallas kernel: each row's pages are fetched from HBM up to its own
+    length, keys and values of a page as one descriptor, a kv head at a time
+    against its ``Lq * H / KH`` query rows, and no gathered copy exists.
+    Everywhere else it is the dense path the kernel is checked against:
+    gather every table's pages, split the rows into keys and values, then
     :func:`multi_query_attention` behind the length mask."""
-    if takes_paged_kernel(q.dtype, k_pool, None, block_size):
+    if takes_paged_kernel(q.dtype, kv_pool, None, block_size):
         from ._pallas.block_paged_attention import \
             block_paged_attention_pallas
         return block_paged_attention_pallas(
-            q, k_pool, v_pool, tables, lengths, layer=layer, scale=scale)
-    if k_pool.ndim == 5:
-        k_pool, v_pool = k_pool[layer], v_pool[layer]
-    keys = gather_pages(k_pool, tables, block_size)
-    vals = gather_pages(v_pool, tables, block_size)
+            q, kv_pool, tables, lengths, layer=layer, scale=scale)
+    if kv_pool.ndim == 5:
+        kv_pool = kv_pool[layer]
+    keys, vals = split_keys_values(gather_pages(kv_pool, tables, block_size))
     pos = jnp.broadcast_to((jnp.asarray(lengths) - 1)[:, None], q.shape[:2])
     return multi_query_attention(q, keys, vals, pos, scale=scale)
 
@@ -367,7 +370,7 @@ def _dense_prob_dropout_attention(q, k, v, causal, scale, seed,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def flash_attention(query, key, value, dropout: float = 0.0,
+def flash_attention(query, key, value=None, dropout: float = 0.0,
                     causal: bool = False, return_softmax: bool = False,
                     *, scale: Optional[float] = None, training: bool = True,
                     fixed_seed_offset=None, causal_block: int = 1):
@@ -382,7 +385,13 @@ def flash_attention(query, key, value, dropout: float = 0.0,
     generation by diffusion over blocks: a query sees every key of its own
     block of ``B`` positions (counted from position 0) and all earlier ones,
     ``key <= query | (B - 1)`` for ``B`` a power of two. Forward only (no
-    dropout, no gradient)."""
+    dropout, no gradient).
+
+    ``value=None``: ``key`` is the fused cache row of a model that caches a
+    token's keys and values as one (``[B, S, 2 * KH, D]``, keys the first
+    ``KH`` heads: ``ops/paged_layout.split_keys_values``)."""
+    if value is None:
+        key, value = split_keys_values(key)
     if return_softmax:
         raise NotImplementedError("return_softmax is a debug-only GPU feature")
     if causal_block > 1:

@@ -14,7 +14,13 @@ chosen from the row's shape such that no axis is padded on the chip:
   does not fill a sublane tile while ``block_size`` does and ``D`` fills
   whole lanes (4 heads of 128 in bfloat16: stored tokens-first every head
   axis of 4 would be padded to 16, four times the bytes). The token axis is
-  then inside the tile, and one head's keys of a page are contiguous.
+  then inside the tile, and one head's keys of a page are contiguous;
+- **a fused row**, keys and values as ONE row of ``2 * KH`` heads (keys the
+  first ``KH``, values the rest; :func:`split_keys_values`): the same rule on
+  its shape, so 8 heads of 128 in bfloat16 are a heads-first page ``[2 * KH,
+  block_size, D]``. One pool then holds what two did, in the same bytes, and
+  a page's keys AND values are one contiguous stretch: one DMA descriptor
+  where two pools take two (``_pallas/block_paged_attention.py``).
 
 A pool is ``[layers, blocks, *page]``. Which layout a five-axis pool has is
 read back from its shape and the block size (the two orders differ in where
@@ -29,7 +35,7 @@ from typing import Sequence, Tuple
 import jax.numpy as jnp
 
 __all__ = ["page_shape", "heads_first", "write_blocks", "write_tokens",
-           "gather_pages"]
+           "gather_pages", "split_keys_values"]
 
 
 def page_shape(row: Sequence[int], block_size: int, dtype) -> Tuple[int, ...]:
@@ -81,3 +87,9 @@ def gather_pages(pool, tables, block_size: int):
     if pool.ndim == 4 and heads_first(pool, block_size):
         got = got.transpose(0, 1, 3, 2, 4)
     return got.reshape((b, m * block_size) + got.shape[3:])
+
+
+def split_keys_values(rows):
+    """A fused row ``[..., 2 * KH, D]`` (or gathered rows of it) as ``(keys,
+    values)``, each ``[..., KH, D]``: keys are the first half of the heads."""
+    return jnp.split(rows, 2, axis=-2)

@@ -88,9 +88,12 @@ serves by giving (``text/models/gpt.py``, ``text/models/deepseek_v2.py`` and
 ``text/models/sdar_moe.py`` do):
 
 - ``serve_cache_rows()``: the shapes of a token's page rows, one a pool:
-  keys and values a head ``((KH, D), (KH, D))``, or one latent row
-  ``((W,),)`` (:mod:`.paged_cache` builds one pool a row, its pages laid
-  out by the row's shape so that no axis is padded on the chip);
+  keys and values a head ``((KH, D), (KH, D))``, one latent row ``((W,),)``,
+  or keys and values fused into one row ``((2 * KH, D),)`` (keys the first
+  ``KH`` heads: a page's keys and values are then one stretch of one pool,
+  which a kernel fetches as one descriptor; :mod:`.paged_cache` builds one
+  pool a row, its pages laid out by the row's shape so that no axis is
+  padded on the chip);
   ``serve_dtype()``; ``serve_latent_value_dim`` (None, or where the pool is
   latent the part of a row that is its value);
 - ``serve_generation``: how it generates. ``None``: a token a row a step,
@@ -203,6 +206,11 @@ def _meters() -> types.SimpleNamespace:
         "serving.kv_tokens",
         "KV positions a decode dispatch needed (kind=needed: the rows' "
         "contexts) and was handed (kind=gathered: width x table x block)")
+    fetches = metrics.counter(
+        "serving.kv_page_fetches",
+        "page reads of a layer's decode attention: the pages under each "
+        "row's length (the whole table on the dense path) times the pools "
+        "read apart, which is the DMA descriptors a paged kernel call issues")
     pf = metrics.counter(
         "serving.prefill_tokens",
         "prompt tokens prefilled (kind=real) and the bucket lengths they "
@@ -236,6 +244,7 @@ def _meters() -> types.SimpleNamespace:
             "launches").labels(),
         kv_needed=kv.labels(kind="needed"),
         kv_gathered=kv.labels(kind="gathered"),
+        kv_fetches=fetches.labels(),
         fed_device=fed.labels(fed="device"),
         fed_host=fed.labels(fed="host"),
         fed_dropped=fed.labels(fed="dropped"),
@@ -2067,14 +2076,15 @@ class ServingEngine:
         decode program and verify are handed the whole table of every row
         of the bucket, whatever the rows' contexts; the paged decode kernel
         reads each row's pages up to its context and the token it wrote
-        (a pad row: the null page), the last page whole."""
+        (a pad row: the null page), the last page whole. Each page is read
+        once from every pool (``serving.kv_page_fetches``: keys and values
+        apart 2 a page, a latent or a fused row 1)."""
         self._m.kv_needed.inc(int(lens.sum()))
         bs = self.block_size
-        if paged:
-            self._m.kv_gathered.inc(int((lens // bs + 1).sum()) * bs)
-        else:
-            self._m.kv_gathered.inc(
-                len(lens) * self.max_blocks_per_seq * bs)
+        pages = int((lens // bs + 1).sum()) if paged \
+            else len(lens) * self.max_blocks_per_seq
+        self._m.kv_gathered.inc(pages * bs)
+        self._m.kv_fetches.inc(pages * self._n_pools)
 
     # -- speculative decoding ------------------------------------------------
 

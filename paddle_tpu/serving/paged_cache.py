@@ -7,7 +7,9 @@ is a pool of fixed-size *blocks* — ``[L, num_blocks, block_size, *row]``
 (heads first, ``[L, num_blocks, KH, block_size, D]``, for a row of few heads
 that the chip would pad: ``ops/paged_layout.py``) for each kind of row the model caches a token (keys and values a head:
 two pools of ``[KH, D]`` rows; a latent-attention model: one pool of its
-latent row, no value pool; the model's ``serve_cache_rows()`` says which) —
+latent row, no value pool; keys and values fused: one pool of ``[2 * KH, D]``
+rows, a page's keys and values contiguous; the model's
+``serve_cache_rows()`` says which) —
 and each sequence owns an ordered block list. Allocation
 is a min-id free list (deterministic: the same request schedule always
 produces the same block assignment, which the tests pin), fragmentation
@@ -178,7 +180,10 @@ class PagedKVCache:
     a head gives ``((kv_heads, head_dim), (kv_heads, head_dim))`` (what
     ``kv_heads``/``head_dim`` alone build; the pools are then also ``.k`` and
     ``.v``); a latent-attention model gives one row of its latent width,
-    ``((576,),)``, and has no second pool. Block tables, the allocator, spill
+    ``((576,),)``, and has no second pool; a model that caches keys and
+    values as one fused row gives ``((2 * kv_heads, head_dim),)`` (keys the
+    first half of the heads) and has one pool of the bytes the two would
+    take. Block tables, the allocator, spill
     and restore are the same for every spec: they move whole blocks of every
     pool together, and so does the prefix tree; none of them looks inside a
     page.

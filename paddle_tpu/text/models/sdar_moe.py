@@ -163,9 +163,10 @@ class SdarMoeAttention(nn.Layer):
         k = rope_halves(self.k_norm(k), pos, cfg.rope_theta)
         return q, k, v
 
-    def attend_plain(self, q, k, v):
+    def attend_plain(self, q, k, v=None):
         """Block-causal self-attention of whole blocks (a prompt's clean
-        blocks, or a full sequence)."""
+        blocks, or a full sequence); ``k`` alone is the fused cache row,
+        which flash splits."""
         return flash_attention(q, k, v, causal=True,
                                causal_block=self.cfg.block_length,
                                training=False)
@@ -232,8 +233,10 @@ class SdarMoeDecoderLayer(nn.Layer):
     # -- the serving engine's layer step -----------------------------------
 
     def serve_project(self, x, pos):
+        """The queries and the token's ONE cache row: keys the first ``KH``
+        heads, values the rest (``ops/paged_layout.py``, the fused row)."""
         q, k, v = self.self_attn.project(self.input_layernorm(x), pos)
-        return q, (k, v)
+        return q, (jnp.concatenate([k, v], axis=2),)
 
     def serve_attend_prefill(self, q, rows):
         return self.self_attn.attend_plain(q, *rows)
@@ -311,8 +314,9 @@ class SdarMoeForCausalLM(nn.Layer):
         return self.cfg.held[1]
 
     def serve_cache_rows(self):
-        row = (self.cfg.num_key_value_heads, self.cfg.head_dim)
-        return (row, row)
+        """One fused row a token, so one pool: a page's keys and values are
+        contiguous and the block kernel fetches them as one descriptor."""
+        return ((2 * self.cfg.num_key_value_heads, self.cfg.head_dim),)
 
     def serve_dtype(self):
         return self.model.embed_tokens.weight.dtype

@@ -467,30 +467,32 @@ def test_latent_serving_prefill_program_compiles(one_chip, monkeypatch):
 # The block-diffusion serving cell's shapes (benchmark/traffic/
 # reason1k-closed.json on sdar-30b-a3b-ep8-l16): 128 rows of a block of 4,
 # tables of 128 pages of 16 tokens, 32 query heads over 4 kv heads of 128,
-# bf16, a pool of 10,240 pages in 16 layers stored heads first (2 x 2.5 GiB).
+# bf16, ONE pool of 10,240 pages in 16 layers of fused keys-and-values rows
+# stored heads first (5.0 GiB, what the two pools it replaced took).
 CELL_BLOCK = dict(b=128, lq=4, m=128, bs=16, h=32, kh=4, d=128, layers=16,
                   nb=10240)
 
 
 def test_block_paged_kernel_compiles(one_chip):
     """The block paged attention kernel alone, at the cell's shapes: the
-    whole pool handed over in HBM, heads first, and stored without padding
-    (32 KB a token over the 16 layers)."""
+    whole pool of fused rows handed over in HBM, heads first, and stored
+    without padding (32 KB a token over the 16 layers, a page 32 KB in one
+    stretch)."""
     from paddle_tpu.ops._pallas.block_paged_attention import (
         block_paged_attention_pallas, supported_shapes)
     from paddle_tpu.ops.paged_layout import page_shape
     c = CELL_BLOCK
-    page = page_shape((c["kh"], c["d"]), c["bs"], jnp.bfloat16)
-    assert page == (c["kh"], c["bs"], c["d"])
+    page = page_shape((2 * c["kh"], c["d"]), c["bs"], jnp.bfloat16)
+    assert page == (2 * c["kh"], c["bs"], c["d"])
     pool = ((c["layers"], c["nb"]) + page, jnp.bfloat16)
     assert supported_shapes(jnp.bfloat16, jax.ShapeDtypeStruct(*pool))
 
-    def fn(q, k, v, tables, lengths, layer):
-        return block_paged_attention_pallas(q, k, v, tables, lengths,
+    def fn(q, kv, tables, lengths, layer):
+        return block_paged_attention_pallas(q, kv, tables, lengths,
                                             layer=layer)
 
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
-        ((c["b"], c["lq"], c["h"], c["d"]), jnp.bfloat16), pool, pool,
+        ((c["b"], c["lq"], c["h"], c["d"]), jnp.bfloat16), pool,
         ((c["b"], c["m"]), jnp.int32), ((c["b"],), jnp.int32),
         ((), jnp.int32))]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -498,9 +500,9 @@ def test_block_paged_kernel_compiles(one_chip):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "block_paged_attention" in text
     mem = compiled.memory_analysis()
-    stored = 2 * c["layers"] * c["nb"] * c["bs"] * c["kh"] * c["d"] * 2
+    stored = c["layers"] * c["nb"] * c["bs"] * 2 * c["kh"] * c["d"] * 2
     assert stored == c["nb"] * c["bs"] * 32 * 1024
-    # the arguments are the two pools as counted (no padded axis) and the
+    # the arguments are the one pool as counted (no padded axis) and the
     # small operands; only the queries' regrouping is a temporary
     assert stored < mem.argument_size_in_bytes < stored + 16 * 2 ** 20
     assert mem.temp_size_in_bytes < 16 * 2 ** 20
@@ -510,9 +512,9 @@ def test_block_decode_program_compiles(one_chip, monkeypatch):
     """The engine's block-decode program as the chip runs it (the entry
     point picks the kernel from the platform, which is the CPU here, so the
     test steers that one question): two layers at SDAR's widths with a
-    quarter of its vocabulary, one custom call a layer sharing one lowered
-    function, no gathered copy of the pool among the temporaries, the pools
-    aliased in place."""
+    quarter of its vocabulary, ONE pool argument, one custom call a layer
+    sharing one lowered function, no gathered copy of the pool among the
+    temporaries, the pool aliased in place."""
     import importlib
     import paddle_tpu as paddle
     from paddle_tpu.serving import ServingEngine
@@ -530,13 +532,14 @@ def test_block_decode_program_compiles(one_chip, monkeypatch):
                         max_batch=c["b"], max_seq_len=c["m"] * c["bs"],
                         prefill_buckets=[256], decode_buckets=[c["b"]])
     assert eng._decode_paged
-    assert eng.cache.k.shape[2:] == (c["kh"], c["bs"], c["d"])
+    (pool,) = eng.cache.pools
+    assert pool.shape[2:] == (2 * c["kh"], c["bs"], c["d"])
 
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
     params = jax.tree_util.tree_map(on_chip, eng._decode_fn.params)
-    pages = jax.ShapeDtypeStruct((2, c["nb"]) + eng.cache.k.shape[2:],
+    pages = jax.ShapeDtypeStruct((2, c["nb"]) + pool.shape[2:],
                                  jnp.bfloat16, sharding=one_chip)
     tail = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
             for s in eng._decode_tail_spec(c["b"])]
@@ -544,7 +547,7 @@ def test_block_decode_program_compiles(one_chip, monkeypatch):
     lowered = eng._decode_fn.jitted.lower(
         params, jax.ShapeDtypeStruct(head.shape, head.dtype,
                                      sharding=one_chip),
-        pages, pages, *tail)
+        pages, *tail)
     mlir = lowered.as_text()
     assert mlir.count("func.func private @_block_call") == 1
     assert mlir.count("call @_block_call") == 2
@@ -552,9 +555,10 @@ def test_block_decode_program_compiles(one_chip, monkeypatch):
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 2
     mem = compiled.memory_analysis()
-    pool_bytes = 2 * c["nb"] * c["bs"] * c["kh"] * c["d"] * 2
-    # both pools are updated in place, and nothing the size of a layer's
-    # pool (168 MB) is gathered: the temporaries are the pass's activations
-    # and its float32 logits (512 x 37,984 x 4 B = 78 MB)
-    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    pool_bytes = 2 * c["nb"] * c["bs"] * 2 * c["kh"] * c["d"] * 2
+    assert pool_bytes == 2 * c["nb"] * c["bs"] * 2 * 1024   # 2 layers' share
+    # the pool is updated in place, and nothing the size of a layer's pages
+    # (336 MB) is gathered: the temporaries are the pass's activations and
+    # its float32 logits (512 x 37,984 x 4 B = 78 MB)
+    assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 512 * 2 ** 20

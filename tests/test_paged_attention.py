@@ -340,25 +340,31 @@ def test_roofline_reader_reads_nothing_in_a_trace_of_the_gather_program(
     assert read(ctx) is None
 
 
-# -- the block kernel: several queries a row over a heads-first pool --------
+# -- the block kernel: several queries a row over ONE heads-first pool of
+# -- fused rows (a token's keys the first KH heads of its row, values the rest)
 
 from paddle_tpu.ops._pallas import block_paged_attention as BPA  # noqa: E402
 from paddle_tpu.ops.flash_attention import (  # noqa: E402
-    block_paged_attention, multi_query_attention)
+    block_paged_attention, flash_attention, multi_query_attention,
+    reference_attention)
 from paddle_tpu.ops.paged_layout import (  # noqa: E402
-    gather_pages, heads_first, page_shape, write_blocks, write_tokens)
+    gather_pages, heads_first, page_shape, split_keys_values, write_blocks,
+    write_tokens)
 from paddle_tpu.serving.paged_cache import PagedKVCache  # noqa: E402
+from paddle_tpu.text.models.sdar_moe import (  # noqa: E402
+    SdarMoeForCausalLM, sdar_moe_tiny)
 
 block_kernel = functools.partial(BPA.block_paged_attention_pallas,
                                  interpret=True)
 
 
 def _block_case(lengths, kh, heads, lq, dtype, seed=0):
+    """Queries, the one pool of fused rows (pages heads first) and tables:
+    each row's pages drawn scattered from the pool, the tail ``NULL_BLOCK``."""
     rng = np.random.default_rng(seed)
     b = len(lengths)
     q = jnp.asarray(rng.standard_normal((b, lq, heads, D)), dtype)
-    k = jnp.asarray(rng.standard_normal((L, NB, kh, BS, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((L, NB, kh, BS, D)), dtype)
+    kv = jnp.asarray(rng.standard_normal((L, NB, 2 * kh, BS, D)), dtype)
     tables = np.full((b, M), NULL_BLOCK, np.int32)
     free = rng.permutation(np.arange(1, NB))
     at = 0
@@ -366,7 +372,13 @@ def _block_case(lengths, kh, heads, lq, dtype, seed=0):
         pages = -(-n // BS)
         tables[i, :pages] = free[at:at + pages]
         at += pages
-    return q, k, v, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    return q, kv, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+
+
+def _block_dense(q, kv, tables, lengths, layer):
+    keys, vals = split_keys_values(gather_pages(kv[layer], tables, BS))
+    pos = jnp.broadcast_to((lengths - 1)[:, None], q.shape[:2])
+    return multi_query_attention(q, keys, vals, pos)
 
 
 @pytest.mark.parametrize("pages", [1, 2, 5])
@@ -376,62 +388,125 @@ def test_block_kernel_equals_dense_attention_over_ragged_contexts(
     """Rows of unequal contexts (a pad row, one block, page edges, the whole
     table): every query of a row over the row's first ``lengths[b]`` keys,
     against gather-and-dense behind the length mask, in either layer."""
-    q, k, v, tables, lengths = _block_case(LENGTHS[:1] + [4] + LENGTHS[2:],
-                                           kh, heads, lq, jnp.bfloat16)
+    q, kv, tables, lengths = _block_case(LENGTHS[:1] + [4] + LENGTHS[2:],
+                                         kh, heads, lq, jnp.bfloat16)
     for layer in (0, 1):
-        got = block_kernel(q, k, v, tables, lengths, layer=layer,
+        got = block_kernel(q, kv, tables, lengths, layer=layer,
                            pages_per_step=pages)
-        keys = gather_pages(k[layer], tables, BS)
-        vals = gather_pages(v[layer], tables, BS)
-        pos = jnp.broadcast_to((lengths - 1)[:, None], q.shape[:2])
-        want = multi_query_attention(q, keys, vals, pos)
+        want = _block_dense(q, kv, tables, lengths, layer)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    atol=2e-2, rtol=2e-2)
         assert not np.asarray(got, np.float32)[0].any()     # the pad row
         # and off the chip the entry point is that dense path
         np.testing.assert_array_equal(
-            np.asarray(block_paged_attention(q, k, v, tables, lengths,
+            np.asarray(block_paged_attention(q, kv, tables, lengths,
                                              block_size=BS, layer=layer),
                        np.float32), np.asarray(want, np.float32))
 
 
+@pytest.mark.parametrize("pages", [1, 2, 5])
+def test_block_kernel_reads_nothing_past_a_rows_pages(pages):
+    """A row that ends inside a page and a row of length 0 beside full rows:
+    every page no row's length reaches holds NaN, in its keys half and its
+    values half (the null page too, and the unused tokens of a row's last
+    page), and nothing of it arrives in the output: a page past a row's
+    length is not fetched, and what a fetched page holds past the length is
+    masked out of scores and values."""
+    lengths = [FULL, 0, BS + 5, 2 * BS, 3]
+    q, kv, tables, lens = _block_case(lengths, 2, 8, 4, jnp.bfloat16, seed=3)
+    clean = np.asarray(kv, np.float32)
+    live = np.zeros((NB, BS), bool)
+    for row, n in zip(np.asarray(tables), lengths):
+        for p in range(-(-n // BS)):
+            live[row[p], :min(BS, n - p * BS)] = True
+    assert not live[NULL_BLOCK].any() and live.sum() == sum(lengths)
+    planted = np.where(live[None, :, None, :, None], clean, np.nan)
+    for half in planted[:, :, :2], planted[:, :, 2:]:   # keys, values
+        assert np.isnan(half).any()
+    planted = jnp.asarray(planted, jnp.bfloat16)
+    for layer in (0, 1):
+        got = np.asarray(block_kernel(q, planted, tables, lens, layer=layer,
+                                      pages_per_step=pages), np.float32)
+        assert np.isfinite(got).all()
+        want = block_kernel(q, kv, tables, lens, layer=layer,
+                            pages_per_step=pages)
+        np.testing.assert_array_equal(got, np.asarray(want, np.float32))
+        assert not got[1].any()                             # the empty row
+
+
 def test_block_kernel_takes_the_cells_shapes_and_refuses_others():
-    pool = jnp.zeros((1, 2, 4, 16, 128), jnp.bfloat16)
+    pool = jnp.zeros((1, 2, 8, 16, 128), jnp.bfloat16)
     assert BPA.supported_shapes(jnp.bfloat16, pool)
     assert not BPA.supported_shapes(jnp.float32, pool)
     assert not BPA.supported_shapes(jnp.bfloat16,
-                                    jnp.zeros((1, 2, 4, 8, 128),
+                                    jnp.zeros((1, 2, 8, 8, 128),
                                               jnp.bfloat16))
     assert not BPA.supported_shapes(jnp.bfloat16,
-                                    jnp.zeros((1, 2, 4, 16, 64),
+                                    jnp.zeros((1, 2, 8, 16, 64),
+                                              jnp.bfloat16))
+    # a fused row has keys and values: an even number of heads
+    assert not BPA.supported_shapes(jnp.bfloat16,
+                                    jnp.zeros((1, 2, 7, 16, 128),
                                               jnp.bfloat16))
     assert not FA.takes_paged_kernel(jnp.bfloat16, pool, None, 16)  # the CPU
+    with pytest.raises(ValueError, match="keys and values"):
+        block_kernel(jnp.zeros((1, 4, 6, 128), jnp.bfloat16),
+                     jnp.zeros((1, 2, 3, 16, 128), jnp.bfloat16),
+                     jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32))
+
+
+def test_flash_splits_a_fused_row():
+    """``flash_attention(q, kv)`` with no values is the attention over the
+    fused row's halves: what a model that caches one row a token hands its
+    prefill."""
+    rng = np.random.default_rng(1)
+    q = jnp.asarray(rng.standard_normal((2, 8, 4, 16)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 8, 2, 16)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 8, 2, 16)), jnp.float32)
+    kv = jnp.concatenate([k, v], axis=2)
+    for got in split_keys_values(kv), split_keys_values(kv[0]):
+        assert got[0].shape[-2:] == (2, 16)
+    np.testing.assert_array_equal(np.asarray(split_keys_values(kv)[1]),
+                                  np.asarray(v))
+    for block in (1, 4):
+        np.testing.assert_array_equal(
+            np.asarray(flash_attention(q, kv, causal=True, training=False,
+                                       causal_block=block)),
+            np.asarray(reference_attention(q, k, v, True,
+                                           causal_block=block)))
 
 
 def test_the_pools_layout_follows_the_rows_shape():
     """Rows of (4, 128) in bfloat16 are stored heads first (tokens first the
-    head axis of 4 would be padded to the tile's 16 on the chip); rows of
-    (16, 128) and the latent row keep tokens first, their bytes unchanged;
-    shapes that fill no tile either way (the tests' small heads) keep it
-    too. Spill and restore move whole pages and follow."""
+    head axis of 4 would be padded to the tile's 16 on the chip), and so is
+    the fused keys-and-values row (8, 128); rows of (16, 128) and the latent
+    row keep tokens first, their bytes unchanged; shapes that fill no tile
+    either way (the tests' small heads) keep it too. Spill and restore move
+    whole pages and follow."""
     bf = jnp.bfloat16
     assert page_shape((4, 128), 16, bf) == (4, 16, 128)
+    assert page_shape((8, 128), 16, bf) == (8, 16, 128)
     assert page_shape((16, 128), 16, bf) == (16, 16, 128)
     assert page_shape((640,), 16, bf) == (16, 640)
     assert page_shape((2, 128), 8, jnp.float32) == (2, 8, 128)
     assert page_shape((4, 32), 8, jnp.float32) == (8, 4, 32)
     assert page_shape((2, 8), 4, jnp.float32) == (4, 2, 8)
     few = PagedKVCache(2, 6, 16, dtype=bf, rows=((4, 128), (4, 128)))
+    one = PagedKVCache(2, 6, 16, dtype=bf, rows=((8, 128),))
     gpt = PagedKVCache(2, 6, 16, dtype=bf, rows=((16, 128), (16, 128)))
     mla = PagedKVCache(2, 6, 16, dtype=bf, rows=((640,),))
     assert few.k.shape == (2, 6, 4, 16, 128) and heads_first(few.k, 16)
+    assert [p.shape for p in one.pools] == [(2, 6, 8, 16, 128)] \
+        and heads_first(one.pools[0], 16)
     assert gpt.k.shape == (2, 6, 16, 16, 128) and not heads_first(gpt.k, 16)
     assert mla.pools[0].shape == (2, 6, 16, 640)
-    for cache, per_token in ((few, 2 * 4 * 128), (gpt, 2 * 16 * 128),
-                             (mla, 640)):
+    for cache, per_token in ((few, 2 * 4 * 128), (one, 8 * 128),
+                             (gpt, 2 * 16 * 128), (mla, 640)):
         assert cache.bytes_per_block == 2 * 16 * per_token * 2
         assert sum(p.nbytes for p in cache.pools) == 6 * cache.bytes_per_block
+    # the one pool of fused rows takes the bytes of the two it replaces
+    assert one.pools[0].nbytes == few.k.nbytes + few.v.nbytes
     # writes and reads agree, whatever the layout
     rng = np.random.default_rng(0)
     rows = jnp.asarray(rng.standard_normal((32, 4, 128)), bf)
@@ -440,15 +515,27 @@ def test_the_pools_layout_follows_the_rows_shape():
     got = gather_pages(pool[1], ids[None], 16)[0]
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(rows, np.float32))
-    one = jnp.asarray(rng.standard_normal((2, 3, 4, 128)), bf)
+    tok = jnp.asarray(rng.standard_normal((2, 3, 4, 128)), bf)
     bi = jnp.asarray([[3, 3, 3], [5, 5, 5]], jnp.int32)
     si = jnp.asarray([[4, 5, 6], [0, 1, 15]], jnp.int32)
-    pool = write_tokens(pool, 1, bi, si, one, 16)
+    pool = write_tokens(pool, 1, bi, si, tok, 16)
     got = np.asarray(gather_pages(pool[1], ids[None], 16)[0], np.float32)
     np.testing.assert_array_equal(got[[4, 5, 6]],
-                                  np.asarray(one[0], np.float32))
+                                  np.asarray(tok[0], np.float32))
     np.testing.assert_array_equal(got[[16, 17, 31]],
-                                  np.asarray(one[1], np.float32))
+                                  np.asarray(tok[1], np.float32))
+    # a fused row written whole or a token at a time reads back as its halves
+    fused = jnp.asarray(rng.standard_normal((32, 8, 128)), bf)
+    kv = write_blocks(one.pools[0], 0, ids, fused, 16)
+    kv = write_tokens(kv, 0, bi, si, jnp.concatenate([tok, tok], axis=2), 16)
+    keys, vals = split_keys_values(gather_pages(kv[0], ids[None], 16)[0])
+    np.testing.assert_array_equal(np.asarray(keys[7], np.float32),
+                                  np.asarray(fused[7, :4], np.float32))
+    np.testing.assert_array_equal(np.asarray(vals[7], np.float32),
+                                  np.asarray(fused[7, 4:], np.float32))
+    for half in keys, vals:
+        np.testing.assert_array_equal(np.asarray(half[31], np.float32),
+                                      np.asarray(tok[1, 2], np.float32))
     # a spill and a restore into other pages keep every byte
     few.pools = (pool, few.v)
     blocks = few.allocator.alloc(2)
@@ -460,3 +547,94 @@ def test_the_pools_layout_follows_the_rows_shape():
     few.restore(host, [4])
     np.testing.assert_array_equal(np.asarray(few.k[:, 4], np.float32), page3)
     few.allocator.free(held)
+
+
+# -- SDAR's engine over the one pool -----------------------------------------
+
+def _sdar_model():
+    paddle.seed(11)
+    m = SdarMoeForCausalLM(sdar_moe_tiny())
+    m.eval()
+    return m
+
+
+def _sdar_serve(model):
+    rng = np.random.default_rng(2)
+    reqs = [Request(rid=f"s{i}", max_new_tokens=n,
+                    prompt_ids=rng.integers(0, 500, p))
+            for i, (p, n) in enumerate([(5, 6), (9, 9), (17, 5), (3, 11)])]
+    eng = ServingEngine(model, block_size=8, num_blocks=24, max_batch=2,
+                        max_seq_len=64, prefill_buckets=[8, 16, 32])
+    fetches = metrics.counter("serving.kv_page_fetches").labels()
+    gathered = metrics.counter("serving.kv_tokens").labels(kind="gathered")
+    before = fetches.get(), gathered.get()
+    done = eng.serve(reqs)
+    return eng, done, (fetches.get() - before[0], gathered.get() - before[1])
+
+
+def test_sdars_engine_builds_one_pool_of_the_bytes_of_two():
+    """What the model says it caches decides the pools: ONE fused row of ``2
+    x KH`` heads a token, so one pool ``[L, NB, 2 * KH, bs, D]`` whose bytes
+    are those of the keys pool and the values pool it replaces."""
+    model = _sdar_model()
+    cfg = model.cfg
+    kh, d = cfg.num_key_value_heads, cfg.head_dim
+    assert model.serve_cache_rows() == ((2 * kh, d),)
+    eng = ServingEngine(model, block_size=8, num_blocks=24, max_batch=2,
+                        max_seq_len=64, prefill_buckets=[8, 16])
+    assert eng._n_pools == 1
+    (pool,) = eng.cache.pools
+    assert pool.shape == (cfg.num_hidden_layers, 24, 2 * kh, 8, d)
+    two = PagedKVCache(cfg.num_hidden_layers, 24, 8, dtype=pool.dtype,
+                       rows=((kh, d), (kh, d)))
+    assert pool.nbytes == two.k.nbytes + two.v.nbytes
+    assert eng.cache.bytes_per_block == two.bytes_per_block
+    # a layer hands the engine the one row, keys first
+    layer = model.serve_layers()[0]
+    x = jnp.ones((1, 4, cfg.hidden_size), jnp.float32)
+    pos = jnp.arange(4)[None]
+    q, rows = layer.serve_project(x, pos)
+    assert len(rows) == 1 and rows[0].shape == (1, 4, 2 * kh, d)
+    _, k, v = layer.self_attn.project(layer.input_layernorm(x), pos)
+    keys, vals = split_keys_values(rows[0])
+    np.testing.assert_array_equal(np.asarray(keys), np.asarray(k))
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(v))
+
+
+def test_sdars_engine_tokens_equal_with_the_block_kernel(monkeypatch):
+    """A whole ``ServingEngine`` run of a block-diffusion model (four requests
+    through two rows, so rows are refilled and blocks straddle pages) with
+    the entry point steered to the interpreted block kernel serves the dense
+    path's tokens, and ``serving.kv_page_fetches`` counts ONE fetch a page the
+    kernel reads: the pages ``serving.kv_tokens{kind=gathered}`` counts."""
+    model = _sdar_model()
+    dense_eng, dense, (dense_fetches, dense_tokens) = _sdar_serve(model)
+    assert not dense_eng._decode_paged
+    # the dense pass is handed every row's whole table, once (one pool)
+    assert dense_fetches * 8 == dense_tokens > 0
+
+    engine_mod = importlib.import_module("paddle_tpu.serving.engine")
+    calls = []
+
+    def interpreted(*a, **kw):
+        calls.append(1)
+        return block_kernel(*a, **kw)
+
+    monkeypatch.setattr(FA, "takes_paged_kernel", lambda *a: True)
+    monkeypatch.setattr(engine_mod, "takes_paged_kernel", lambda *a: True)
+    monkeypatch.setattr(BPA, "block_paged_attention_pallas", interpreted)
+    paged_eng, paged, (fetches, tokens) = _sdar_serve(model)
+    assert paged_eng._decode_paged
+    assert calls and len(calls) % model.cfg.num_hidden_layers == 0
+    assert set(paged) == set(dense) and len(paged) == 4
+    for rid in dense:
+        np.testing.assert_array_equal(paged[rid].output, dense[rid].output)
+    assert fetches * 8 == tokens and 0 < fetches < dense_fetches
+
+
+def test_page_fetches_count_two_a_page_for_keys_and_values_apart():
+    """GPT caches keys and values in two pools: 2 fetches a page read."""
+    fetches = metrics.counter("serving.kv_page_fetches").labels()
+    before = fetches.get()
+    _, _, counted = _serve(_micro_model())
+    assert (fetches.get() - before) * 4 == 2 * counted["gathered"] > 0

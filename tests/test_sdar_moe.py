@@ -33,7 +33,8 @@ from benchmark.lib import correct  # noqa: E402
 from benchmark.lib import weights as LW  # noqa: E402
 from benchmark.lib.family import load_family  # noqa: E402
 from paddle_tpu.observability import metrics  # noqa: E402
-from paddle_tpu.ops.paged_layout import gather_pages  # noqa: E402
+from paddle_tpu.ops.paged_layout import (  # noqa: E402
+    gather_pages, split_keys_values)
 from paddle_tpu.serving import Request, ServingEngine  # noqa: E402
 from paddle_tpu.serving.paged_cache import NULL_BLOCK  # noqa: E402
 
@@ -144,7 +145,7 @@ def test_every_denoise_state_through_the_paged_cache(built, n_prompt):
     end = n_prompt + len(answer)
     eng = engine(model)
     pools = eng.cache.pools
-    assert pools[0].shape == (2, 64, 2, BS, 128)      # heads first
+    assert [p.shape for p in pools] == [(2, 64, 4, BS, 128)]  # one, fused
     blocks = eng.cache.allocator.alloc(-(-(end + B) // BS))
     table = np.full((4, eng.max_blocks_per_seq), NULL_BLOCK, np.int32)
     table[0, :len(blocks)] = blocks
@@ -203,9 +204,10 @@ def test_every_denoise_state_through_the_paged_cache(built, n_prompt):
     _, kv = ref.forward(w, clean, keep_kv=True)
     tab = jnp.asarray(table[:1])
     for li, (k, v) in enumerate(kv):
-        for pool, want in zip(pools, (k, v)):
-            got = gather_pages(pool[li], tab, BS)[0, :len(clean)]
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+        halves = split_keys_values(gather_pages(pools[0][li], tab, BS)[0])
+        for got, want in zip(halves, (k, v)):
+            np.testing.assert_allclose(np.asarray(got[:len(clean)]),
+                                       np.asarray(want),
                                        atol=2e-5, rtol=1e-4)
 
 
@@ -326,7 +328,7 @@ def test_the_model_answers_the_engines_question():
     gen = model.serve_generation
     assert (gen.block_length, gen.steps, gen.threshold, gen.mask_id) == \
         (4, 4, 0.9, 511)
-    assert model.serve_cache_rows() == ((2, 128), (2, 128))
+    assert model.serve_cache_rows() == ((4, 128),)       # keys | values
     assert model.serve_counts == 4 and model.serve_latent_value_dim is None
     with pytest.raises(ValueError, match="diffusion over blocks"):
         engine(model, prefix_cache=True)

@@ -1476,9 +1476,10 @@ class TestBlockDiffusion:
         eng = self._engine(block_model())
         assert eng._gen.block_length == 4
         assert eng._decode_head_spec(3).shape == (3, 4)
-        # the pool of few kv heads is stored heads first, GPT's as ever
-        assert eng.cache.k.shape == (2, 40, 2, 8, 128)
-        assert gpt.cache.k.shape == (2, 17, 4, 4, 12)
+        # the ONE pool of fused rows (keys | values: 2 x 2 heads) is stored
+        # heads first, GPT's two pools as ever
+        assert [p.shape for p in eng.cache.pools] == [(2, 40, 4, 8, 128)]
+        assert [p.shape for p in gpt.cache.pools] == [(2, 17, 4, 4, 12)] * 2
 
     def test_rows_join_and_leave_mid_block_and_tokens_match_a_free_run(self):
         """Seven requests through three rows: a row that ends frees its slot
