@@ -44,17 +44,24 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["block_paged_attention_pallas", "supported_shapes",
-           "PAGES_PER_STEP"]
+__all__ = ["block_paged_attention_pallas", "supported_shapes", "pages_for",
+           "SLOT_BYTES"]
 
-# Pages fetched and attended a step. At the serving cell's page (8 heads x 16
-# tokens x 128 x bf16 = 32 KB) thirty-two pages are 512 tokens: 2 x 1 MiB of
-# VMEM slots and four [32, 512] float32 score tiles. Measured on the chip at
-# the cell's shapes (PERF.md section 6, PR 35): 16 pages 0.66 ms a call, 32
-# 0.48, 48 0.52, 64 0.51; what a step costs beside its keys (the softmax
-# state's update, the loop) is paid half as often at 32 as at 16, and past 32
-# a row's last step attends more masked keys than that saves.
-PAGES_PER_STEP = 32
+# Bytes of pages fetched and attended a step (one of the two VMEM slots). At
+# SDAR's page (8 heads x 16 tokens x 128 x bf16 = 32 KB) that is thirty-two
+# pages, 512 tokens, and four [32, 512] float32 score tiles. Measured on the
+# chip at that cell's shapes (PERF.md section 6): 16 pages 0.66 ms a
+# call, 32 0.48, 48 0.52, 64 0.51; what a step costs beside its keys (the
+# softmax state's update, the loop) is paid half as often at 32 as at 16, and
+# past 32 a row's last step attends more masked keys than that saves. At
+# Olmo-Hybrid's page (60 heads: 240 KB) the same slot is four pages.
+SLOT_BYTES = 2 ** 20
+
+
+def pages_for(page_bytes: int) -> int:
+    """Pages a step: as many as fill a slot of ``SLOT_BYTES``, at least one
+    (32 at 32 KB, 4 at 240 KB)."""
+    return max(1, SLOT_BYTES // int(page_bytes))
 
 _NEG = -1e30        # masked score: exp(_NEG - m) is an exact 0 for finite m
 
@@ -222,14 +229,15 @@ def _block_call(q, kv_pool, tables, lengths, layer, *, scale,
 
 def block_paged_attention_pallas(q, kv_pool, tables, lengths, *, layer=0,
                                  scale: Optional[float] = None,
-                                 pages_per_step: int = PAGES_PER_STEP,
+                                 pages_per_step: Optional[int] = None,
                                  interpret: bool = False):
     """``q [B, Lq, H, D]`` over the pages ``tables [B, M]`` names in
     ``kv_pool`` (``[L, NB, 2 * KH, bs, D]``, or one layer's ``[NB, 2 * KH,
     bs, D]``: keys the first ``KH`` heads of a page, values the rest), every
     query of row ``b`` over the row's first ``lengths[b]`` keys; returns
     ``[B, Lq, H, D]``. ``layer`` may be a traced scalar: the unrolled layers
-    of a program then share one traced and lowered kernel."""
+    of a program then share one traced and lowered kernel. ``pages_per_step``
+    None: from a page's bytes (:func:`pages_for`)."""
     b, lq, h, d = q.shape
     if kv_pool.ndim == 4:
         kv_pool, layer = kv_pool[None], 0
@@ -244,6 +252,9 @@ def block_paged_attention_pallas(q, kv_pool, tables, lengths, *, layer=0,
     g = h // kh
     # a kv head's query rows together: [B, KH, Lq * G, D]
     qr = q.reshape(b, lq, kh, g, d).transpose(0, 2, 1, 3, 4)
+    if pages_per_step is None:
+        pages_per_step = pages_for(math.prod(kv_pool.shape[-3:])
+                                   * kv_pool.dtype.itemsize)
     pages = max(1, min(pages_per_step, tables.shape[1]))
     out = _block_call(
         qr.reshape(b, kh * lq * g, d), kv_pool, tables.astype(jnp.int32),

@@ -84,8 +84,8 @@ one step after its first.
 
 **The model seam.** The programs know no model: they ask the model for its
 layer step, for what it caches a token and for how it generates. A model
-serves by giving (``text/models/gpt.py``, ``text/models/deepseek_v2.py`` and
-``text/models/sdar_moe.py`` do):
+serves by giving (``text/models/gpt.py``, ``text/models/deepseek_v2.py``,
+``text/models/sdar_moe.py`` and ``text/models/olmo_hybrid.py`` do):
 
 - ``serve_cache_rows()``: the shapes of a token's page rows, one a pool:
   keys and values a head ``((KH, D), (KH, D))``, one latent row ``((W,),)``,
@@ -117,13 +117,43 @@ serves by giving (``text/models/gpt.py``, ``text/models/deepseek_v2.py`` and
   token, in the one array the host already waits for, and the engine hands
   them to ``model.serve_record_counts(counts, n_real_tokens, n_slots)``
   (the expert counters of a routed-expert model; ``n_slots`` is the
-  program's static token count, its bucket).
+  program's static token count, its bucket);
+- **a layer that keeps a state a sequence** (``text/models/olmo_hybrid.py``'s
+  linear layers) says ``serve_keeps = "state"`` and gives, in place of the
+  four steps above, ``serve_prefill_state(x, n_tokens) -> (x, parts)`` (the
+  prompt's first ``n_tokens`` positions real; ``parts`` what the layer keeps
+  after them, one array a part with a leading axis of 1) and
+  ``serve_decode_state(x, pools, slots, layer) -> (x, pools)`` (one token a
+  row over the slot pools, each row's state advanced in its slot ``slots[b]``
+  of ``layer``, the layer's place among the state layers; slot 0 is a pad
+  row's). The model then gives ``serve_state()``, the shapes and dtypes of
+  the parts (:mod:`.paged_cache`: a slot pool a part over the state layers,
+  the page pools over the other layers only, each kind indexed by a layer's
+  place among its own). The prefill program writes a sequence's state into
+  its slot (overwriting it), the decode program takes the slot pools donated
+  behind the page pools and the rows' slots last. A sequence is granted a
+  slot at admission and gives it back where it gives back its blocks; a
+  preemption spills the state with the pages and restores it into a fresh
+  slot. Prefix sharing, chunked prefill and speculation are refused for such
+  a model (each would need the state at a point no page holds: ROADMAP).
 
 The engine writes the rows into the pools, keeps the block tables, and
 calls no model by name; scheduler, allocator, spill, spans and counters are
-the same for every model. Decoding is greedy: the argmax of a row's logits,
-matching ``model.generate``'s default, or for a block model the argmax at
-the positions the unmask rule chooses.
+the same for every model. A model without state layers has the programs it
+had before any model had them: the same arguments, the same donations.
+Decoding is greedy: the argmax of a row's logits, matching
+``model.generate``'s default, or for a block model the argmax at the
+positions the unmask rule chooses.
+
+**The launch in flight and a state.** A row's token in flight (above) was
+computed by a program that also advanced the row's state by the row's input
+token. Dropped, that token is computed again, which is harmless for rows a
+token (the write is redone) and would count the token twice in a state. So
+a row with state layers that is preempted while its token is in flight has
+that launch collected first (its tokens taken and committed, the device
+order kept): the state spilled is that of its committed tokens only, and the
+row resumes from them. A cancelled row never resumes; its slot is given back
+and the next prefill into it, queued behind the launch, overwrites it.
 
 **Generation by diffusion over blocks.** A row carries a block of ``B``
 positions, some still masked, through several passes of one program over
@@ -172,6 +202,7 @@ from ..observability import metrics, request_timeline, trace
 from ..observability.request_timeline import percentile
 from ..observability.step_monitor import RecompileSentinel
 from ..ops.flash_attention import takes_paged_kernel
+from ..ops.gated_delta import takes_state_kernel
 from ..ops.paged_layout import write_blocks, write_tokens
 from .buckets import BucketSet, pow2_buckets, pad_axis
 from .paged_cache import (NULL_BLOCK, OutOfBlocksError, PagedKVCache,
@@ -188,6 +219,20 @@ __all__ = ["ServingEngine"]
 
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _keeps_state(layer) -> bool:
+    """Whether a layer keeps a fixed-size state a sequence (``serve_keeps =
+    "state"``) rather than rows a token."""
+    return getattr(layer, "serve_keeps", "rows") == "state"
+
+
+def _state_spec(model) -> Tuple:
+    """What a state layer of ``model`` keeps a sequence, one slot pool a part
+    (``model.serve_state()``); () for a model none of whose layers does."""
+    if not any(_keeps_state(layer) for layer in model.serve_layers()):
+        return ()
+    return tuple(model.serve_state())
 
 
 def _commit_stamps(seq: Sequence) -> Dict[str, Any]:
@@ -230,7 +275,20 @@ def _meters() -> types.SimpleNamespace:
         "positions unmasked, by the branch of the rule that chose them "
         "(rule=threshold: every masked position over the confidence "
         "threshold; rule=schedule: the most confident)")
+    slots = metrics.gauge(
+        "serving.state_slots",
+        "slots of the state pools (a model whose layers keep a state a "
+        "sequence) held by sequences (kind=used) and free (kind=free)")
+    state_rows = metrics.counter(
+        "serving.state_rows",
+        "rows of the decode programs that hold a sequence (kind=needed) and "
+        "rows whose state slot a program read (kind=read: the kernel skips "
+        "a pad row's null slot, the dense path gathers every row's)")
     return types.SimpleNamespace(
+        slots_used=slots.labels(kind="used"),
+        slots_free=slots.labels(kind="free"),
+        state_needed=state_rows.labels(kind="needed"),
+        state_read=state_rows.labels(kind="read"),
         passes_denoise=passes.labels(kind="denoise"),
         passes_commit=passes.labels(kind="commit"),
         unmasked_threshold=unmasked.labels(rule="threshold"),
@@ -345,9 +403,9 @@ class _ParamJit:
             with _swapped_state(model, params, None):
                 return raw(*args)
 
-        # every step is (tokens, *pools, ...): the model's page pools are
-        # donated (raw args 1.. — behind the weights: 2..)
-        n_pools = len(model.serve_cache_rows())
+        # every step is (tokens, *pools, ...): the model's page pools, then
+        # its slot pools, are donated (raw args 1.. — behind the weights: 2..)
+        n_pools = len(model.serve_cache_rows()) + len(_state_spec(model))
         self.jitted = jax.jit(step,
                               donate_argnums=tuple(range(2, 2 + n_pools)))
 
@@ -470,8 +528,15 @@ class ServingEngine:
                     "block in flight has to lie inside one page")
 
         # -- device state ----------------------------------------------------
-        self.cache = self._pool_for(model, num_blocks)
-        self._n_pools = len(self.cache.pools)
+        self.cache = self._pool_for(model, num_blocks, max_batch)
+        if self.cache.states and (self.prefix_on or self.chunk_tokens or spec
+                                  or self._gen is not None):
+            raise ValueError(
+                "a model whose layers keep a state a sequence is served "
+                "without prefix sharing, chunked prefill and speculation (a "
+                "shared prefix, a chunk or a rejected draft would need the "
+                "state at its end, which no page holds)")
+        self._n_pools = len(self.cache.arrays)
         #: counts the decode and prefill programs return behind the token
         #: (0: none), handed to ``model.serve_record_counts``
         self._n_counts = int(model.serve_counts)
@@ -488,6 +553,10 @@ class ServingEngine:
         self._decode_paged = takes_paged_kernel(
             self.cache.dtype, self.cache.pools[0],
             model.serve_latent_value_dim, self.block_size)
+        #: whether the decode program's state layers take the kernel (what
+        #: ``serving.state_rows{kind=read}`` then counts)
+        self._state_paged = bool(self.cache.states) and takes_state_kernel(
+            self.cache.states[0])
         self.prefix = PrefixCache(self.cache, mirror=self._draft_cache) \
             if self.prefix_on else None
         self.sched = FCFSScheduler(max_batch, max_waiting=max_waiting)
@@ -577,11 +646,19 @@ class ServingEngine:
     # The bucketed executables
     # ------------------------------------------------------------------
 
-    def _pool_for(self, model, num_blocks: int) -> PagedKVCache:
-        """The page pools ``model`` asks for: one a row of its cache spec."""
-        return PagedKVCache(len(model.serve_layers()), num_blocks,
+    def _pool_for(self, model, num_blocks: int,
+                  max_batch: int = 0) -> PagedKVCache:
+        """The pools ``model`` asks for: a page pool a row of its cache spec
+        over the layers that cache rows, and where layers keep a state a
+        sequence a slot pool a part of it over those, a slot for each of
+        ``max_batch`` rows and the null slot."""
+        layers = model.serve_layers()
+        n_state = sum(_keeps_state(layer) for layer in layers)
+        return PagedKVCache(len(layers) - n_state, num_blocks,
                             self.block_size, dtype=model.serve_dtype(),
-                            rows=model.serve_cache_rows())
+                            rows=model.serve_cache_rows(),
+                            state=_state_spec(model), n_state_layers=n_state,
+                            n_slots=max_batch + 1)
 
     @property
     def _donated(self):
@@ -618,34 +695,48 @@ class ServingEngine:
         m = model if model is not None else self.model
         bs = self.block_size
         n_pools = len(m.serve_cache_rows())
+        n_states = len(_state_spec(m))
         counted = bool(m.serve_counts)
 
         def prefill(ids, *rest):
-            """ids [1, S] bucket-padded; then the pools; block_ids [S//bs]
-            (null-padded); n_tokens: true prompt length. Writes the
-            prompt's page rows and returns the first generated token."""
-            pools, (block_ids, n_tokens) = list(rest[:n_pools]), \
-                rest[n_pools:]
+            """ids [1, S] bucket-padded; then the pools (and slot pools);
+            block_ids [S//bs] (null-padded); n_tokens: true prompt length;
+            for a model with state layers the sequence's slot. Writes the
+            prompt's page rows (and its state: a state layer's after the
+            prompt, overwriting the slot) and returns the first generated
+            token."""
+            pools = list(rest[:n_pools])
+            states = list(rest[n_pools:n_pools + n_states])
+            block_ids, n_tokens, *slot = rest[n_pools + n_states:]
             s = ids.shape[1]
             pos = jnp.arange(s)[None, :]
             real = pos < n_tokens if counted else None
             counts = []
             x = m.serve_embed(ids, pos)
-            for li, layer in enumerate(m.serve_layers()):
+            ri = ki = 0         # a layer's place among its kind
+            for layer in m.serve_layers():
+                if _keeps_state(layer):
+                    x, kept = layer.serve_prefill_state(x, n_tokens)
+                    for j, part in enumerate(kept):
+                        states[j] = states[j].at[ki, slot[0]].set(
+                            part[0].astype(states[j].dtype))
+                    ki += 1
+                    continue
                 q, rows = layer.serve_project(x, pos)
                 o = layer.serve_attend_prefill(q, rows)
                 for pi, row in enumerate(rows):
-                    pools[pi] = write_blocks(pools[pi], li, block_ids,
+                    pools[pi] = write_blocks(pools[pi], ri, block_ids,
                                              row[0], bs)
                 x, c = layer.serve_finish(x, o, real)
                 if c is not None:
                     counts.append(c)
+                ri += 1
             hidden = m.serve_final_norm(x)
             last = jax.lax.dynamic_index_in_dim(hidden, n_tokens - 1,
                                                 axis=1, keepdims=True)
             logits = m.logits(last)[0, 0]
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (self._with_counts(tok, counts), *pools)
+            return (self._with_counts(tok, counts), *pools, *states)
 
         return prefill
 
@@ -653,6 +744,7 @@ class ServingEngine:
         m = model if model is not None else self.model
         bs = self.block_size
         n_pools = len(m.serve_cache_rows())
+        n_states = len(_state_spec(m))
         counted = bool(m.serve_counts)
 
         def decode(tokens, *rest):
@@ -667,9 +759,16 @@ class ServingEngine:
             launch of this bucket returned (tokens first), and ``src`` [B]:
             row i reads its token from ``prev[src[i]]`` where ``src[i] >= 0``
             (the row that sequence held in that launch, whose token the host
-            has not seen yet) and from ``tokens[i]`` where it is -1."""
+            has not seen yet) and from ``tokens[i]`` where it is -1.
+
+            A model with state layers: the slot pools behind the pools, and
+            ``slots`` [B] last (0 = a pad row): a state layer advances each
+            row's state in its slot, in place."""
             pools = list(rest[:n_pools])
-            tables, ctx_lens, *fed = rest[n_pools:]
+            states = tuple(rest[n_pools:n_pools + n_states])
+            tables, ctx_lens, *fed = rest[n_pools + n_states:]
+            if n_states:
+                *fed, slots = fed
             if fed:
                 prev, src = fed
                 tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
@@ -682,20 +781,26 @@ class ServingEngine:
             bi = jnp.take_along_axis(tables, (pos // bs)[:, None],
                                      axis=1)[:, 0]
             si = pos % bs
-            for li, layer in enumerate(m.serve_layers()):
+            ri = ki = 0         # a layer's place among its kind
+            for layer in m.serve_layers():
+                if _keeps_state(layer):
+                    x, states = layer.serve_decode_state(x, states, slots, ki)
+                    ki += 1
+                    continue
                 q, rows = layer.serve_project(x, pos_col)
                 for pi, row in enumerate(rows):
-                    pools[pi] = write_tokens(pools[pi], li, bi, si,
+                    pools[pi] = write_tokens(pools[pi], ri, bi, si,
                                              row[:, 0], bs)
                 o = layer.serve_attend_paged(q, pools, tables, pos + 1, bs,
-                                             li)
+                                             ri)
                 x, c = layer.serve_finish(x, o, real)
                 if c is not None:
                     counts.append(c)
+                ri += 1
             hidden = m.serve_final_norm(x)
             logits = m.logits(hidden)[:, 0]
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (self._with_counts(tok, counts), *pools)
+            return (self._with_counts(tok, counts), *pools, *states)
 
         return decode
 
@@ -938,13 +1043,14 @@ class ServingEngine:
         b0 = self.decode_buckets.sizes[0]
         c = self.cache
         m_blocks = self.max_blocks_per_seq
-        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.pools]
+        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.arrays]
         donated = self._donated
         i32 = jnp.int32
+        slot = (jax.ShapeDtypeStruct((), i32),) if c.states else ()
         pre = jax.make_jaxpr(self._prefill_raw)(
             jax.ShapeDtypeStruct((1, s0), i32), *pages,
             jax.ShapeDtypeStruct((s0 // self.block_size,), i32),
-            jax.ShapeDtypeStruct((), i32))
+            jax.ShapeDtypeStruct((), i32), *slot)
         dec = jax.make_jaxpr(self._decode_raw)(
             self._decode_head_spec(b0), *pages, *self._decode_tail_spec(b0))
         out = {"prefill": (pre, donated), "decode": (dec, donated)}
@@ -983,13 +1089,16 @@ class ServingEngine:
         """The decode program's arguments behind the pools at bucket
         ``width``: tables, contexts (a block-decode program: each block's
         first position, its masked flags, whether the pass commits, where
-        the answer ends), the previous launch's result and the row map."""
+        the answer ends), the previous launch's result and the row map (and
+        the rows' state slots, for a model with state layers)."""
         i32 = jnp.int32
         row = jax.ShapeDtypeStruct((width,), i32)
         tables = jax.ShapeDtypeStruct((width, self.max_blocks_per_seq), i32)
         if self._gen is None:
+            slots = (row,) if self.cache.states else ()
             return (tables, row, jax.ShapeDtypeStruct(
-                (self._state_len(width) + self._n_counts,), i32), row)
+                (self._state_len(width) + self._n_counts,), i32), row,
+                *slots)
         return (tables, row, self._decode_head_spec(width), row, row,
                 jax.ShapeDtypeStruct(
                     (self._state_len(width) + self._n_counts,), i32),
@@ -1005,8 +1114,7 @@ class ServingEngine:
         module must compile with zero collectives (X001)."""
         b0 = self.decode_buckets.sizes[0]
         c = self.cache
-        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.pools]
-        i32 = jnp.int32
+        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.arrays]
         compiled = self._decode_fn.lower(
             self._decode_head_spec(b0), *pages,
             *self._decode_tail_spec(b0)).compile()
@@ -1021,7 +1129,7 @@ class ServingEngine:
             raise ValueError("extend executable not armed (enable "
                              "prefix_cache/chunked_prefill/speculative)")
         c = self.cache
-        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.pools]
+        pages = [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in c.arrays]
         i32 = jnp.int32
         if verify:
             b, L = self.decode_buckets.sizes[0], self.spec_gamma + 1
@@ -1129,6 +1237,9 @@ class ServingEngine:
             self.cache.allocator.free(private)
         seq.block_ids = []
         seq.n_shared_blocks = 0
+        if seq.state_slot:
+            self.cache.slots.free([seq.state_slot])
+            seq.state_slot = 0
 
     # ------------------------------------------------------------------
     # Request lifecycle
@@ -1199,6 +1310,9 @@ class ServingEngine:
         usable = self.cache.num_blocks - 1
         m.free_block_frac.set(self.cache.allocator.n_free / usable
                               if usable else 0.0)
+        if self.cache.slots is not None:
+            m.slots_used.set(self.cache.slots.n_used)
+            m.slots_free.set(self.cache.slots.n_free)
         if fleet_live.enabled():     # the armed exporter publishes it
             self._decode_p99()
 
@@ -1233,6 +1347,7 @@ class ServingEngine:
         if seq.host_kv is not None:
             seq.host_kv = None
             seq.host_draft_kv = None
+            seq.host_state = None
             self._account_spill(-seq.spilled_bytes)
             seq.spilled_bytes = 0
         seq.error = reason
@@ -1363,7 +1478,11 @@ class ServingEngine:
                     f"{self.cache.allocator.n_free}", diagnose=True)
                 return True
             return False
+        slot = self._take_slot(ids)
+        if slot is None:
+            return False
         self.sched.admit(seq)
+        seq.state_slot = slot
         try:
             self._prefill(seq, ids)
         except Exception as e:  # per-sequence device error: isolate it
@@ -1387,7 +1506,11 @@ class ServingEngine:
         ids = self._alloc(n_need)
         if ids is None:
             return False
+        slot = self._take_slot(ids)
+        if slot is None:
+            return False
         self.sched.admit(seq)
+        seq.state_slot = slot
         try:
             self._restore(seq, ids)
         except Exception as e:
@@ -1396,6 +1519,18 @@ class ServingEngine:
             self._cancel(seq, Status.FAILED,
                          f"{type(e).__name__}: {e}", diagnose=True)
         return True
+
+    def _take_slot(self, ids: List[int]) -> Optional[int]:
+        """A state slot for a sequence being admitted with blocks ``ids``: 0
+        for a model without state layers, None (the blocks given back) where
+        none is free."""
+        if self.cache.slots is None:
+            return 0
+        got = self.cache.slots.alloc(1)
+        if got is None:
+            self.cache.allocator.free(ids)
+            return None
+        return got[0]
 
     def _admit_extend(self, seq: Sequence) -> bool:
         """Admission with the prefix tree and/or chunked prefill armed:
@@ -1502,9 +1637,11 @@ class ServingEngine:
                 # (a prompt's tail may have a block past the bucket's)
                 held = block_ids[:nb_bucket]
                 btab[:len(held)] = held
-                args = (jnp.asarray(ids, jnp.int32), *self.cache.pools,
+                args = (jnp.asarray(ids, jnp.int32), *self.cache.arrays,
                         jnp.asarray(btab),
                         jnp.asarray(n_tokens, jnp.int32))
+                if self.cache.states:
+                    args += (jnp.asarray(seq.state_slot, jnp.int32),)
                 self._maybe_lint()
                 self._assert_cow(block_ids)
                 self._sent_prefill.observe_tree(
@@ -1561,7 +1698,7 @@ class ServingEngine:
                 table = np.full((1, self.max_blocks_per_seq), NULL_BLOCK,
                                 np.int32)
                 table[0, :len(seq.block_ids)] = seq.block_ids
-                args = (jnp.asarray(toks, jnp.int32), *self.cache.pools,
+                args = (jnp.asarray(toks, jnp.int32), *self.cache.arrays,
                         jnp.asarray(table),
                         jnp.asarray([start], jnp.int32),
                         jnp.asarray([span], jnp.int32))
@@ -1671,6 +1808,9 @@ class ServingEngine:
         with trace.span("serve/restore", rid=seq.rid,
                         blocks=len(ids)) as sp:
             self.cache.restore(seq.host_kv, ids)
+            if seq.host_state is not None:
+                self.cache.restore_state(seq.host_state, seq.state_slot)
+                seq.host_state = None
             if self._draft_cache is not None and \
                     seq.host_draft_kv is not None:
                 self._draft_cache.restore(seq.host_draft_kv, ids)
@@ -1691,6 +1831,15 @@ class ServingEngine:
         _account(sp.t0_ns, sp.end_ns, "prefill", (seq,))
 
     def _preempt(self, seq: Sequence) -> None:
+        if self.cache.states and self._flight_row(seq) >= 0:
+            # the launch in flight has advanced this row's state by a token
+            # the host has not taken; dropped and computed again, it would
+            # count twice in the state. So that launch is collected first, and
+            # the state spilled is that of the committed tokens only.
+            ahead, self._ahead = self._ahead, None
+            self._decode_collect(ahead)
+            if seq.status is not Status.RUNNING:
+                return          # that token finished it
         with trace.span("serve/preempt", rid=seq.rid):
             self._spill(seq)
 
@@ -1710,6 +1859,10 @@ class ServingEngine:
                        if self._draft_cache is not None else 0)
         seq.spilled_bytes = (len(private) * self.cache.bytes_per_block
                              + draft_bytes)
+        if seq.state_slot:
+            seq.host_state = self.cache.spill_state(seq.state_slot)
+            seq.state_slot = 0
+            seq.spilled_bytes += self.cache.bytes_per_slot
         self._account_spill(seq.spilled_bytes)
         # queue time for the preempted span restarts now; t_submit stays
         # the TRUE arrival so latency + deadlines measure end to end
@@ -1774,8 +1927,9 @@ class ServingEngine:
 
     def _grow_blocks(self, seq: Sequence, needed: int) -> None:
         """Top ``seq`` up to ``needed`` blocks, preempting for room; with
-        nothing left to preempt the sequence fails."""
-        while len(seq.block_ids) < needed:
+        nothing left to preempt the sequence fails (or, where a preemption
+        took the launch in flight first, that launch's token finished it)."""
+        while seq.status is Status.RUNNING and len(seq.block_ids) < needed:
             got = self._alloc(1)
             if got is not None:
                 seq.block_ids.extend(got)
@@ -1883,8 +2037,12 @@ class ServingEngine:
                     else self._no_prev_for(width)
                 first_d, tables_d, *after_d, src_d = jax.device_put(
                     (first, tables, *after, src))
-                args = (first_d, *self.cache.pools, tables_d, *after_d, prev,
-                        src_d)
+                args = (first_d, *self.cache.arrays, tables_d, *after_d,
+                        prev, src_d)
+                if self.cache.states:
+                    slots = np.zeros((width,), np.int32)
+                    slots[:n] = [seq.state_slot for seq in batch]
+                    args += (jax.device_put(slots),)
                 n_device = int((src >= 0).sum())
                 self._m.fed_device.inc(n_device)
                 self._m.fed_host.inc(n - n_device)
@@ -1965,6 +2123,10 @@ class ServingEngine:
         """Commit a decode program's token to each live row, and return the
         rows that token finished."""
         self._kv_count(launch.lens, self._decode_paged)
+        if self.cache.states:
+            n = len(launch.batch)
+            self._m.state_needed.inc(n)
+            self._m.state_read.inc(n if self._state_paged else launch.width)
         toks = out.tolist()
         finished: List[Sequence] = []
         for i in live:
@@ -2084,7 +2246,7 @@ class ServingEngine:
         pages = int((lens // bs + 1).sum()) if paged \
             else len(lens) * self.max_blocks_per_seq
         self._m.kv_gathered.inc(pages * bs)
-        self._m.kv_fetches.inc(pages * self._n_pools)
+        self._m.kv_fetches.inc(pages * len(self.cache.pools))
 
     # -- speculative decoding ------------------------------------------------
 
@@ -2161,7 +2323,7 @@ class ServingEngine:
                     tokens[i, :len(fed)] = fed
                     lens[i] = seq.ctx_len
                     n_real[i] = len(fed)
-                args = (jnp.asarray(tokens), *self.cache.pools,
+                args = (jnp.asarray(tokens), *self.cache.arrays,
                         jnp.asarray(tables), jnp.asarray(lens),
                         jnp.asarray(n_real))
             with trace.span("serve/decode/checks"):

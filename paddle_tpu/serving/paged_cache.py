@@ -169,6 +169,17 @@ def _gather_blocks(pages, ids):
     return pages[:, ids]
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_slot(pool, slot, vals):
+    """pool[:, slot] = vals, pool donated."""
+    return pool.at[:, slot].set(vals)
+
+
+@jax.jit
+def _gather_slot(pool, slot):
+    return pool[:, slot]
+
+
 class PagedKVCache:
     """The device page pool for one model: one array a kind of row the model
     caches for a token, each ``[n_layers, num_blocks, *page]`` with the page
@@ -203,12 +214,25 @@ class PagedKVCache:
     return the updated pools; :meth:`swap` re-homes the references. Spill
     and restore move whole per-sequence block lists between the pools and
     the host memory tier.
+
+    **A second kind of cache: a state a sequence.** A model whose layers
+    are of two kinds (rows a token in some, a fixed-size state a sequence in
+    others: ``serve_keeps = "state"`` at the engine's seam) gives ``state``,
+    the parts of what such a layer keeps (``model.serve_state()``). The page
+    pools then span only the row layers (``n_layers`` of them, indexed by
+    their order among them), and ``states`` holds one slot pool a part,
+    ``[n_state_layers, n_slots, *part]``, over the state layers; a sequence
+    holds one slot of all of them, granted by a :class:`BlockAllocator` of
+    its own (``slots``; slot 0 is the null sink of pad rows). ``arrays`` is
+    what the programs take donated: page pools, then slot pools.
     """
 
     def __init__(self, n_layers: int, num_blocks: int, block_size: int,
                  kv_heads: Optional[int] = None,
                  head_dim: Optional[int] = None, dtype=jnp.float32,
-                 rows: Optional[Sequence[Sequence[int]]] = None):
+                 rows: Optional[Sequence[Sequence[int]]] = None,
+                 state: Sequence[jax.ShapeDtypeStruct] = (),
+                 n_state_layers: int = 0, n_slots: int = 0):
         if rows is None:
             rows = ((int(kv_heads), int(head_dim)),) * 2
         self.rows = tuple(tuple(int(d) for d in r) for r in rows)
@@ -221,6 +245,13 @@ class PagedKVCache:
                       + page_shape(r, block_size, self.dtype), self.dtype)
             for r in self.rows)
         self.allocator = BlockAllocator(num_blocks)
+        #: the slot pools of the layers that keep a state a sequence, one a
+        #: part of the state (``[n_state_layers, n_slots, *part]``), and the
+        #: allocator of their slots (slot 0 the null sink of pad rows)
+        self.states = tuple(
+            jnp.zeros((int(n_state_layers), int(n_slots)) + tuple(p.shape),
+                      p.dtype) for p in (state if n_state_layers else ()))
+        self.slots = BlockAllocator(n_slots) if self.states else None
         self.host_kind = host_memory_kind()
 
     # the two pools of a keys-and-values spec, by their old names
@@ -246,13 +277,25 @@ class PagedKVCache:
         return (self.n_layers * self.block_size * per_token
                 * self.dtype.itemsize)
 
-    def swap(self, *pools) -> None:
-        """Adopt the pool arrays an executable returned (the old ones were
-        donated into it)."""
-        if len(pools) != len(self.pools):
-            raise ValueError(f"swap of {len(pools)} pools into "
-                             f"{len(self.pools)}")
-        self.pools = tuple(pools)
+    @property
+    def bytes_per_slot(self) -> int:
+        """What one sequence's state takes over every state layer."""
+        return sum(p.nbytes // p.shape[1] for p in self.states)
+
+    @property
+    def arrays(self) -> Tuple:
+        """Every array the programs take donated: the page pools, then the
+        slot pools."""
+        return self.pools + self.states
+
+    def swap(self, *arrays) -> None:
+        """Adopt the arrays an executable returned (the old ones were donated
+        into it): the page pools, then the slot pools."""
+        if len(arrays) != len(self.arrays):
+            raise ValueError(f"swap of {len(arrays)} pools into "
+                             f"{len(self.arrays)}")
+        self.pools = tuple(arrays[:len(self.pools)])
+        self.states = tuple(arrays[len(self.pools):])
 
     # -- spill / restore -----------------------------------------------------
 
@@ -329,6 +372,32 @@ class PagedKVCache:
             for p, h in zip(self.pools, host_kv))
         metrics.counter("serving.kv_restores",
                         "sequence KV restores from host memory").inc()
+
+    # -- the state of one sequence, by slot ------------------------------------
+
+    def spill_state(self, slot: int) -> Tuple:
+        """Gather one slot of every slot pool to the host tier and free the
+        slot; :meth:`restore_state` takes the tuple back, bitwise."""
+        try:
+            host = tuple(self._to_host(_gather_slot(p, slot))
+                         for p in self.states)
+            if self.host_kind is not None:
+                jax.block_until_ready(host)
+        except (RuntimeError, MemoryError, ValueError) as e:
+            raise SpillError(f"host spill of state slot {slot} failed: {e}"
+                             ) from e
+        self.slots.free([slot])
+        return host
+
+    def restore_state(self, host_state: Tuple, slot: int) -> None:
+        """Scatter a spilled state into a freshly granted slot."""
+        self.states = tuple(
+            _scatter_slot(p, slot, jnp.asarray(h, p.dtype))
+            for p, h in zip(self.states, host_state))
+
+    def read_state(self, slot: int) -> Tuple[np.ndarray, ...]:
+        """Host copies of one slot of every slot pool (tests / debugging)."""
+        return tuple(np.asarray(_gather_slot(p, slot)) for p in self.states)
 
     def read_blocks(self, block_ids: Sequence[int]) -> Tuple[np.ndarray, ...]:
         """Host copies of the given blocks, one array a pool (tests /

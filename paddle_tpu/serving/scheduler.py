@@ -125,6 +125,9 @@ class Sequence:
     block_ids: List[int] = field(default_factory=list)
     host_kv: Any = None                  # spilled KV while PREEMPTED
     spilled_bytes: int = 0               # host bytes held while PREEMPTED
+    # -- a model whose layers keep a state a sequence (paged_cache.py) -----
+    state_slot: int = 0                  # its slot of the slot pools (0: none)
+    host_state: Any = None               # the spilled state while PREEMPTED
     preemptions: int = 0
     # -- prefix sharing (FLAGS_serve_prefix_cache) ------------------------
     # the first n_shared_blocks of block_ids are copy-on-write tree pages

@@ -562,3 +562,127 @@ def test_block_decode_program_compiles(one_chip, monkeypatch):
     # its float32 logits (512 x 37,984 x 4 B = 78 MB)
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 512 * 2 ** 20
+
+
+# Olmo-Hybrid's cell (serve.olmo-hybrid-7b-l4.reason1k-closed256): 256 rows;
+# a linear layer's state [96, 30 x 192] float32 in a pool of 257 slots over
+# the 3 linear layers; the full layer's fused rows of 60 heads (a 240 KB
+# page), 24,576 pages.
+CELL_STATE = dict(b=256, layers=3, slots=257, h=30, dk=96, dv=192, m=128,
+                  bs=16, nb=24576, hidden=3840)
+
+
+def test_gated_delta_decode_kernel_compiles(one_chip):
+    """The state kernel alone at the cell's shapes: the pool stays where it
+    lies (aliased to the output: no copy of its 1.7 GB), one custom call."""
+    from paddle_tpu.ops._pallas.gated_delta_decode import (
+        gated_delta_decode_pallas, supported_shapes)
+    c = CELL_STATE
+    width = c["h"] * c["dv"]
+    pool = ((c["layers"], c["slots"], c["dk"], width), jnp.float32)
+    assert supported_shapes(jax.ShapeDtypeStruct(*pool), c["h"])
+
+    def fn(q, k, v, g, beta, pool, slots, layer):
+        return gated_delta_decode_pallas(q, k, v, g, beta, pool, slots,
+                                         layer=layer)
+
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((c["b"], c["h"], c["dk"]), f32), ((c["b"], c["h"], c["dk"]), f32),
+        ((c["b"], c["h"], c["dv"]), f32), ((c["b"], c["h"]), f32),
+        ((c["b"], c["h"]), f32), pool, ((c["b"],), jnp.int32),
+        ((), jnp.int32))]
+    compiled = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "gated_delta_decode" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = c["layers"] * c["slots"] * c["dk"] * width * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_block_kernel_compiles_at_one_position_over_60_heads(one_chip):
+    """The block kernel as the full layer's decode runs it: one query a row,
+    30 heads over a fused row of 60 (keys and values of 30), pages of 240 KB,
+    four a step (a slot of ~1 MiB, as 32 of SDAR's 32 KB)."""
+    from paddle_tpu.ops._pallas.block_paged_attention import (
+        block_paged_attention_pallas, pages_for, supported_shapes)
+    from paddle_tpu.ops.paged_layout import page_shape
+    c = CELL_STATE
+    page = page_shape((2 * c["h"], 128), c["bs"], jnp.bfloat16)
+    assert page == (2 * c["h"], c["bs"], 128)
+    assert pages_for(2 * c["h"] * c["bs"] * 128 * 2) == 4
+    assert pages_for(8 * 16 * 128 * 2) == 32
+    pool = ((1, c["nb"]) + page, jnp.bfloat16)
+    assert supported_shapes(jnp.bfloat16, jax.ShapeDtypeStruct(*pool))
+
+    def fn(q, kv, tables, lengths, layer):
+        return block_paged_attention_pallas(q, kv, tables, lengths,
+                                            layer=layer)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((c["b"], 1, c["h"], 128), jnp.bfloat16), pool,
+        ((c["b"], c["m"]), jnp.int32), ((c["b"],), jnp.int32),
+        ((), jnp.int32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+def test_state_decode_program_compiles(one_chip, monkeypatch):
+    """The engine's decode program for a model with state layers as the chip
+    runs it (the entry points pick their kernels from the platform, which is
+    the CPU here, so the test steers that one question): one linear and one
+    full layer at Olmo-Hybrid's widths with an eighth of its vocabulary,
+    256 rows; one state kernel call and one block kernel call; the page pool
+    and the slot pools updated in place; no gathered copy of a row's state
+    among the temporaries."""
+    import importlib
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.text.models.olmo_hybrid import (OlmoHybridConfig,
+                                                    OlmoHybridForCausalLM)
+    for mod in ("paddle_tpu.ops.flash_attention",
+                "paddle_tpu.ops.gated_delta"):
+        monkeypatch.setattr(importlib.import_module(mod), "_platform_of",
+                            lambda x: "tpu")
+    c = CELL_STATE
+    cfg = OlmoHybridConfig(vocab_size=12544, num_hidden_layers=2,
+                           layer_types=["linear_attention", "full_attention"],
+                           dtype="bfloat16", init_weights=False)
+    paddle.seed(0)
+    model = OlmoHybridForCausalLM(cfg)
+    # a small engine: the program is lowered at the cell's shapes below
+    eng = ServingEngine(model, block_size=c["bs"], num_blocks=c["m"] + 1,
+                        max_batch=1, max_seq_len=c["m"] * c["bs"],
+                        prefill_buckets=[1024], decode_buckets=[c["b"]])
+    assert eng._decode_paged and eng._state_paged
+    (pool,) = eng.cache.pools
+    state, tail = eng.cache.states
+    assert state.shape[2:] == (c["dk"], c["h"] * c["dv"])
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._decode_fn.params)
+    arrays = [jax.ShapeDtypeStruct((1, c["nb"]) + pool.shape[2:],
+                                   jnp.bfloat16, sharding=one_chip),
+              jax.ShapeDtypeStruct((1, c["slots"]) + state.shape[2:],
+                                   jnp.float32, sharding=one_chip),
+              jax.ShapeDtypeStruct((1, c["slots"]) + tail.shape[2:],
+                                   jnp.bfloat16, sharding=one_chip)]
+    tail_args = [on_chip(s) for s in eng._decode_tail_spec(c["b"])]
+    lowered = eng._decode_fn.jitted.lower(
+        params, on_chip(eng._decode_head_spec(c["b"])), *arrays, *tail_args)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    mem = compiled.memory_analysis()
+    state_bytes = c["slots"] * c["dk"] * c["h"] * c["dv"] * 4
+    page_bytes = c["nb"] * c["bs"] * 2 * c["h"] * 128 * 2
+    assert mem.alias_size_in_bytes >= state_bytes + page_bytes
+    # the temporaries are the step's activations and its float32 logits
+    # (256 x 12,544 x 4 B = 13 MB), not a gathered state (256 x 2.2 MB)
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20

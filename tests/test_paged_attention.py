@@ -382,7 +382,8 @@ def _block_dense(q, kv, tables, lengths, layer):
 
 
 @pytest.mark.parametrize("pages", [1, 2, 5])
-@pytest.mark.parametrize("kh,heads,lq", [(4, 32, 4), (2, 4, 4), (1, 8, 2)])
+@pytest.mark.parametrize("kh,heads,lq", [(4, 32, 4), (2, 4, 4), (1, 8, 2),
+                                         (3, 3, 1)])
 def test_block_kernel_equals_dense_attention_over_ragged_contexts(
         pages, kh, heads, lq):
     """Rows of unequal contexts (a pad row, one block, page edges, the whole
