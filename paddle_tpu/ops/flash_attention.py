@@ -227,7 +227,8 @@ def block_paged_attention(q, kv_pool, tables, lengths, *,
     ``_pallas.block_paged_attention.supported_shapes`` takes, this is the
     Pallas kernel: each row's pages are fetched from HBM up to its own
     length, keys and values of a page as one descriptor, a kv head at a time
-    against its ``Lq * H / KH`` query rows, and no gathered copy exists.
+    against its ``Lq * H / KH`` query rows (every head in one product where
+    that is one row), and no gathered copy exists.
     Everywhere else it is the dense path the kernel is checked against:
     gather every table's pages, split the rows into keys and values, then
     :func:`multi_query_attention` behind the length mask."""

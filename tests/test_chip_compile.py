@@ -498,7 +498,9 @@ def test_block_paged_kernel_compiles(one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # 32 query rows a kv head: the per-head form, not the heads-joint one
     assert "block_paged_attention" in text
+    assert "block_paged_attention_one_query" not in text
     mem = compiled.memory_analysis()
     stored = c["layers"] * c["nb"] * c["bs"] * 2 * c["kh"] * c["d"] * 2
     assert stored == c["nb"] * c["bs"] * 32 * 1024
@@ -604,16 +606,18 @@ def test_gated_delta_decode_kernel_compiles(one_chip):
 
 def test_block_kernel_compiles_at_one_position_over_60_heads(one_chip):
     """The block kernel as the full layer's decode runs it: one query a row,
-    30 heads over a fused row of 60 (keys and values of 30), pages of 240 KB,
-    four a step (a slot of ~1 MiB, as 32 of SDAR's 32 KB)."""
+    30 heads over a fused row of 60 (keys and values of 30), pages of 240 KB:
+    the heads-joint form, eight pages a step (a slot of ~2 MiB; the per-head
+    form's slot of 1 MiB holds 32 of SDAR's 32 KB)."""
     from paddle_tpu.ops._pallas.block_paged_attention import (
         block_paged_attention_pallas, pages_for, supported_shapes)
     from paddle_tpu.ops.paged_layout import page_shape
     c = CELL_STATE
     page = page_shape((2 * c["h"], 128), c["bs"], jnp.bfloat16)
     assert page == (2 * c["h"], c["bs"], 128)
-    assert pages_for(2 * c["h"] * c["bs"] * 128 * 2) == 4
-    assert pages_for(8 * 16 * 128 * 2) == 32
+    assert pages_for(2 * c["h"] * c["bs"] * 128 * 2, 1) == 8
+    assert pages_for(2 * c["h"] * c["bs"] * 128 * 2, 32) == 4
+    assert pages_for(8 * 16 * 128 * 2, 32) == 32
     pool = ((1, c["nb"]) + page, jnp.bfloat16)
     assert supported_shapes(jnp.bfloat16, jax.ShapeDtypeStruct(*pool))
 
@@ -626,8 +630,10 @@ def test_block_kernel_compiles_at_one_position_over_60_heads(one_chip):
         ((c["b"], c["m"]), jnp.int32), ((c["b"],), jnp.int32),
         ((), jnp.int32))]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 1
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.findall(r'/(block_paged_attention\w*)/pallas_call', text) == [
+        "block_paged_attention_one_query"]
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
 
 

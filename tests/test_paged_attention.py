@@ -9,7 +9,9 @@ the entry point steered to the interpreted kernel.
 
 import functools
 import importlib
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -383,12 +385,13 @@ def _block_dense(q, kv, tables, lengths, layer):
 
 @pytest.mark.parametrize("pages", [1, 2, 5])
 @pytest.mark.parametrize("kh,heads,lq", [(4, 32, 4), (2, 4, 4), (1, 8, 2),
-                                         (3, 3, 1)])
+                                         (3, 3, 1), (4, 4, 1), (30, 30, 1)])
 def test_block_kernel_equals_dense_attention_over_ragged_contexts(
         pages, kh, heads, lq):
     """Rows of unequal contexts (a pad row, one block, page edges, the whole
     table): every query of a row over the row's first ``lengths[b]`` keys,
-    against gather-and-dense behind the length mask, in either layer."""
+    against gather-and-dense behind the length mask, in either layer; at one
+    query row a kv head (the last three cases) in the heads-joint form."""
     q, kv, tables, lengths = _block_case(LENGTHS[:1] + [4] + LENGTHS[2:],
                                          kh, heads, lq, jnp.bfloat16)
     for layer in (0, 1):
@@ -407,15 +410,18 @@ def test_block_kernel_equals_dense_attention_over_ragged_contexts(
 
 
 @pytest.mark.parametrize("pages", [1, 2, 5])
-def test_block_kernel_reads_nothing_past_a_rows_pages(pages):
+@pytest.mark.parametrize("kh,heads,lq", [(2, 8, 4), (4, 4, 1)])
+def test_block_kernel_reads_nothing_past_a_rows_pages(pages, kh, heads, lq):
     """A row that ends inside a page and a row of length 0 beside full rows:
     every page no row's length reaches holds NaN, in its keys half and its
     values half (the null page too, and the unused tokens of a row's last
     page), and nothing of it arrives in the output: a page past a row's
     length is not fetched, and what a fetched page holds past the length is
-    masked out of scores and values."""
+    masked out of scores and values (in the heads-joint form too, where a
+    query's product spans every head's columns)."""
     lengths = [FULL, 0, BS + 5, 2 * BS, 3]
-    q, kv, tables, lens = _block_case(lengths, 2, 8, 4, jnp.bfloat16, seed=3)
+    q, kv, tables, lens = _block_case(lengths, kh, heads, lq, jnp.bfloat16,
+                                      seed=3)
     clean = np.asarray(kv, np.float32)
     live = np.zeros((NB, BS), bool)
     for row, n in zip(np.asarray(tables), lengths):
@@ -423,7 +429,7 @@ def test_block_kernel_reads_nothing_past_a_rows_pages(pages):
             live[row[p], :min(BS, n - p * BS)] = True
     assert not live[NULL_BLOCK].any() and live.sum() == sum(lengths)
     planted = np.where(live[None, :, None, :, None], clean, np.nan)
-    for half in planted[:, :, :2], planted[:, :, 2:]:   # keys, values
+    for half in planted[:, :, :kh], planted[:, :, kh:]:   # keys, values
         assert np.isnan(half).any()
     planted = jnp.asarray(planted, jnp.bfloat16)
     for layer in (0, 1):
@@ -434,6 +440,29 @@ def test_block_kernel_reads_nothing_past_a_rows_pages(pages):
                             pages_per_step=pages)
         np.testing.assert_array_equal(got, np.asarray(want, np.float32))
         assert not got[1].any()                             # the empty row
+
+
+@pytest.mark.parametrize("kh,heads,lq,joint", [
+    (30, 30, 1, True), (3, 3, 1, True), (4, 32, 4, False),
+    (2, 4, 4, False), (1, 8, 2, False), (4, 8, 1, False)])
+def test_block_kernel_form_follows_the_query_rows_a_kv_head(kh, heads, lq,
+                                                             joint):
+    """The heads-joint form is taken where a kv head has ONE query row
+    (``Lq * H / KH``), whatever the model: its pages a step fill the joint
+    form's slot, and the kernel it lowers to carries the joint form's name;
+    SDAR's 32 rows (and any other count) keep the per-head form."""
+    qrows = lq * heads // kh
+    assert BPA.one_query(qrows) is joint
+    page = 2 * kh * BS * D * 2
+    slot = BPA.ONE_QUERY_SLOT_BYTES if joint else BPA.SLOT_BYTES
+    assert BPA.pages_for(page, qrows) == max(1, slot // page)
+    q, kv, tables, lengths = _block_case([FULL, 3], kh, heads, lq,
+                                         jnp.bfloat16)
+    text = jax.jit(BPA.block_paged_attention_pallas).trace(
+        q, kv, tables, lengths).lower(lowering_platforms=("tpu",)).as_text()
+    names = re.findall(r'kernel_name = "(block_paged_attention\w*)"', text)
+    assert names == (["block_paged_attention_one_query"] if joint
+                     else ["block_paged_attention"])
 
 
 def test_block_kernel_takes_the_cells_shapes_and_refuses_others():
