@@ -148,20 +148,13 @@ def renormalised_topk(probs, top_k: int, renormalise: bool = True):
     return idx, val
 
 
-def record_held_pairs(load, n_tokens: int, n_slots: int, *, top_k: int,
-                      n_layers: int, first: int) -> None:
+def record_held_pairs(load, n_tokens: int, *, top_k: int, n_layers: int,
+                      first: int) -> None:
     """The serving counters behind the counts a routed-expert model's
     programs return (``model.serve_record_counts``): ``n_tokens`` real tokens
     went through ``n_layers`` expert layers, ``load[e]`` of their pairs fell
-    to held expert ``first + e``; the program was traced for ``n_slots``
-    tokens, which is what chose its expert layers' form."""
+    to held expert ``first + e``."""
     from .....observability import metrics
-    metrics.counter(
-        "serving.moe_expert_calls",
-        "expert layers the launched prefill and decode programs ran, "
-        "by the form their token count selects (form=dense: every held "
-        "expert over the whole batch; form=grouped: sorted pairs)"
-    ).labels(form=expert_form(n_slots)).inc(n_layers)
     pairs = metrics.counter(
         "serving.moe_assignments",
         "(token, expert) pairs the router made (kind=routed: tokens x "

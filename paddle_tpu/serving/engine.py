@@ -115,9 +115,8 @@ serves by giving (``text/models/gpt.py``, ``text/models/deepseek_v2.py``,
 - ``serve_counts`` (0, or how many int32 counts the layers' ``serve_finish``
   return): the prefill and decode programs then return them behind the
   token, in the one array the host already waits for, and the engine hands
-  them to ``model.serve_record_counts(counts, n_real_tokens, n_slots)``
-  (the expert counters of a routed-expert model; ``n_slots`` is the
-  program's static token count, its bucket);
+  them to ``model.serve_record_counts(counts, n_real_tokens)`` (the expert
+  counters of a routed-expert model);
 - **a layer that keeps a state a sequence** (``text/models/olmo_hybrid.py``'s
   linear layers) says ``serve_keeps = "state"`` and gives, in place of the
   four steps above, ``serve_prefill_state(x, n_tokens) -> (x, parts)`` (the
@@ -141,6 +140,12 @@ The engine writes the rows into the pools, keeps the block tables, and
 calls no model by name; scheduler, allocator, spill, spans and counters are
 the same for every model. A model without state layers has the programs it
 had before any model had them: the same arguments, the same donations.
+Every call across the seam runs under one ``jax.named_scope`` of
+:data:`device_names.SEAMS` (``embed``, ``attn/project``, ``finish``, ``head``,
+``sample``, ...; a family's own scopes nest under them), and a program's
+module is named by its kind (``jit_serve_decode``, ...), so that the device
+trace names every instruction by the part of the model it runs
+(OBSERVABILITY.md, "Device-side names of every serving program").
 Decoding is greedy: the argmax of a row's logits, matching
 ``model.generate``'s default, or for a block model the argmax at the
 positions the unmask rule chooses.
@@ -197,6 +202,7 @@ import numpy as np
 from ..core import flags as _flags
 from ..fault.injection import fire as _fault_fire
 from ..framework.functional import _swapped_state, get_params
+from ..observability import device_names
 from ..observability import live as fleet_live
 from ..observability import metrics, request_timeline, trace
 from ..observability.request_timeline import percentile
@@ -394,15 +400,22 @@ class _ParamJit:
     afford on a 16 GB chip (and which no compiler should be handed as
     literals). Callers keep the raw signature: ``fn(*args)`` and
     ``fn.lower(*args)`` put the weights (snapshotted here, as the
-    closure's were at first trace) in front."""
+    closure's were at first trace) in front.
 
-    def __init__(self, raw, model):
+    ``kind`` names the program: its module is ``jit_serve_<kind>`` on the
+    device trace's ``XLA Modules`` line."""
+
+    def __init__(self, raw, model, kind: str):
         self.params = get_params(model)
+        self.kind = kind
 
         def step(params, *args):
             with _swapped_state(model, params, None):
                 return raw(*args)
 
+        # the module's name; not functools.wraps, whose __wrapped__ jax
+        # would follow for the signature (shifting donate_argnums)
+        step.__name__ = step.__qualname__ = f"serve_{kind}"
         # every step is (tokens, *pools, ...): the model's page pools, then
         # its slot pools, are donated (raw args 1.. — behind the weights: 2..)
         n_pools = len(model.serve_cache_rows()) + len(_state_spec(model))
@@ -414,6 +427,20 @@ class _ParamJit:
 
     def lower(self, *args):
         return self.jitted.lower(self.params, *args)
+
+
+def _dispatch(fn, args, new: bool):
+    """``fn(*args)``; where the recompile sentinel has just seen the
+    signature for the first time (``new``), the program also goes to
+    :func:`device_names.note`, at shapes taken before the dispatch donates
+    the pools. A callable that is no :class:`_ParamJit` (a wrapper put in
+    its place) has no program to note."""
+    if not (new and isinstance(fn, _ParamJit)):
+        return fn(*args)
+    shapes = device_names.abstract((fn.params,) + args)
+    out = fn(*args)
+    device_names.note(fn.kind, fn.jitted, shapes)
+    return out
 
 
 class ServingEngine:
@@ -601,8 +628,10 @@ class ServingEngine:
         self._prefill_raw = self._make_prefill()
         self._decode_raw = self._make_decode() if self._gen is None \
             else self._make_block_decode()
-        self._prefill_fn = _ParamJit(self._prefill_raw, model)
-        self._decode_fn = _ParamJit(self._decode_raw, model)
+        self._prefill_fn = _ParamJit(self._prefill_raw, model, "prefill")
+        self._decode_fn = _ParamJit(
+            self._decode_raw, model,
+            "decode" if self._gen is None else "block_decode")
         self._sent_prefill = RecompileSentinel(
             threshold=len(self.prefill_buckets))
         self._sent_decode = RecompileSentinel(
@@ -613,7 +642,7 @@ class ServingEngine:
         if self.prefix_on or self.chunk_tokens:
             self._chunk_raw = self._make_extend(self.model,
                                                 last_only=True)
-            self._chunk_fn = _ParamJit(self._chunk_raw, model)
+            self._chunk_fn = _ParamJit(self._chunk_raw, model, "extend")
             self._sent_chunk = RecompileSentinel(
                 threshold=len(self.prefill_buckets))
         self._verify_raw = None
@@ -622,7 +651,7 @@ class ServingEngine:
         if self.spec_gamma:
             self._verify_raw = self._make_extend(self.model,
                                                  last_only=False)
-            self._verify_fn = _ParamJit(self._verify_raw, model)
+            self._verify_fn = _ParamJit(self._verify_raw, model, "verify")
             self._sent_verify = RecompileSentinel(
                 threshold=len(self.decode_buckets))
         self._draft_decode_fn = None
@@ -631,10 +660,10 @@ class ServingEngine:
         if self._draft_cache is not None:
             self._draft_decode_fn = _ParamJit(
                 self._make_decode(self.drafter.model),
-                self.drafter.model)
+                self.drafter.model, "draft_decode")
             self._draft_extend_fn = _ParamJit(
                 self._make_extend(self.drafter.model, last_only=True),
-                self.drafter.model)
+                self.drafter.model, "draft_extend")
             self._sent_draft = RecompileSentinel(
                 threshold=len(self.decode_buckets) +
                 len(self.prefill_buckets))
@@ -669,16 +698,15 @@ class ServingEngine:
         """A step's arguments but the pools (what its sentinel watches)."""
         return (args[0],) + tuple(args[1 + self._n_pools:])
 
-    def _take_counts(self, out: np.ndarray, n_tok: int, n_real: int,
-                     n_slots: int):
+    def _take_counts(self, out: np.ndarray, n_tok: int, n_real: int):
         """Split what a prefill or decode program returned beside the pools
         into its token(s) and, for a model that counts
         (``model.serve_counts``), the counts behind them, which go to
         ``model.serve_record_counts`` with the number of real tokens the
-        program ran and the ``n_slots`` it was traced for (its bucket)."""
+        program ran."""
         if not self._n_counts:
             return out
-        self.model.serve_record_counts(out[n_tok:], n_real, n_slots)
+        self.model.serve_record_counts(out[n_tok:], n_real)
         return out[:n_tok]
 
     @staticmethod
@@ -709,34 +737,44 @@ class ServingEngine:
             states = list(rest[n_pools:n_pools + n_states])
             block_ids, n_tokens, *slot = rest[n_pools + n_states:]
             s = ids.shape[1]
-            pos = jnp.arange(s)[None, :]
-            real = pos < n_tokens if counted else None
             counts = []
-            x = m.serve_embed(ids, pos)
+            with jax.named_scope("embed"):
+                pos = jnp.arange(s)[None, :]
+                real = pos < n_tokens if counted else None
+                x = m.serve_embed(ids, pos)
             ri = ki = 0         # a layer's place among its kind
             for layer in m.serve_layers():
                 if _keeps_state(layer):
-                    x, kept = layer.serve_prefill_state(x, n_tokens)
-                    for j, part in enumerate(kept):
-                        states[j] = states[j].at[ki, slot[0]].set(
-                            part[0].astype(states[j].dtype))
+                    with jax.named_scope("state"):
+                        x, kept = layer.serve_prefill_state(x, n_tokens)
+                    with jax.named_scope("state/write"):
+                        for j, part in enumerate(kept):
+                            states[j] = states[j].at[ki, slot[0]].set(
+                                part[0].astype(states[j].dtype))
                     ki += 1
                     continue
-                q, rows = layer.serve_project(x, pos)
-                o = layer.serve_attend_prefill(q, rows)
-                for pi, row in enumerate(rows):
-                    pools[pi] = write_blocks(pools[pi], ri, block_ids,
-                                             row[0], bs)
-                x, c = layer.serve_finish(x, o, real)
+                with jax.named_scope("attn/project"):
+                    q, rows = layer.serve_project(x, pos)
+                with jax.named_scope("attn/attend"):
+                    o = layer.serve_attend_prefill(q, rows)
+                with jax.named_scope("attn/cache_write"):
+                    for pi, row in enumerate(rows):
+                        pools[pi] = write_blocks(pools[pi], ri, block_ids,
+                                                 row[0], bs)
+                with jax.named_scope("finish"):
+                    x, c = layer.serve_finish(x, o, real)
                 if c is not None:
                     counts.append(c)
                 ri += 1
-            hidden = m.serve_final_norm(x)
-            last = jax.lax.dynamic_index_in_dim(hidden, n_tokens - 1,
-                                                axis=1, keepdims=True)
-            logits = m.logits(last)[0, 0]
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (self._with_counts(tok, counts), *pools, *states)
+            with jax.named_scope("head"):
+                hidden = m.serve_final_norm(x)
+                last = jax.lax.dynamic_index_in_dim(hidden, n_tokens - 1,
+                                                    axis=1, keepdims=True)
+                logits = m.logits(last)[0, 0]
+            with jax.named_scope("sample"):
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("counts"):
+                return (self._with_counts(tok, counts), *pools, *states)
 
         return prefill
 
@@ -771,36 +809,48 @@ class ServingEngine:
                 *fed, slots = fed
             if fed:
                 prev, src = fed
-                tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
-                                   tokens)
+                with jax.named_scope("feed"):
+                    tokens = jnp.where(src >= 0, prev[jnp.maximum(src, 0)],
+                                       tokens)
             pos = ctx_lens
-            real = (ctx_lens > 0)[:, None] if counted else None
             counts = []
-            pos_col = pos[:, None]
-            x = m.serve_embed(tokens[:, None], pos_col)
-            bi = jnp.take_along_axis(tables, (pos // bs)[:, None],
-                                     axis=1)[:, 0]
-            si = pos % bs
+            with jax.named_scope("embed"):
+                real = (ctx_lens > 0)[:, None] if counted else None
+                pos_col = pos[:, None]
+                x = m.serve_embed(tokens[:, None], pos_col)
+            with jax.named_scope("attn/cache_write"):
+                bi = jnp.take_along_axis(tables, (pos // bs)[:, None],
+                                         axis=1)[:, 0]
+                si = pos % bs
             ri = ki = 0         # a layer's place among its kind
             for layer in m.serve_layers():
                 if _keeps_state(layer):
-                    x, states = layer.serve_decode_state(x, states, slots, ki)
+                    with jax.named_scope("state"):
+                        x, states = layer.serve_decode_state(x, states, slots,
+                                                             ki)
                     ki += 1
                     continue
-                q, rows = layer.serve_project(x, pos_col)
-                for pi, row in enumerate(rows):
-                    pools[pi] = write_tokens(pools[pi], ri, bi, si,
-                                             row[:, 0], bs)
-                o = layer.serve_attend_paged(q, pools, tables, pos + 1, bs,
-                                             ri)
-                x, c = layer.serve_finish(x, o, real)
+                with jax.named_scope("attn/project"):
+                    q, rows = layer.serve_project(x, pos_col)
+                with jax.named_scope("attn/cache_write"):
+                    for pi, row in enumerate(rows):
+                        pools[pi] = write_tokens(pools[pi], ri, bi, si,
+                                                 row[:, 0], bs)
+                with jax.named_scope("attn/attend"):
+                    o = layer.serve_attend_paged(q, pools, tables, pos + 1,
+                                                 bs, ri)
+                with jax.named_scope("finish"):
+                    x, c = layer.serve_finish(x, o, real)
                 if c is not None:
                     counts.append(c)
                 ri += 1
-            hidden = m.serve_final_norm(x)
-            logits = m.logits(hidden)[:, 0]
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (self._with_counts(tok, counts), *pools, *states)
+            with jax.named_scope("head"):
+                hidden = m.serve_final_norm(x)
+                logits = m.logits(hidden)[:, 0]
+            with jax.named_scope("sample"):
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("counts"):
+                return (self._with_counts(tok, counts), *pools, *states)
 
         return decode
 
@@ -860,58 +910,71 @@ class ServingEngine:
             pools = list(rest[:n_pools])
             tables, pos0, masked, commit, end_pos, prev, src = rest[n_pools:]
             w = tokens.shape[0]
-            at = jnp.arange(B)[None, :]
             # -- a resident row's state, from the launch in flight ----------
-            row = jnp.maximum(src, 0)
-            p_tok, p_masked, p_stage, p_pos0 = (
-                part[row] for part in self._block_state(prev, w))
-            nxt = (p_stage == 2)[:, None]       # its block was committed
-            d_pos0 = p_pos0 + jnp.where(p_stage == 2, B, 0)
-            d_tok = jnp.where(nxt, mask_id, p_tok)
-            d_masked = jnp.where(nxt, d_pos0[:, None] + at < end_pos[:, None],
-                                 p_masked > 0)
-            fed = src >= 0
-            tokens = jnp.where(fed[:, None], d_tok, tokens)
-            masked = jnp.where(fed[:, None], d_masked, masked > 0)
-            pos0 = jnp.where(fed, d_pos0, pos0)
-            commit = jnp.where(fed, p_stage == 1, commit > 0)
-            live = end_pos > 0
-            masked = jnp.logical_and(masked, live[:, None])
+            with jax.named_scope("feed"):
+                at = jnp.arange(B)[None, :]
+                row = jnp.maximum(src, 0)
+                p_tok, p_masked, p_stage, p_pos0 = (
+                    part[row] for part in self._block_state(prev, w))
+                nxt = (p_stage == 2)[:, None]       # its block was committed
+                d_pos0 = p_pos0 + jnp.where(p_stage == 2, B, 0)
+                d_tok = jnp.where(nxt, mask_id, p_tok)
+                d_masked = jnp.where(
+                    nxt, d_pos0[:, None] + at < end_pos[:, None],
+                    p_masked > 0)
+                fed = src >= 0
+                tokens = jnp.where(fed[:, None], d_tok, tokens)
+                masked = jnp.where(fed[:, None], d_masked, masked > 0)
+                pos0 = jnp.where(fed, d_pos0, pos0)
+                commit = jnp.where(fed, p_stage == 1, commit > 0)
+                live = end_pos > 0
+                masked = jnp.logical_and(masked, live[:, None])
             # -- the pass ---------------------------------------------------
-            pos = pos0[:, None] + at
-            real = jnp.broadcast_to(live[:, None], (w, B)) if counted \
-                else None
             counts = []
-            x = m.serve_embed(tokens, pos)
-            bi = jnp.take_along_axis(
-                tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1), axis=1)
-            bi = jnp.where(live[:, None], bi, NULL_BLOCK)
-            si = pos % bs
-            lengths = jnp.where(live, pos0 + B, 0)
+            with jax.named_scope("embed"):
+                pos = pos0[:, None] + at
+                real = jnp.broadcast_to(live[:, None], (w, B)) if counted \
+                    else None
+                x = m.serve_embed(tokens, pos)
+            with jax.named_scope("attn/cache_write"):
+                bi = jnp.take_along_axis(
+                    tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1),
+                    axis=1)
+                bi = jnp.where(live[:, None], bi, NULL_BLOCK)
+                si = pos % bs
+            with jax.named_scope("attn/attend"):
+                lengths = jnp.where(live, pos0 + B, 0)
             for li, layer in enumerate(m.serve_layers()):
-                q, rows = layer.serve_project(x, pos)
-                for pi, r in enumerate(rows):
-                    pools[pi] = write_tokens(pools[pi], li, bi, si, r, bs)
-                o = layer.serve_attend_block(q, pools, tables, lengths, bs,
-                                             li)
-                x, c = layer.serve_finish(x, o, real)
+                with jax.named_scope("attn/project"):
+                    q, rows = layer.serve_project(x, pos)
+                with jax.named_scope("attn/cache_write"):
+                    for pi, r in enumerate(rows):
+                        pools[pi] = write_tokens(pools[pi], li, bi, si, r, bs)
+                with jax.named_scope("attn/attend"):
+                    o = layer.serve_attend_block(q, pools, tables, lengths,
+                                                 bs, li)
+                with jax.named_scope("finish"):
+                    x, c = layer.serve_finish(x, o, real)
                 if c is not None:
                     counts.append(c)
-            logits = m.logits(m.serve_final_norm(x)).astype(jnp.float32)
-            x0, chosen, by_threshold = _unmask(logits, masked, k_min, thr)
-            chosen = jnp.logical_and(chosen,
-                                     jnp.logical_not(commit)[:, None])
-            tokens = jnp.where(chosen, x0, tokens)
-            masked = jnp.logical_and(masked, jnp.logical_not(chosen))
-            stage = jnp.where(commit, 2,
-                              jnp.where(jnp.any(masked, axis=-1), 0, 1))
-            n_thr = jnp.sum(jnp.logical_and(chosen, by_threshold))
-            state = jnp.concatenate([
-                tokens.reshape(-1), masked.astype(jnp.int32).reshape(-1),
-                stage.astype(jnp.int32), pos0,
-                jnp.stack([n_thr, jnp.sum(chosen) - n_thr]).astype(
-                    jnp.int32)])
-            return (self._with_counts(state, counts), *pools)
+            with jax.named_scope("head"):
+                logits = m.logits(m.serve_final_norm(x)).astype(jnp.float32)
+            with jax.named_scope("sample"):
+                x0, chosen, by_threshold = _unmask(logits, masked, k_min, thr)
+                chosen = jnp.logical_and(chosen,
+                                         jnp.logical_not(commit)[:, None])
+                tokens = jnp.where(chosen, x0, tokens)
+                masked = jnp.logical_and(masked, jnp.logical_not(chosen))
+                stage = jnp.where(commit, 2,
+                                  jnp.where(jnp.any(masked, axis=-1), 0, 1))
+                n_thr = jnp.sum(jnp.logical_and(chosen, by_threshold))
+                state = jnp.concatenate([
+                    tokens.reshape(-1), masked.astype(jnp.int32).reshape(-1),
+                    stage.astype(jnp.int32), pos0,
+                    jnp.stack([n_thr, jnp.sum(chosen) - n_thr]).astype(
+                        jnp.int32)])
+            with jax.named_scope("counts"):
+                return (self._with_counts(state, counts), *pools)
 
         return block_decode
 
@@ -937,32 +1000,40 @@ class ServingEngine:
             pools, (tables, ctx_lens, n_real) = list(rest[:n_pools]), \
                 rest[n_pools:]
             b, L = tokens.shape
-            pos = ctx_lens[:, None] + jnp.arange(L)[None, :]       # [B, L]
-            real = jnp.arange(L)[None, :] < n_real[:, None]        # [B, L]
-            pos_q = jnp.where(real, pos, 0)
-            x = m.serve_embed(tokens, pos_q)
-            bi = jnp.take_along_axis(
-                tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1),
-                axis=1)
-            bi = jnp.where(real, bi, NULL_BLOCK)
-            si = pos % bs
+            with jax.named_scope("embed"):
+                pos = ctx_lens[:, None] + jnp.arange(L)[None, :]   # [B, L]
+                real = jnp.arange(L)[None, :] < n_real[:, None]    # [B, L]
+                pos_q = jnp.where(real, pos, 0)
+                x = m.serve_embed(tokens, pos_q)
+            with jax.named_scope("attn/cache_write"):
+                bi = jnp.take_along_axis(
+                    tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1),
+                    axis=1)
+                bi = jnp.where(real, bi, NULL_BLOCK)
+                si = pos % bs
             for li, layer in enumerate(m.serve_layers()):
-                q, rows = layer.serve_project(x, pos_q)
-                for pi, row in enumerate(rows):
-                    pools[pi] = write_tokens(pools[pi], li, bi, si, row, bs)
-                o = layer.serve_attend_extend(q, pools, tables, pos_q, bs,
-                                              li)
-                x, _ = layer.serve_finish(x, o, None)
-            hidden = m.serve_final_norm(x)
-            if last_only:
-                idx = jnp.maximum(n_real - 1, 0)[:, None, None]
-                last = jnp.take_along_axis(
-                    hidden, jnp.broadcast_to(
-                        idx, (b, 1, hidden.shape[-1])), axis=1)
-                logits = m.logits(last)[:, 0]
-                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
+                with jax.named_scope("attn/project"):
+                    q, rows = layer.serve_project(x, pos_q)
+                with jax.named_scope("attn/cache_write"):
+                    for pi, row in enumerate(rows):
+                        pools[pi] = write_tokens(pools[pi], li, bi, si, row,
+                                                 bs)
+                with jax.named_scope("attn/attend"):
+                    o = layer.serve_attend_extend(q, pools, tables, pos_q,
+                                                  bs, li)
+                with jax.named_scope("finish"):
+                    x, _ = layer.serve_finish(x, o, None)
+            with jax.named_scope("head"):
+                hidden = m.serve_final_norm(x)
+                if last_only:
+                    idx = jnp.maximum(n_real - 1, 0)[:, None, None]
+                    hidden = jnp.take_along_axis(
+                        hidden, jnp.broadcast_to(
+                            idx, (b, 1, hidden.shape[-1])), axis=1)
                 logits = m.logits(hidden)
+            with jax.named_scope("sample"):
+                if last_only:
+                    logits = logits[:, 0]
                 toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (toks, *pools)
 
@@ -1644,15 +1715,15 @@ class ServingEngine:
                     args += (jnp.asarray(seq.state_slot, jnp.int32),)
                 self._maybe_lint()
                 self._assert_cow(block_ids)
-                self._sent_prefill.observe_tree(
+                new = self._sent_prefill.observe_tree(
                     "serving.prefill", self._undonated(args),
                     donate=self._donated, where="serving.prefill")
             with trace.span("serve/prefill/launch"):
-                tok, *pools = self._prefill_fn(*args)
+                tok, *pools = _dispatch(self._prefill_fn, args, new)
             with trace.span("serve/prefill/wait") as wait:
                 # host sync: the first token exists now
                 tok = int(self._take_counts(
-                    np.asarray(tok), 1, n_tokens, bucket).reshape(-1)[0])
+                    np.asarray(tok), 1, n_tokens).reshape(-1)[0])
             _account(sp.t0_ns, wait.end_ns, "prefill", (seq,))
             with trace.span("serve/prefill/commit"):
                 self.cache.swap(*pools)
@@ -1704,11 +1775,11 @@ class ServingEngine:
                         jnp.asarray([span], jnp.int32))
                 self._maybe_lint()
                 self._assert_cow(self._write_span_ids(seq, start, span))
-                self._sent_chunk.observe_tree(
+                new = self._sent_chunk.observe_tree(
                     "serving.extend", self._undonated(args),
                     donate=self._donated, where="serving.extend")
             with trace.span("serve/prefill/launch"):
-                out, *pools = self._chunk_fn(*args)
+                out, *pools = _dispatch(self._chunk_fn, args, new)
             with trace.span("serve/prefill/wait") as wait:
                 out = np.asarray(out)   # host sync: the chunk is done
             _account(sp.t0_ns, wait.end_ns, "chunk_prefill", (seq,))
@@ -2051,11 +2122,11 @@ class ServingEngine:
                 if self.prefix is not None:
                     for seq, pos in zip(batch, after[0].tolist()):
                         self._assert_cow(self._write_span_ids(seq, pos, 1))
-                self._sent_decode.observe_tree(
+                new = self._sent_decode.observe_tree(
                     "serving.decode", self._undonated(args),
                     donate=self._donated, where="serving.decode")
             with trace.span("serve/decode/launch"):
-                out, *pools = self._decode_fn(*args)
+                out, *pools = _dispatch(self._decode_fn, args, new)
                 # the pools this program returns are the cache from here
                 # on: whatever is launched before its tokens are taken (a
                 # prefill, a spill) runs behind it on them
@@ -2092,8 +2163,7 @@ class ServingEngine:
             with self._acted_span("serve/decode/wait") as wait:
                 # host sync per iteration
                 out = self._take_counts(
-                    np.asarray(out), self._state_len(width), rows * per_row,
-                    width * per_row)
+                    np.asarray(out), self._state_len(width), rows * per_row)
             if self._ahead is not None and wait.end_ns > self._ahead.t0_ns:
                 # the launch queued behind this one began on the device now
                 self._ahead = self._ahead._replace(t0_ns=wait.end_ns)
@@ -2331,11 +2401,11 @@ class ServingEngine:
                 for i, seq in enumerate(batch):
                     self._assert_cow(self._write_span_ids(
                         seq, seq.ctx_len, int(n_real[i])))
-                self._sent_verify.observe_tree(
+                new = self._sent_verify.observe_tree(
                     "serving.verify", self._undonated(args),
                     donate=self._donated, where="serving.verify")
             with trace.span("serve/decode/launch"):
-                out, *pools = self._verify_fn(*args)
+                out, *pools = _dispatch(self._verify_fn, args, new)
             with self._acted_span("serve/decode/wait") as wait:
                 out = np.asarray(out)
             with trace.span("serve/decode/commit"):

@@ -507,14 +507,12 @@ class DeepseekV2ForCausalLM(nn.Layer):
         entries (what the engine hands ``takes_paged_kernel``)."""
         return self.cfg.kv_lora_rank
 
-    def serve_record_counts(self, load: np.ndarray, n_tokens: int,
-                            n_slots: int) -> None:
+    def serve_record_counts(self, load: np.ndarray, n_tokens: int) -> None:
         """The counters behind the programs' counts: ``n_tokens`` real tokens
         went through every expert layer, ``load[e]`` of their pairs fell to
-        held expert ``e``; the program was traced for ``n_slots`` tokens,
-        which is what chose its expert layers' form."""
+        held expert ``e``."""
         cfg = self.cfg
         record_held_pairs(
-            load, n_tokens, n_slots, top_k=cfg.num_experts_per_tok,
+            load, n_tokens, top_k=cfg.num_experts_per_tok,
             n_layers=sum(1 for l in self.model.layers if l.is_moe),
             first=cfg.held[0])

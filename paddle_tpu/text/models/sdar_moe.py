@@ -330,13 +330,10 @@ class SdarMoeForCausalLM(nn.Layer):
     def serve_final_norm(self, x):
         return self.model.norm(x)
 
-    def serve_record_counts(self, load: np.ndarray, n_tokens: int,
-                            n_slots: int) -> None:
+    def serve_record_counts(self, load: np.ndarray, n_tokens: int) -> None:
         """The counters behind the programs' counts, as DeepSeek-V2's:
         ``n_tokens`` real tokens went through every layer, ``load[e]`` of
-        their pairs fell to held expert ``e``; the program was traced for
-        ``n_slots`` tokens, which is what chose its expert layers' form."""
+        their pairs fell to held expert ``e``."""
         cfg = self.cfg
-        record_held_pairs(load, n_tokens, n_slots,
-                          top_k=cfg.num_experts_per_tok,
+        record_held_pairs(load, n_tokens, top_k=cfg.num_experts_per_tok,
                           n_layers=cfg.num_hidden_layers, first=cfg.held[0])

@@ -692,3 +692,87 @@ def test_state_decode_program_compiles(one_chip, monkeypatch):
     # the temporaries are the step's activations and its float32 logits
     # (256 x 12,544 x 4 B = 13 MB), not a gathered state (256 x 2.2 MB)
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
+def _serving_engine(family):
+    """A two-layer engine of each family at its published widths (weights
+    as zeros where the family builds them so: nothing runs), small buckets:
+    the programs' scopes do not depend on the sizes."""
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingEngine
+    paddle.seed(0)
+    if family == "gpt":
+        from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=50304, hidden_size=2048, num_layers=2, num_heads=16,
+            intermediate_size=8192, max_position_embeddings=2048))
+        model.astype(paddle.bfloat16)
+    elif family == "deepseek":
+        from paddle_tpu.text.models.deepseek_v2 import (
+            DeepseekV2Config, DeepseekV2ForCausalLM)
+        model = DeepseekV2ForCausalLM(DeepseekV2Config(
+            vocab_size=12800, num_hidden_layers=2, experts_held=(0, 10),
+            dtype="bfloat16", init_weights=False))
+    elif family == "sdar":
+        from paddle_tpu.text.models.sdar_moe import (SdarMoeConfig,
+                                                     SdarMoeForCausalLM)
+        model = SdarMoeForCausalLM(SdarMoeConfig(
+            vocab_size=37984, num_hidden_layers=2, experts_held=(0, 16),
+            mask_token_id=37000, dtype="bfloat16", init_weights=False))
+    else:
+        from paddle_tpu.text.models.olmo_hybrid import (
+            OlmoHybridConfig, OlmoHybridForCausalLM)
+        model = OlmoHybridForCausalLM(OlmoHybridConfig(
+            vocab_size=12544, num_hidden_layers=2,
+            layer_types=["linear_attention", "full_attention"],
+            dtype="bfloat16", init_weights=False))
+    return ServingEngine(model, block_size=16, num_blocks=129, max_batch=16,
+                         max_seq_len=2048, prefill_buckets=[256],
+                         decode_buckets=[16])
+
+
+@pytest.mark.parametrize("family", ["gpt", "deepseek", "sdar", "olmo"])
+def test_serving_programs_name_every_instruction_by_seam(one_chip,
+                                                         monkeypatch, family):
+    """The decode and prefill programs as the chip runs them (the entry
+    points steered to the chip's kernels): every instruction traced from
+    the engine's code lies under a seam scope, and in the name table every
+    leaf instruction the programs execute does (what the compiler made
+    takes its user's scope); the head's product lies under ``head``, the
+    argmax under ``sample``."""
+    import importlib
+    from paddle_tpu.observability import device_names as DN
+    for mod in ("paddle_tpu.ops.flash_attention",
+                "paddle_tpu.ops.gated_delta"):
+        monkeypatch.setattr(importlib.import_module(mod), "_platform_of",
+                            lambda x: "tpu")
+    eng = _serving_engine(family)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._decode_fn.params)
+    arrays = [on_chip(a) for a in eng.cache.arrays]
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    slot = (i32(()),) if eng.cache.states else ()
+    texts = {
+        "decode": eng._decode_fn.jitted.lower(
+            params, on_chip(eng._decode_head_spec(16)), *arrays,
+            *[on_chip(s) for s in eng._decode_tail_spec(16)]),
+        "prefill": eng._prefill_fn.jitted.lower(
+            params, i32((1, 256)), *arrays, i32((256 // 16,)), i32(()),
+            *slot)}
+    exempt = ("parameter", "constant", "tuple", "get-tuple-element",
+              "bitcast", "while", "conditional", "call")
+    for kind, lowered in texts.items():
+        text = lowered.compile().as_text()
+        names = re.findall(r'op_name="(jit\([^"]*)"', text)
+        assert names and [n for n in names if not DN.scopes(n)[0]] == []
+        assert any(re.search(r"/head/(.*/)?dot_general$", n) for n in names)
+        assert any(re.search(r"/sample/(.*/)?(argmax|reduce)$", n)
+                   for n in names)
+        prog = DN.parse(kind, text)
+        assert prog.module.startswith("jit_serve_")
+        assert [t for t, (seam, _) in prog.ops.items()
+                if DN.opcode(t) not in exempt and not seam] == []

@@ -33,7 +33,7 @@ from benchmark.lib.family import load_family  # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe.dropless import (  # noqa: E402
     DENSE_MAX_TOKENS, EXPERT_FORMS, dropless_glu_experts, dropless_route,
     expert_form, group_limited_topk)
-from paddle_tpu.observability import metrics  # noqa: E402
+from paddle_tpu.observability import device_names, metrics  # noqa: E402
 from paddle_tpu.ops._pallas.latent_paged_attention import (  # noqa: E402
     latent_paged_attention_pallas, supported_shapes)
 from paddle_tpu.serving import Request, ServingEngine  # noqa: E402
@@ -83,9 +83,18 @@ def ref_logits(fam, cfg, w, ids):
                                 w["head"]))
 
 
-def _expert_calls():
-    calls = metrics.counter("serving.moe_expert_calls")
-    return {form: calls.labels(form=form).get() for form in EXPERT_FORMS}
+def _expert_forms():
+    """The form each program kind's expert layers ran in, from the names of
+    the programs the engine noted: grouped where an instruction under
+    ``moe/experts`` sorts the pairs or is a grouped product, else dense."""
+    forms = {}
+    for p in device_names.table():
+        grouped = any(family == "moe/experts" and device_names.opcode(t)
+                      in ("sort", "ragged-dot")
+                      for t, (_, family) in p.ops.items())
+        forms.setdefault(p.kind, set()).add(
+            "grouped" if grouped else "dense")
+    return forms
 
 
 def assert_greedy_by_the_reference(fam, cfg, w, reqs, res):
@@ -180,14 +189,12 @@ def test_engine_serves_the_references_greedy_tokens(built):
             for i in range(5)]
     eng = ServingEngine(model, block_size=BS, num_blocks=33, max_batch=2,
                         max_seq_len=32)
-    before = _expert_calls()
+    device_names.reset()
     with jax.default_matmul_precision("highest"):
         res = eng.serve(reqs)
     assert len(res) == 5
     # no program here has more tokens than the dense form takes
-    after = _expert_calls()
-    assert after["grouped"] == before["grouped"]
-    assert after["dense"] > before["dense"]
+    assert _expert_forms() == {"prefill": {"dense"}, "decode": {"dense"}}
     assert_greedy_by_the_reference(fam, cfg, w, reqs, res)
 
 
@@ -415,8 +422,7 @@ def test_engine_serves_the_references_tokens_through_both_forms(built):
     """Prompts longer than the dense form's most tokens, so that each
     prefill program (bucket 1024) runs the grouped form and each decode
     program (bucket 2) the dense one: token for token the reference's greedy
-    continuation, and ``serving.moe_expert_calls`` moves by the expert
-    layers of the programs launched, each under its form."""
+    continuation, and the compiled programs' names show each form."""
     fam, cfg, w, model = built
     rng = np.random.default_rng(8)
     reqs = [Request(rid=f"r{i}", max_new_tokens=3 + i,
@@ -426,17 +432,11 @@ def test_engine_serves_the_references_tokens_through_both_forms(built):
     eng = ServingEngine(model, block_size=16, num_blocks=129, max_batch=2,
                         max_seq_len=1024, prefill_buckets=[1024],
                         decode_buckets=[2])
-    before = _expert_calls()
+    device_names.reset()
     with jax.default_matmul_precision("highest"):
         res = eng.serve(reqs)
-    moved = {form: n - before[form] for form, n in _expert_calls().items()}
-    moe_layers = sum(1 for l in model.model.layers if l.is_moe)
-    assert moe_layers == 2
-    # a prefill a request; the two rows decode together, so the longer
-    # answer sets the number of decode programs
-    assert moved == {"grouped": 2 * moe_layers,
-                     "dense": (max(q.max_new_tokens for q in reqs) - 1)
-                     * moe_layers}
+    assert sum(1 for l in model.model.layers if l.is_moe) == 2
+    assert _expert_forms() == {"prefill": {"grouped"}, "decode": {"dense"}}
     assert_greedy_by_the_reference(fam, cfg, w, reqs, res)
 
 
