@@ -128,13 +128,17 @@ serves by giving (``text/models/gpt.py``, ``text/models/deepseek_v2.py``,
   row's). The model then gives ``serve_state()``, the shapes and dtypes of
   the parts (:mod:`.paged_cache`: a slot pool a part over the state layers,
   the page pools over the other layers only, each kind indexed by a layer's
-  place among its own). The prefill program writes a sequence's state into
-  its slot (overwriting it), the decode program takes the slot pools donated
-  behind the page pools and the rows' slots last. A sequence is granted a
-  slot at admission and gives it back where it gives back its blocks; a
-  preemption spills the state with the pages and restores it into a fresh
-  slot. Prefix sharing, chunked prefill and speculation are refused for such
-  a model (each would need the state at a point no page holds: ROADMAP).
+  place among its own). A part's last two axes should be whole tiles of
+  128 lanes: the decode program writes a part by slot, and a part that is
+  one flat row lies in one sublane of each tile, which the chip's compiler
+  writes row by row in a loop. The prefill program writes a sequence's
+  state into its slot (overwriting it), the decode program takes the slot
+  pools donated behind the page pools and the rows' slots last. A sequence
+  is granted a slot at admission and gives it back where it gives back its
+  blocks; a preemption spills the state with the pages and restores it into
+  a fresh slot. Prefix sharing, chunked prefill and speculation are refused
+  for such a model (each would need the state at a point no page holds:
+  ROADMAP).
 
 The engine writes the rows into the pools, keeps the block tables, and
 calls no model by name; scheduler, allocator, spill, spans and counters are

@@ -54,6 +54,32 @@ __all__ = ["OlmoHybridConfig", "OlmoHybridForCausalLM"]
 
 PERIOD = ("linear_attention",) * 3 + ("full_attention",)
 
+#: a slot's convolution tail is kept as rows of this many lanes
+LANES = 128
+
+
+def _tail_rows(n: int) -> int:
+    """Rows of :data:`LANES` that hold a tail of ``n`` values."""
+    return -(-n // LANES)
+
+
+def _to_lane_rows(tail):
+    """``tail [B, K - 1, C]`` -> ``[B, rows, LANES]``, flat and the last row
+    zero-padded."""
+    b = tail.shape[0]
+    flat = tail.reshape(b, -1)
+    n = flat.shape[1]
+    flat = jnp.pad(flat, ((0, 0), (0, _tail_rows(n) * LANES - n)))
+    return flat.reshape(b, -1, LANES)
+
+
+def _from_lane_rows(rows, k_taps, width):
+    """:func:`_to_lane_rows`' inverse: ``[B, rows, LANES]`` -> ``[B, K - 1,
+    C]``, the padding dropped."""
+    b = rows.shape[0]
+    n = (k_taps - 1) * width
+    return rows.reshape(b, -1)[:, :n].reshape(b, k_taps - 1, width)
+
 
 @dataclass
 class OlmoHybridConfig:
@@ -250,10 +276,11 @@ class OlmoHybridLinearLayer(_Block):
 
     def serve_prefill_state(self, x, n_tokens):
         """A prompt ``x [B, S, hidden]`` whose first ``n_tokens`` are real ->
-        ``(x, (state [B, d_k, H * d_v] float32, tail [B, (K - 1) * C]))``: the
+        ``(x, (state [B, d_k, H * d_v] float32, tail [B, rows, 128]))``: the
         state after the real tokens (positions past them have ``g = beta =
         0``, which leave it as it is) and the convolution's inputs of the
-        last ``K - 1`` real tokens (zeros before position 0)."""
+        last ``K - 1`` real tokens (zeros before position 0), in the rows of
+        lanes ``serve_state()`` gives."""
         k_taps = self.cfg.linear_conv_kernel_dim
         xc, z, g, beta = self._mix_in(x)
         real = (jnp.arange(x.shape[1]) < n_tokens)[None, :, None]
@@ -265,23 +292,25 @@ class OlmoHybridLinearLayer(_Block):
                                                 axis=1)
         with jax.named_scope("gdn/chunk"):
             o, state = chunk_gated_delta(q, k, v, g, beta)
-        tail = tail.reshape(tail.shape[0], -1)
-        return self._mix_out(x, o, z), (state, tail)
+        return self._mix_out(x, o, z), (state, _to_lane_rows(tail))
 
     def serve_decode_state(self, x, pools, slots, layer):
         """One token a row ``x [B, 1, hidden]`` over the pools of what the
         state layers keep (``[state layers, slots, ...]``, ``serve_state()``),
         each row's by ``slots [B]`` (0: a pad row) in ``layer`` (this layer's
         place among the state layers) -> ``(x, pools)``, the row's state and
-        tail advanced in place."""
+        tail advanced in place. A slot's tail is whole ``(16, 128)`` tiles of
+        its pool, so the write by slot is one scatter of whole tiles (a flat
+        tail is one sublane of each tile, and its write a loop over the
+        rows)."""
         state_pool, tail_pool = pools
-        b = x.shape[0]
         xc, z, g, beta = self._mix_in(x[:, 0])
         with jax.named_scope("gdn/conv"):
-            tail = tail_pool[layer, slots].reshape(
-                b, self.cfg.linear_conv_kernel_dim - 1, -1)
+            tail = _from_lane_rows(tail_pool[layer, slots],
+                                   self.cfg.linear_conv_kernel_dim,
+                                   xc.shape[-1])
             conv, tail = conv_step(xc, tail, self.conv_weight)
-            tail_pool = tail_pool.at[layer, slots].set(tail.reshape(b, -1))
+            tail_pool = tail_pool.at[layer, slots].set(_to_lane_rows(tail))
             q, k, v = self._heads(conv)
         with jax.named_scope("gdn/step"):
             o, state_pool = gated_delta_decode(q, k, v, g, beta, state_pool,
@@ -343,15 +372,17 @@ class OlmoHybridForCausalLM(nn.Layer):
         """What a linear layer keeps for a sequence, one pool each: the state
         ``[d_k, H * d_v]`` float32 (head ``h`` the columns ``h * d_v ..``:
         whole tiles on the chip) and the convolution's last ``K - 1`` inputs,
-        flat."""
+        flat in rows of 128 lanes (``[ceil((K - 1) * C / 128), 128]``, the
+        last row zero-padded): whole tiles too, which the decode program
+        writes by slot in place."""
         cfg = self.cfg
         nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                       cfg.linear_value_head_dim)
         width = 2 * cfg.linear_num_key_heads * dk + nv * dv
+        n_tail = (cfg.linear_conv_kernel_dim - 1) * width
         return (jax.ShapeDtypeStruct((dk, nv * dv), jnp.float32),
-                jax.ShapeDtypeStruct(
-                    ((cfg.linear_conv_kernel_dim - 1) * width,),
-                    self.serve_dtype()))
+                jax.ShapeDtypeStruct((_tail_rows(n_tail), LANES),
+                                     self.serve_dtype()))
 
     def serve_dtype(self):
         return self.model.embed_tokens.weight.dtype

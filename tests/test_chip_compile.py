@@ -694,6 +694,68 @@ def test_state_decode_program_compiles(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
 
 
+def test_state_decode_program_writes_tails_by_whole_tiles(one_chip,
+                                                         monkeypatch):
+    """The decode program at the Olmo cell's shapes (3 linear layers and 1
+    full layer, 256 rows, 257 slots, an eighth of the vocabulary): each
+    linear layer's convolution tails are written back by slot as whole
+    tiles, in place: no ``while`` loop of row writes, no
+    ``dynamic-update-slice`` into the tail pool, and every pool aliased to
+    its output."""
+    import importlib
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.text.models.olmo_hybrid import (PERIOD, OlmoHybridConfig,
+                                                    OlmoHybridForCausalLM)
+    for mod in ("paddle_tpu.ops.flash_attention",
+                "paddle_tpu.ops.gated_delta"):
+        monkeypatch.setattr(importlib.import_module(mod), "_platform_of",
+                            lambda x: "tpu")
+    c = CELL_STATE
+    cfg = OlmoHybridConfig(vocab_size=12544, num_hidden_layers=4,
+                           layer_types=list(PERIOD), dtype="bfloat16",
+                           init_weights=False)
+    paddle.seed(0)
+    model = OlmoHybridForCausalLM(cfg)
+    eng = ServingEngine(model, block_size=c["bs"], num_blocks=c["m"] + 1,
+                        max_batch=1, max_seq_len=c["m"] * c["bs"],
+                        prefill_buckets=[1024], decode_buckets=[c["b"]])
+    (pool,) = eng.cache.pools
+    state, tail = eng.cache.states
+    # (K - 1) * C = 3 * 11,520 bf16: 270 rows of 128 lanes a slot
+    assert tail.shape[2:] == (270, 128)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, eng._decode_fn.params)
+    lead = (c["layers"], c["slots"])
+    arrays = [jax.ShapeDtypeStruct((1, c["nb"]) + pool.shape[2:],
+                                   jnp.bfloat16, sharding=one_chip),
+              jax.ShapeDtypeStruct(lead + state.shape[2:], jnp.float32,
+                                   sharding=one_chip),
+              jax.ShapeDtypeStruct(lead + tail.shape[2:], jnp.bfloat16,
+                                   sharding=one_chip)]
+    tail_args = [on_chip(s) for s in eng._decode_tail_spec(c["b"])]
+    compiled = eng._decode_fn.jitted.lower(
+        params, on_chip(eng._decode_head_spec(c["b"])), *arrays,
+        *tail_args).compile()
+    text = compiled.as_text()
+    # a state kernel call a linear layer and the block kernel's one
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert " while(" not in text
+    # the tail pool (bf16 [3 layers, 257 slots, ...]) in any layout
+    assert not re.search(r"bf16\[3,257,[\d,]+\]\S* dynamic-update-slice\(",
+                         text)
+    assert len(re.findall(r"bf16\[3,257,270,128\]\S* scatter\(", text)) == 3
+    mem = compiled.memory_analysis()
+    state_bytes = 3 * c["slots"] * c["dk"] * c["h"] * c["dv"] * 4
+    tail_bytes = 3 * c["slots"] * 272 * 128 * 2     # 270 rows pad to 272
+    page_bytes = c["nb"] * c["bs"] * 2 * c["h"] * 128 * 2
+    assert mem.alias_size_in_bytes >= state_bytes + tail_bytes + page_bytes
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
 def _serving_engine(family):
     """A two-layer engine of each family at its published widths (weights
     as zeros where the family builds them so: nothing runs), small buckets:

@@ -10,7 +10,8 @@ the jnp step's, a pad row's null slot and a row whose slot is not its batch
 index included; (c) the model's forward and the engine's prefill and decode
 through both caches give the reference's logits and tokens; (d) slots are
 given back on finish, cancel and failure, and a spill and restore of a state
-is bitwise; (e) a row preempted with its token in flight resumes from the
+is bitwise, (c) and (d) at a convolution tail of whole rows of 128 lanes and
+at one whose last row is padded; (e) a row preempted with its token in flight resumes from the
 state of its committed tokens; (f) a model without state layers has the
 programs it had; (g) the tiers that would need a state at a shared point are
 refused.
@@ -59,13 +60,28 @@ def small_cfg(**over):
     return cfg
 
 
-@pytest.fixture(scope="module")
-def built():
-    cfg = small_cfg()
+def _build(**over):
+    cfg = small_cfg(**over)
     fam = load_family(ROOT, cfg)
     w = LW.make_weights(fam.weights, cfg, SEED, dtype=jnp.float32)
     ref = fam.reference.Reference(cfg)
     return cfg, fam, w, ref
+
+
+@pytest.fixture(scope="module")
+def built():
+    return _build()
+
+
+#: the convolution tail's width ``(K - 1) * C``: 384, whole rows of 128
+#: lanes (``built``), and 336, whose last row is padded (d_v 24: C = 112)
+TAILS = {"tail384": None, "tail336": dict(linear_value_head_dim=24)}
+
+
+@pytest.fixture(scope="module", params=list(TAILS))
+def by_tail(request, built):
+    over = TAILS[request.param]
+    return _build(**over) if over else built
 
 
 def model_of(built):
@@ -174,9 +190,18 @@ def test_the_models_forward_is_the_references(built):
     np.testing.assert_allclose(got, want, rtol=1e-3, atol=2e-4)
 
 
-def test_the_engine_serves_the_references_tokens(built):
+def test_the_engine_serves_the_references_tokens(by_tail):
+    built = by_tail
+    cfg = built[0]
     model = model_of(built)
     eng = engine(model)
+    # the tail kept a slot is rows of 128 lanes, the last zero-padded
+    width = (2 * cfg["linear_num_key_heads"] * cfg["linear_key_head_dim"]
+             + cfg["linear_num_value_heads"] * cfg["linear_value_head_dim"])
+    n_tail = (cfg["linear_conv_kernel_dim"] - 1) * width
+    tail = model.serve_state()[1]
+    assert tail.shape == (-(-n_tail // 128), 128)
+    assert eng.cache.states[1].shape[2:] == tail.shape
     reqs = [Request(f"r{i}", p, max_new_tokens=int(n)) for i, (p, n) in
             enumerate(zip(prompts(5, seed=4), [9, 14, 4, 20, 11]))]
     out = eng.serve(reqs)
@@ -219,7 +244,8 @@ def test_slots_are_given_back_on_cancel_and_failure(built):
     assert eng.cache.slots.n_used == 0
 
 
-def test_a_spilled_state_comes_back_bitwise(built):
+def test_a_spilled_state_comes_back_bitwise(by_tail):
+    built = by_tail
     model = model_of(built)
     eng = engine(model)
     seq = eng.submit(Request("s", prompts(1, seed=7)[0], max_new_tokens=24))
